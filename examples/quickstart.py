@@ -20,10 +20,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from photon_ml_tpu.utils import apply_env_platforms
-
-apply_env_platforms()  # honor JAX_PLATFORMS even where site config overrides
-
 import numpy as np
 import jax.numpy as jnp
 

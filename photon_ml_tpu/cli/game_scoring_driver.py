@@ -23,7 +23,8 @@ from photon_ml_tpu.io.model_io import load_game_model
 from photon_ml_tpu.io.schemas import SCORING_RESULT_SCHEMA
 from photon_ml_tpu.evaluation import get_evaluator
 from photon_ml_tpu.models import RandomEffectModel
-from photon_ml_tpu.utils import PhotonLogger, Timed, resolve_dtype
+from photon_ml_tpu.utils import (PhotonLogger, Timed,
+                                 configure_compile_cache, resolve_dtype)
 
 
 def _positive_int(value: str) -> int:
@@ -81,6 +82,7 @@ def _slice_host_sparse(sp, row_slice):
 
 
 def main(argv: Sequence[str] | None = None) -> int:
+    configure_compile_cache()
     try:
         return _main(argv)
     except Exception as e:

@@ -59,7 +59,8 @@ from photon_ml_tpu.optimize import OptimizerConfig
 from photon_ml_tpu.parallel.data_parallel import fit_distributed
 from photon_ml_tpu.parallel.mesh import make_mesh
 from photon_ml_tpu.types import LabeledBatch, SparseFeatures, make_batch
-from photon_ml_tpu.utils import (PhotonLogger, Timed, is_device_loss,
+from photon_ml_tpu.utils import (PhotonLogger, Timed,
+                                 configure_compile_cache, is_device_loss,
                                  resolve_dtype)
 
 
@@ -270,6 +271,7 @@ def _read(paths, fmt, index_map: Optional[IndexMap], add_intercept):
 
 
 def main(argv: Sequence[str] | None = None) -> int:
+    configure_compile_cache()
     args = build_arg_parser().parse_args(argv)
     from photon_ml_tpu.obs import logging as obs_logging
     from photon_ml_tpu.obs import trace as obs_trace

@@ -41,7 +41,7 @@ import threading
 import time
 from typing import Sequence
 
-from photon_ml_tpu.utils import PhotonLogger, Timed
+from photon_ml_tpu.utils import PhotonLogger, Timed, configure_compile_cache
 
 
 def positive_int(value: str) -> int:
@@ -456,6 +456,7 @@ def _run_multi_replica(args, logger) -> int:
 
 
 def main(argv: Sequence[str] | None = None) -> int:
+    configure_compile_cache()
     args = build_arg_parser().parse_args(argv)
     from photon_ml_tpu.obs import logging as obs_logging
     from photon_ml_tpu.obs import trace as obs_trace

@@ -82,9 +82,7 @@ def bootstrap_coefficients(
 
     @jax.jit
     def run_all(key):
-        from photon_ml_tpu.compat import random_multinomial
-
-        counts = random_multinomial(
+        counts = jax.random.multinomial(
             key, n, jnp.full((n,), 1.0 / n), shape=(n_replicates, n)
         ).astype(batch.weights.dtype)
 

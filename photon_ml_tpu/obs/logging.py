@@ -71,13 +71,17 @@ def configure(level: int = logging.INFO,
     call reuses the installed handler (so repeated driver invocations
     in one process don't duplicate lines)."""
     logger = logging.getLogger(logger_name)
-    logger.addFilter(_ensure_filter(logger))
+    stamp = _ensure_filter(logger)
+    logger.addFilter(stamp)
     for h in logger.handlers:
         if getattr(h, "_photon_obs_handler", False):
             break
     else:
         handler = logging.StreamHandler()
         handler.setFormatter(logging.Formatter(fmt))
+        # a logger's filter never sees records that propagate up from
+        # child loggers (photon_ml_tpu.serve...), the handler's does
+        handler.addFilter(stamp)
         handler._photon_obs_handler = True
         logger.addHandler(handler)
     logger.setLevel(level)

@@ -15,7 +15,9 @@ Why matmul: a [T, 128] tile's per-lane inclusive prefix is ``x @ L`` with
 strict-lower-triangular matmul of the per-row totals. Both hit the MXU with
 static shapes.
 
-Falls back to interpret mode off-TPU (CPU tests run the same kernel code).
+``lax.platform_dependent`` picks the compiled Mosaic kernel when the
+program is lowered for a TPU and interpret mode for any other platform (the
+CPU tests run the same kernel body).
 """
 
 from __future__ import annotations
@@ -25,7 +27,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.compat import VMA_TRANSPOSE, typeof
 from jax.experimental import pallas as pl
 
 _LANES = 128
@@ -62,16 +63,17 @@ def _mps_kernel(v_ref, d_ref, out_ref):
 
     # the tile total is the local prefix's last element; the wrapper slices
     # it out of this output, so the kernel has no second (scalar-shaped)
-    # output — the r05 chip session showed Mosaic pads an [n_tiles, 1]
-    # SMEM output window to 512 B/element, overflowing SMEM at bench-shape
-    # tile counts (docs/tpu_r05_logs/bench.log: u8[1277952] > 1 MB)
+    # output — Mosaic pads an [n_tiles, 1] SMEM output window to
+    # 512 B/element, overflowing SMEM at bench-shape tile counts
+    # (u8[1277952] > 1 MB; builder-measured on a v5e, 2026-07-31, not
+    # re-measured since)
     out_ref[:] = lane_cum + row_excl
 
 
 def _mps_call(v, d, n_tiles, block_rows, interpret):
     # under shard_map (manual mode) the output varies over the same mesh
     # axes as the inputs; plumb the vma through or check_vma rejects the call
-    vma = frozenset(getattr(typeof(v), "vma", frozenset()))
+    vma = frozenset(getattr(jax.typeof(v), "vma", frozenset()))
     def _shape(sh):
         return (jax.ShapeDtypeStruct(sh, v.dtype, vma=vma) if vma
                 else jax.ShapeDtypeStruct(sh, v.dtype))
@@ -104,10 +106,9 @@ def multiply_prefix_sum(
 
     ``interpret=None`` selects per LOWERING platform via
     ``lax.platform_dependent`` — the compiled Mosaic kernel for TPU,
-    interpret mode elsewhere. The old device-probe auto-detect picked
-    interpret mode whenever the CURRENT backend was CPU, which silently
-    exported interpreter HLO (not the kernel) when lowering for TPU from
-    a CPU host (jax.export / AOT)."""
+    interpret mode elsewhere — by the platform the program is lowered
+    for, not the current backend, so lowering for a TPU from a CPU host
+    (jax.export / AOT) gets the kernel."""
     nnz = values.shape[0]
     tile = block_rows * _LANES
     n_tiles = max(pl.cdiv(nnz, tile), 1)
@@ -117,22 +118,13 @@ def multiply_prefix_sum(
     d = jnp.pad(d_sorted, (0, pad)).reshape(-1, _LANES)
 
     if interpret is None:
-        if not VMA_TRANSPOSE:
-            # legacy jax lowers BOTH platform_dependent branches for the
-            # current platform, and the compiled-kernel branch hard-fails
-            # CPU lowering; fall back to the trace-time backend probe there
-            # (losing only the lower-for-TPU-from-CPU-host export case)
-            local = _mps_call(v, d, n_tiles, block_rows,
-                              interpret=jax.default_backend() != "tpu")
-        else:
-            local = jax.lax.platform_dependent(
-                v, d,
-                tpu=functools.partial(_mps_call, n_tiles=n_tiles,
-                                      block_rows=block_rows, interpret=False),
-                default=functools.partial(_mps_call, n_tiles=n_tiles,
-                                          block_rows=block_rows,
-                                          interpret=True),
-            )
+        local = jax.lax.platform_dependent(
+            v, d,
+            tpu=functools.partial(_mps_call, n_tiles=n_tiles,
+                                  block_rows=block_rows, interpret=False),
+            default=functools.partial(_mps_call, n_tiles=n_tiles,
+                                      block_rows=block_rows, interpret=True),
+        )
     else:
         local = _mps_call(v, d, n_tiles, block_rows, interpret)
     totals = local.reshape(n_tiles, -1)[:, -1]

@@ -17,8 +17,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.compat import typeof
-
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
@@ -210,8 +208,8 @@ def match_vma(x, ref):
     input/output types to match exactly, so optimizer loop state initialized
     from constants must be cast to the gradient's vma. Outside shard_map this
     is a no-op."""
-    vma = frozenset(getattr(typeof(ref), "vma", frozenset()))
-    cur = frozenset(getattr(typeof(x), "vma", frozenset()))
+    vma = frozenset(getattr(jax.typeof(ref), "vma", frozenset()))
+    cur = frozenset(getattr(jax.typeof(x), "vma", frozenset()))
     missing = tuple(sorted(vma - cur))
     if missing:
         x = jax.lax.pcast(x, missing, to="varying")

@@ -18,10 +18,9 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from photon_ml_tpu.compat import VMA_TRANSPOSE, shard_map
 from photon_ml_tpu.ops.losses import apply_weights, mask_margins
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
@@ -55,11 +54,8 @@ def distributed_value_and_grad(
         # value needs an explicit psum: under shard_map's varying-axis
         # tracking (check_vma), the AD transpose of "replicated w touches
         # sharded batch" inserts the gradient's all-reduce automatically —
-        # psumming g again would multiply it by the axis size. Legacy
-        # check_rep shard_map inserts nothing, so psum explicitly there.
+        # psumming g again would multiply it by the axis size.
         f, g = objective.value_and_grad(w, batch, 0.0)
-        if not VMA_TRANSPOSE:
-            g = lax.psum(g, axis)
         return lax.psum(f, axis), g
 
     def fg(w, batch, l2=0.0):
@@ -84,11 +80,9 @@ def distributed_hvp(objective: GLMObjective, mesh: Mesh, axis: str = "data") -> 
     )
     def shard_hvp(w, v, batch):
         # Like the gradient, the HVP's all-reduce is inserted by the AD
-        # transpose (w and v are replicated, batch varies over `axis`) —
-        # except on legacy check_rep shard_map, where it must be explicit.
+        # transpose (w and v are replicated, batch varies over `axis`).
         grad_data = lambda x: objective.grad(x, batch, 0.0)
-        hv = jax.jvp(grad_data, (w,), (v,))[1]
-        return hv if VMA_TRANSPOSE else lax.psum(hv, axis)
+        return jax.jvp(grad_data, (w,), (v,))[1]
 
     def hvp(w, v, batch, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
@@ -347,9 +341,10 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
 
 
 # Measured per-platform sparse-gradient defaults for "auto" (both
-# platforms calibrated — docs/PERF.md): the v5e r05 calibration at the
+# platforms calibrated — docs/PERF.md): the v5e calibration at the
 # bench shape ran {scatter 17.9s, csc 12.6s, csc_segment 27.2s,
-# csc_pallas 12.5s}/20 iters — the fused Mosaic kernel wins on TPU,
+# csc_pallas 12.5s}/20 iters (builder-measured on a v5e, 2026-07-31, not
+# re-measured since) — the fused Mosaic kernel wins on TPU,
 # while on CPU the XLA scatter-add is ~10x faster than the csc paths.
 _SPARSE_GRAD_DEFAULT = {"cpu": "scatter", "tpu": "csc_pallas"}
 _sparse_grad_warned: set = set()

@@ -181,7 +181,6 @@ class AsyncScoringServer:
         self._draining = True
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
         deadline = time.monotonic() + drain_timeout_s
         while self._conns and time.monotonic() < deadline:
             await asyncio.sleep(0.01)
@@ -190,6 +189,11 @@ class AsyncScoringServer:
             None, self.service.close, drain_timeout_s)
         for task in list(self._conns):
             task.cancel()
+        if self._server is not None:
+            # last: since Python 3.12 wait_closed() waits for every open
+            # connection, and an idle keep-alive one (a front door's
+            # pool) only goes away with the cancel above
+            await self._server.wait_closed()
 
     def run_forever(self, drain_timeout_s: float = 30.0,
                     ready_callback=None) -> int:

@@ -251,7 +251,7 @@ class _SimGroup:
                 return list(self.results[gen])
             while gen not in self.results:
                 # a declared-dead peer that never deposited can never
-                # complete this round: fail fast with the same taxonomy
+                # complete this round: fail fast with the same classification
                 # the watchdog would use, naming the dead ranks
                 dead_missing = sorted(self.deaths - set(slot))
                 if dead_missing:

@@ -27,9 +27,9 @@ from flax import struct
 # 1-D table gather for the sparse hot path.
 #
 # XLA:TPU lowers a word-granular gather (slice size 1) to a serial loop —
-# ~1 element/cycle. Measured on the v5e chip (docs/tpu_r05_logs/tpu_diag.log):
-# the 82M-element margin gather ran at ~1 GB/s, 0.1% of HBM peak, and the
-# whole L-BFGS iteration was 2x that gather. The fix is the standard TPU
+# ~1 element/cycle. Builder-measured on a v5e, 2026-07-31, not re-measured
+# since: the 82M-element margin gather ran at ~1 GB/s, 0.1% of HBM peak, and
+# the whole L-BFGS iteration was 2x that gather. The fix is the standard TPU
 # embedding-lookup shape: reshape the table to [d/128, 128] so each gathered
 # element is a full 128-lane row (a vectorizable (1,128)-slice gather), then
 # select the wanted lane with a one-hot multiply+reduce on the VPU. The sum
@@ -95,7 +95,7 @@ def table_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
     mode = _gather_mode
     if mode == "auto":
         # TPU only: the serial-gather pathology is a TPU lowering property
-        # (measured docs/tpu_r05_logs/tpu_diag.log); GPUs and CPUs gather
+        # (builder-measured on a v5e, 2026-07-31); GPUs and CPUs gather
         # words natively and would only pay the [m, 128] expansion
         mode = "vector" if jax.default_backend() == "tpu" else "scalar"
     if (mode == "scalar" or table.ndim != 1

@@ -75,7 +75,7 @@ def test_entity_sharded_device_put_layout_roundtrip():
 def test_entity_axis_shard_map_sum_matches_host():
     """A no-collective shard_map over the entity axis (the bucket-solver
     pattern) computes the same per-entity results as the host."""
-    from photon_ml_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh({"entity": 8})
     x = np.arange(32.0).reshape(8, 4)

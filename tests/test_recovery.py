@@ -147,7 +147,7 @@ def test_retry_transient_deadline_abandons_the_next_sleep():
 
 
 # -- failure classification -------------------------------------------------
-def test_classify_failure_taxonomy():
+def test_classify_failure_classes():
     assert classify_failure(
         WatchdogTimeout("gone", tag="t", failed={2: CODE_ERROR})) == RANK_LOSS
     assert classify_failure(

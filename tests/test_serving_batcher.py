@@ -131,7 +131,7 @@ def test_watchdog_fails_stuck_batch_and_worker_survives():
         stuck = b.submit(_rows(-1.0))
         with pytest.raises(BatchWatchdogTimeout, match="watchdog"):
             stuck.result(10.0)
-        assert isinstance(stuck._error, WatchdogTimeout)  # PR-1 taxonomy
+        assert isinstance(stuck._error, WatchdogTimeout)  # PR-1 failure class
         # the worker abandoned the wedged execution and keeps serving
         assert b.score(_rows(5.0), timeout=10.0)[0] == 5.0
     finally:

@@ -357,6 +357,10 @@ class TestFrontDoorChaos:
                 except (asyncio.IncompleteReadError, ConnectionError,
                         asyncio.CancelledError):
                     pass
+                finally:
+                    # since Python 3.12 Server.wait_closed() waits for
+                    # every connection's transport to be closed
+                    writer.close()
 
             import functools
             slow = await asyncio.start_server(

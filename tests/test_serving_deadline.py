@@ -395,6 +395,10 @@ class TestWarmingProbe:
                         await writer.drain()
                 except (asyncio.IncompleteReadError, ConnectionError):
                     pass
+                finally:
+                    # since Python 3.12 Server.wait_closed() waits for
+                    # every connection's transport to be closed
+                    writer.close()
 
             server = await asyncio.start_server(fake_backend,
                                                 "127.0.0.1", 0)

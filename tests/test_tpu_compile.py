@@ -1,0 +1,193 @@
+"""Real TPU compiles of the main path's programs, without a chip.
+
+``tests/test_tpu_lowering.py`` stops at ``jax.export`` lowering. These go
+the rest of the way: the installed TPU compiler compiles each program for
+a *described* v5e 2x2 (``jax.experimental.topologies``), at the shapes
+``chip_smoke.py`` runs, so a kernel the chip's compiler refuses (tiling,
+VMEM, a program that does not fit HBM) fails here at no chip time.
+Nothing executes: these say nothing about results or times.
+
+The topology is described inside a module-scoped fixture — never at
+import — and every compile runs in the test's own process: only one
+process may hold libtpu, and under xdist every worker imports this file.
+Code that asks ``jax.default_backend()`` sees the CPU here, so the
+chip's choices (``csc_pallas``, the vector gather, ``newton``) are passed
+explicitly.
+"""
+
+import contextlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from photon_ml_tpu.ops.objective import make_objective
+from photon_ml_tpu.optimize import OptimizerConfig
+from photon_ml_tpu.parallel.mesh import make_mesh
+from photon_ml_tpu.types import LabeledBatch, SparseFeatures
+
+# chip_smoke.py's glm phase: Criteo-shaped hashed features, explicit values
+# (what the glm driver builds from a LIBSVM file)
+ROWS, DIM, K = 1 << 17, 1 << 18, 39
+f32, i32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / already held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def chip_settings():
+    """What the program sees on the chip: f32 (conftest turns x64 on for
+    the parity tests; Mosaic has no i64) and the gather mode that "auto"
+    resolves to there."""
+    from photon_ml_tpu import types as T
+
+    prev = T.gather_mode()
+    T.set_gather_mode("vector")
+    try:
+        with jax.enable_x64(False):
+            yield
+    finally:
+        T.set_gather_mode(prev)
+
+
+@pytest.fixture(autouse=True)
+def _chip_settings():
+    with chip_settings():
+        yield
+
+
+def _compile_fit(mesh):
+    """Compile one whole csc_pallas L-BFGS fit for ``mesh`` from shapes."""
+    from photon_ml_tpu.parallel.data_parallel import fit_distributed
+
+    obj = make_objective("logistic")
+    cfg = OptimizerConfig(max_iters=20, tolerance=1e-7)
+
+    def fit(w0, indices, values, labels, offsets, weights):
+        batch = LabeledBatch(SparseFeatures(indices, values, dim=DIM),
+                             labels, offsets, weights)
+        r = fit_distributed(obj, batch, mesh, w0, l2=1.0, config=cfg,
+                            optimizer="lbfgs", sparse_grad="csc_pallas")
+        return r.w, r.value
+
+    rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    s = jax.ShapeDtypeStruct
+    row = s((ROWS,), f32, sharding=rows)
+    return jax.jit(fit).lower(
+        s((DIM,), f32, sharding=rep), s((ROWS, K), i32, sharding=rows),
+        s((ROWS, K), f32, sharding=rows), row, row, row).compile()
+
+
+@pytest.mark.parametrize("rows", [1 << 17, 1 << 21])
+def test_multiply_prefix_sum_compiles(one_chip, rows):
+    from photon_ml_tpu.ops.pallas_kernels import multiply_prefix_sum
+
+    v = jax.ShapeDtypeStruct((rows * K,), f32, sharding=one_chip)
+    compiled = multiply_prefix_sum.lower(v, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def one_chip_fit(topo):
+    with chip_settings():  # module scope: set up before the autouse one
+        return _compile_fit(make_mesh({"data": 1}, devices=topo.devices[:1]))
+
+
+def test_glm_fit_compiles_on_one_chip(one_chip_fit):
+    compiled = one_chip_fit
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(mem)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_glm_fit_compiles_on_four_chips(topo, one_chip_fit):
+    one = one_chip_fit
+    four = _compile_fit(make_mesh({"data": 4}, devices=topo.devices))
+    text = four.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    # rows are laid out over the four chips: only w0 is replicated
+    ratio = (four.memory_analysis().argument_size_in_bytes
+             / one.memory_analysis().argument_size_in_bytes)
+    assert 0.24 < ratio < 0.30, ratio
+
+
+def test_newton_re_solver_compiles_at_one_block(topo):
+    """One real block of the batched dense-Newton solver: the widest
+    program the GAME phase can hand the batched-Cholesky compile."""
+    from photon_ml_tpu.game.random_effect import (
+        _RE_BLOCK_ENTITIES,
+        _jitted_sharded_solver,
+    )
+
+    E, D_loc, rows = _RE_BLOCK_ENTITIES, 32, 64
+    mesh = make_mesh({"entity": 1}, devices=topo.devices[:1])
+    run = _jitted_sharded_solver(
+        D_loc, "logistic", "newton",
+        OptimizerConfig(max_iters=30, tolerance=1e-6),
+        False, mesh, "entity", 0)
+    ent = NamedSharding(mesh, P("entity"))
+    rep = NamedSharding(mesh, P())
+
+    def s(shape, dtype=f32, sharding=ent):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    compiled = run.lower(
+        s((E, rows, D_loc), i32), s((E, rows, D_loc)),
+        s((E, rows)), s((E, rows)), s((E, rows)), s((E, D_loc)),
+        s((E, 1)), s((E, 1)), s((), sharding=rep),
+        s((), sharding=rep)).compile()
+    mem = compiled.memory_analysis()
+    print(mem)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_serving_fused_score_compiles(one_chip):
+    """Serving's one-call score at the serving driver's defaults
+    (--re-pages 4 --re-page-rows 256 --pad-nnz 64, row bucket 256): a
+    fixed-effect margin plus one paged random-effect gather."""
+    from photon_ml_tpu.ops.pallas_kernels import paged_gather_score
+    from photon_ml_tpu.types import margins
+
+    B, k, slots, d_fixed, d_re = 256, 64, 4 * 256, DIM, 4096
+
+    def score(offsets, idx_f, val_f, w, idx_r, val_r, table, slot):
+        m = margins(SparseFeatures(idx_f, val_f, dim=d_fixed), w)
+        return offsets + m + paged_gather_score(table, slot, idx_r, val_r)
+
+    def s(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(score).lower(
+        s((B,)), s((B, k), i32), s((B, k)), s((d_fixed,)),
+        s((B, k), i32), s((B, k)), s((slots, d_re)),
+        s((B,), i32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9

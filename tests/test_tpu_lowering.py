@@ -4,11 +4,12 @@
 Pallas->Mosaic) lowering for the TPU target from a CPU host — the layer
 interpret-mode execution parity can never exercise. Round 4 this caught
 two chip-blocking kernel bugs (docs/PERF.md "Round-4 Mosaic lowering"),
-so every distributed hot-path program is pinned here: a live chip session
-must start at "compile", not "debug the lowering" (VERDICT r3 #4).
+so every distributed hot-path program is pinned here: a chip run must
+start at "compile", not "debug the lowering" (VERDICT r3 #4).
 
-These certify LOWERING only; Mosaic's compile to LLO and the numerics
-still need the chip (scripts/tpu_session.sh).
+These certify LOWERING only; the TPU compiler's own pass over the main
+path's programs is tests/test_tpu_compile.py, and the numerics need the
+chip (chip_smoke.py).
 """
 
 import jax

@@ -1,8 +1,8 @@
 """Vectorized 1-D table gather (``types.table_gather``).
 
-The TPU chip session measured XLA's word-granular gather at ~1 GB/s
-(docs/tpu_r05_logs/tpu_diag.log) — a serial lowering that bounded the
-whole fit. ``table_gather`` replaces it with a (1,128)-slice row gather
+XLA's word-granular gather ran at ~1 GB/s on the chip (builder-measured
+on a v5e, 2026-07-31, not re-measured since) — a serial lowering that
+bounded the whole fit. ``table_gather`` replaces it with a (1,128)-slice row gather
 plus a one-hot lane select, which is bit-identical arithmetic (one real
 value + 127 exact zeros per output element). These tests pin that
 bit-identity on every path (direct, chunked, values/implicit-ones, and
