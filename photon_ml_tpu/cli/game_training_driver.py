@@ -689,7 +689,7 @@ def _run(args) -> int:
         for rec in result.history:
             logger.log("cd_iteration", config=gi, **rec)
 
-    from photon_ml_tpu.utils import profile_trace
+    from photon_ml_tpu.obs.trace import profile
 
     # Device-loss recovery (SURVEY §5.3): a TPU worker crash surfaces as
     # JaxRuntimeError("UNAVAILABLE ...") and the dead backend cannot be
@@ -701,7 +701,7 @@ def _run(args) -> int:
     # command with --auto-resume, which adopts that checkpoint as the
     # warm start. --auto-resume consumed the marker above.
     try:
-        with Timed(logger, "training"), profile_trace(args.profile_dir):
+        with Timed(logger, "training"), profile(args.profile_dir):
             results = estimator.fit(
                 train, validation, config_grid=grid, warm_start=warm,
                 locked=args.locked_coordinates, checkpoint_callback=ckpt,

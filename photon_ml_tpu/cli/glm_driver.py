@@ -482,7 +482,7 @@ def _run(args) -> int:
     # -- stage: train over the lambda grid with warm start -------------------
     results = []
     w = jnp.zeros((dim,), dtype)
-    from photon_ml_tpu.utils import profile_trace
+    from photon_ml_tpu.obs.trace import profile
 
     # Device-loss recovery over the lambda grid (same contract as the
     # GAME driver's RESUME marker, but lambda-granular: every finished
@@ -606,7 +606,7 @@ def _run(args) -> int:
             path_solver.seed_state(lam_done, np.asarray(res_done.w))
 
     try:
-        with Timed(logger, "training"), profile_trace(args.profile_dir):
+        with Timed(logger, "training"), profile(args.profile_dir):
             start_idx = len(results)
             for li, lam in enumerate(args.reg_weights[start_idx:],
                                      start=start_idx):
@@ -691,6 +691,12 @@ def _run(args) -> int:
                         if np.isfinite(v)
                     ],
                 }
+                if res.gather_products is not None:
+                    # what the passes cost: the X v and X^T d products the
+                    # optimizer ran (a line search or CG step adds to
+                    # these, not to `iterations`)
+                    diag["gather_products"] = int(res.gather_products)
+                    diag["transpose_products"] = int(res.transpose_products)
                 if res.stream_stats is not None:
                     # streamed fits: decode-wait / transfer / compute-stall
                     # seconds for this lambda's whole pass sequence

@@ -18,6 +18,7 @@ of float ops, and the handler threads + batcher worker all write here.
 
 from __future__ import annotations
 
+import collections
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -91,11 +92,12 @@ class Histogram:
         return self.bounds[-1] if self.bounds else float("inf")
 
     def render(self, name: str, out: List[str],
-               labels: str = "") -> None:
+               labels: str = "", type_line: bool = True) -> None:
         """Emit the cumulative bucket series. ``labels`` is a pre-
         rendered ``k="v",…`` fragment (empty for the unlabeled form —
-        which keeps the serving render byte-identical)."""
-        if not labels:
+        which keeps the serving render byte-identical). A registry
+        series writes its own ``# TYPE`` line (``type_line=False``)."""
+        if type_line and not labels:
             out.append(f"# TYPE {name} histogram")
         cum = 0
         sep = "," if labels else ""
@@ -163,7 +165,7 @@ class _Series:
         for key, val in self.values.items():
             labels = _label_str(key)
             if self.kind == "histogram":
-                val.render(self.name, out, labels)
+                val.render(self.name, out, labels, type_line=False)
             elif labels:
                 out.append(f"{self.name}{{{labels}}} {_fmt(val)}")
             else:
@@ -243,8 +245,18 @@ class TrainingMetrics:
       prefetch_{stall,decode,transfer}_seconds_total — the streamed-pass
         pipeline accounting (``StreamStats``) as counters;
       exchange_{bytes_sent,bytes_gathered,rounds}_total /
-        exchange_seconds_total — cross-shard score-delta traffic.
+        exchange_seconds_total — cross-shard score-delta traffic;
+      fit_total / fit_dispatch_seconds — ``fit_distributed`` calls and the
+        host's time inside each before the device has the program;
+      fit_passes_total / fit_products_total{kind=gather|transpose} — the
+        optimizer iterations and the ``X v`` / ``X^T d`` products those
+        fits ran, as their programs counted them. A fit's counters stay
+        device scalars in its record (:meth:`record_fit`) until a read
+        (:meth:`fit_records`, :meth:`snapshot`, :meth:`render`) or until
+        the record leaves the ring of ``FIT_RECORDS``.
     """
+
+    FIT_RECORDS = 64
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -298,6 +310,20 @@ class TrainingMetrics:
             "photon_train_path_fallback_total",
             "lambdas that exhausted the KKT repair budget and fell back "
             "to a full-width solve")
+        self._fits = r.counter("photon_train_fit_total",
+                               "fit_distributed calls")
+        self._fit_passes = r.counter(
+            "photon_train_fit_passes_total",
+            "optimizer iterations of those fits, counted on the device")
+        self._fit_products = r.counter(
+            "photon_train_fit_products_total",
+            "data products of those fits: X v (gather), X^T d (transpose)")
+        self._fit_dispatch = r.histogram(
+            "photon_train_fit_dispatch_seconds",
+            "host seconds inside fit_distributed, entry to dispatch",
+            bounds=DEFAULT_SECONDS_BUCKETS)
+        self._fit_lock = threading.Lock()
+        self._fit_ring: collections.deque = collections.deque()
 
     def record_step(self, coordinate: str, solve_s: float, eval_s: float,
                     comm_s: float) -> None:
@@ -334,10 +360,67 @@ class TrainingMetrics:
         self._rounds.inc(1)
         self._exch_s.inc(seconds)
 
+    def record_fit(self, *, optimizer: str, sparse_grad: str,
+                   compiled: bool, dispatch_s: float, result) -> None:
+        """One ``fit_distributed`` call, on its return. ``result``'s
+        ``iterations`` / ``gather_products`` / ``transpose_products`` are
+        kept as they are — device scalars of a fit that may still be
+        running — and fetched only when the record is read, never on the
+        fit's path; only a record pushed out of the ring (a fit
+        ``FIT_RECORDS`` calls back) is fetched here, to be counted."""
+        rec = {"optimizer": optimizer, "sparse_grad": sparse_grad,
+               "compiled": bool(compiled), "dispatch_s": float(dispatch_s),
+               "iterations": result.iterations,
+               "gather_products": result.gather_products,
+               "transpose_products": result.transpose_products,
+               "counted": False}
+        evicted = None
+        with self._fit_lock:
+            self._fits.inc(1)
+            self._fit_dispatch.observe(rec["dispatch_s"])
+            self._fit_ring.append(rec)
+            if len(self._fit_ring) > self.FIT_RECORDS:
+                evicted = self._fit_ring.popleft()
+        if evicted is not None:
+            self._count_fit(evicted)
+
+    def _count_fit(self, rec: dict) -> None:
+        """Fetch a record's device scalars (once) and add them to the
+        series. The fetch waits for the fit if it still runs, so it is
+        made outside the lock ``record_fit`` takes."""
+        if rec["counted"]:
+            return
+        fields = ("iterations", "gather_products", "transpose_products")
+        fetched = {f: None if rec[f] is None else int(rec[f]) for f in fields}
+        with self._fit_lock:
+            if rec["counted"]:
+                return
+            rec.update(fetched, counted=True)
+            self._fit_passes.inc(fetched["iterations"] or 0)
+            self._fit_products.inc(fetched["gather_products"] or 0,
+                                   kind="gather")
+            self._fit_products.inc(fetched["transpose_products"] or 0,
+                                   kind="transpose")
+
+    def _count_fits(self) -> List[dict]:
+        with self._fit_lock:
+            ring = list(self._fit_ring)
+        for rec in ring:
+            self._count_fit(rec)
+        return ring
+
+    def fit_records(self) -> List[dict]:
+        """The last ``FIT_RECORDS`` fits, oldest first, counters fetched
+        (``None`` where the optimizer counts none)."""
+        return [{k: v for k, v in rec.items() if k != "counted"}
+                for rec in self._count_fits()]
+
     def render(self) -> str:
+        self._count_fits()
         return self.registry.render()
 
     def snapshot(self) -> Dict[str, dict]:
+        self._count_fits()
         return self.registry.snapshot()
 
 

@@ -4,11 +4,13 @@ Design constraints, in priority order:
 
 1. **Off means off.** Every hot path calls ``span(...)`` unconditionally
    (descent sweeps, the streamed-pass ring, the batcher worker, the
-   fused scoring dispatch). With no tracer installed the call is one
-   module-global load, one ``is None`` test, and the return of a shared
-   immutable null context manager — no allocation, no lock, no clock
-   read. ``bench.py trace`` gates this (< 2% on the streamed-fit and
-   serving closed-loop legs, ``BENCH_trace.json``).
+   fused scoring dispatch, ``fit_distributed``). With no tracer installed
+   and no profiler session live the call is one module-global load, one
+   ``is None`` test, one ``TraceAnnotation.is_enabled()`` (a C call that
+   reads a flag: ~20-30 ns) and the return of a shared immutable null
+   context manager — no allocation, no lock, no clock read. ``bench.py
+   trace`` gates this (< 2% on the streamed-fit and serving closed-loop
+   legs, ``BENCH_trace.json``).
 2. **Context is explicit at thread handoffs.** A span's trace-id and
    request-id live in a :class:`TraceContext` carried in a
    ``contextvars.ContextVar`` — ambient per thread AND per asyncio
@@ -33,6 +35,14 @@ Design constraints, in priority order:
    a complete ``trace-rank{r}.json`` per rank via write-temp +
    ``os.replace``, the registry's atomic-publish idiom. A killed
    process leaves the last complete flush, never a torn file.
+
+5. **One clock with the device.** While a JAX profiler session is live
+   (:func:`profile`, a driver's ``--profile-dir``, the benchmark's
+   ``--trace 1``) every span is also a ``jax.profiler.TraceAnnotation``
+   of the same name and args, with or without a photon tracer: it lands
+   in the ``.xplane.pb`` on the host's thread line beside the device's
+   lines, so a device gap can be laid against the span that covers it.
+   Outside a session that costs the ``is_enabled()`` call above.
 
 Sampling: ``PHOTON_TRACE_SAMPLE`` (or ``start(sample=…)``) decides at
 trace-root creation whether the whole trace records — a sampled-out
@@ -68,14 +78,19 @@ import time
 import uuid
 from typing import Dict, Iterator, Optional
 
+import jax.profiler
+from jax.profiler import TraceAnnotation
+
 from photon_ml_tpu.io.durable import durable_replace
 
 __all__ = [
     "TraceContext", "Tracer", "current_context", "use_context",
     "span", "start", "stop", "enabled", "active_tracer",
     "maybe_start_from_env", "new_request_id", "current_request_id",
-    "request_context",
+    "request_context", "profile",
 ]
+
+_profiling = TraceAnnotation.is_enabled  # a profiler session is live
 
 # Shared clock origin: one value per process, taken at import. In the
 # simulated harness every rank is a thread of this process, so per-rank
@@ -179,6 +194,15 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(TraceAnnotation):
+    """The span of a run that is profiled but has no photon tracer: the
+    annotation alone, with the span's ``set``."""
+
+    def set(self, **kwargs):
+        self.set_metadata(**kwargs)
+        return self
+
+
 def _rank() -> int:
     try:
         from photon_ml_tpu.parallel.resilience import current_process_index
@@ -188,7 +212,8 @@ def _rank() -> int:
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_owns_ctx")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_owns_ctx",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -197,6 +222,7 @@ class _Span:
         self.args = args
         self._t0 = 0.0
         self._owns_ctx = None  # a _CTX reset token when this span roots
+        self._annotation = None  # the span in a live profiler session
 
     def set(self, **kwargs) -> "_Span":
         """Attach args discovered mid-span (batch size, fault count)."""
@@ -209,11 +235,16 @@ class _Span:
             ctx = TraceContext(sampled=self._tracer.sample_decision())
             # keep the reset token so __exit__ restores the outer state
             self._owns_ctx = _CTX.set(ctx)
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name, **self.args)
+            self._annotation.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         ctx = _CTX.get()
         if self._owns_ctx is not None:
             _CTX.reset(self._owns_ctx)
@@ -348,10 +379,12 @@ def enabled() -> bool:
 def span(name: str, cat: str = "app", **args):
     """The one instrumentation entry point. Disabled: returns the shared
     null span (no allocation). Enabled: a recording span whose trace
-    context comes from — or is installed into — the calling thread."""
+    context comes from — or is installed into — the calling thread. In a
+    live profiler session either one is also an annotation in the
+    profiler's own trace."""
     t = _TRACER
     if t is None:
-        return _NULL_SPAN
+        return _ProfilerSpan(name, **args) if _profiling() else _NULL_SPAN
     return t.span(name, cat, args)
 
 
@@ -386,6 +419,16 @@ def stop(timeout_s: float = 5.0) -> None:
     _TRACER = None  # flip the off switch before the (slow) join
     if t is not None:
         t.stop(timeout_s)
+
+
+def profile(trace_dir: Optional[str]):
+    """A JAX profiler session into ``trace_dir`` (no-op when None), as a
+    context manager: the one place the program starts one (the drivers'
+    ``--profile-dir``). Load the result in TensorBoard/Perfetto, or read
+    it by scope with ``photon-trace kernels``."""
+    if not trace_dir:
+        return contextlib.nullcontext()
+    return jax.profiler.trace(trace_dir)
 
 
 def maybe_start_from_env() -> Optional[Tracer]:

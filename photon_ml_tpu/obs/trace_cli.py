@@ -1,4 +1,5 @@
-"""``photon-trace``: merge, validate, and smoke-test per-rank traces.
+"""``photon-trace``: merge, validate, and smoke-test per-rank traces, and
+read a device trace by scope.
 
 ``merge``: combine ``trace-rank*.json`` files (one per process, written
 by :mod:`photon_ml_tpu.obs.trace`) into a single Perfetto-loadable
@@ -22,6 +23,11 @@ whose events carry name/ph/pid/tid and numeric ts (plus dur for
 through the real tracer and the real sharded exchange, merge it,
 validate the merged file. Exercises exactly the path the training
 driver uses, without touching jax-compiled code.
+
+``kernels``: the JAX profiler's device trace (a ``--profile-dir``, or one
+``.xplane.pb``) by the program's ``photon.*`` scopes: device seconds,
+share of busy time, executions, bytes accessed per second and the
+instruction names that carried each scope (:mod:`photon_ml_tpu.obs.xplane`).
 """
 
 from __future__ import annotations
@@ -234,10 +240,22 @@ def _cmd_smoke(args) -> int:
     return 0
 
 
+def _cmd_kernels(args) -> int:
+    from photon_ml_tpu.obs import xplane
+
+    table = xplane.kernel_table(args.trace)
+    if args.json:
+        print(json.dumps(table))
+    else:
+        print(xplane.format_table(table, instructions=args.instructions))
+    return 0 if table["busy_s"] > 0 else 1
+
+
 def build_arg_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="photon-trace",
-        description="merge / validate / smoke-test photon trace files")
+        description="merge / validate / smoke-test photon trace files; "
+                    "read a device trace by scope")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     m = sub.add_parser("merge", help="merge per-rank trace files")
@@ -259,6 +277,17 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="keep the smoke trace files here (default: "
                         "a temp dir)")
     s.set_defaults(fn=_cmd_smoke)
+
+    k = sub.add_parser("kernels",
+                       help="device time by photon.* scope, from a "
+                            "profiler trace")
+    k.add_argument("trace", help="a profile directory, or one .xplane.pb "
+                                 "(or its .gz)")
+    k.add_argument("--instructions", type=int, default=4,
+                   help="instruction names shown per scope")
+    k.add_argument("--json", action="store_true",
+                   help="print the table as one JSON object")
+    k.set_defaults(fn=_cmd_kernels)
     return p
 
 

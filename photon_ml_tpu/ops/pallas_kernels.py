@@ -87,10 +87,12 @@ def _mps_call(v, d, n_tiles, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0)),
         out_shape=_shape(v.shape),
         interpret=interpret,
+        name="photon_multiply_prefix_sum",
     )(v, d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@jax.named_scope("photon.csc/prefix_sum")
 def multiply_prefix_sum(
     values: jax.Array,
     d_sorted: jax.Array,

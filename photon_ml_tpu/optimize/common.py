@@ -151,6 +151,14 @@ class OptimizationResult(NamedTuple):
     # the geometry, not just the outcome.
     solver_tolerance: "float | None" = None
     screened_dim: "int | None" = None
+    # Data products the fit ran, counted inside the optimizer's
+    # ``while_loop`` (i32 scalars): every ``X v`` (margins of a point or
+    # of a direction, the first half of an HVP) and every ``X^T d`` (a
+    # data gradient, the second half of an HVP; not the Jacobi diagonal).
+    # None for the host-driven streamed fits (``stream_stats`` counts
+    # their passes).
+    gather_products: "jax.Array | None" = None
+    transpose_products: "jax.Array | None" = None
 
 
 def converged_check(f_prev, f, g_norm, g0_norm, tol, f_scale=None):

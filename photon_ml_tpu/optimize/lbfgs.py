@@ -42,8 +42,10 @@ class _State(NamedTuple):
     stalled: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
+    n_evals: jax.Array  # i32: fun_and_grad evaluations (one X v + X^T d each)
 
 
+@jax.named_scope("photon.lbfgs/two_loop")
 def two_loop_direction(g, s_hist, y_hist, rho, k, m):
     """Two-loop recursion over a circular buffer; slot (k-1-i) mod m is the
     i-th most recent pair, masked out when i >= min(k, m)."""
@@ -93,6 +95,7 @@ def lbfgs(
     g0_norm = l2_norm(g0)
     loss_hist, gnorm_hist = init_history(config.max_iters, f0.dtype)
 
+    @jax.named_scope("photon.lbfgs/update")
     def body(s: _State) -> _State:
         p = two_loop_direction(s.g, s.s_hist, s.y_hist, s.rho, s.k, m)
         # ensure descent; fall back to steepest descent if the metric degraded
@@ -150,6 +153,7 @@ def lbfgs(
             conv, stalled,
             s.loss_hist.at[s.it].set(f_out),
             s.gnorm_hist.at[s.it].set(l2_norm(g_out)),
+            s.n_evals + ls.n_evals.astype(jnp.int32),
         )
 
     def cond(s: _State):
@@ -161,9 +165,11 @@ def lbfgs(
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
+        n_evals=jnp.asarray(1, jnp.int32),  # (f0, g0)
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     return OptimizationResult(
         w=s.w, value=s.f, grad_norm=l2_norm(s.g), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
+        gather_products=s.n_evals, transpose_products=s.n_evals,
     )

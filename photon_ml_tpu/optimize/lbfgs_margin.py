@@ -62,6 +62,7 @@ class _State(NamedTuple):
     stalled: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
+    n_passes: jax.Array  # i32: gather + transpose pairs (the search adds none)
 
 
 def lbfgs_margin(
@@ -107,6 +108,7 @@ def lbfgs_margin(
     g0_norm = l2_norm(g0)
     loss_hist, gnorm_hist = init_history(config.max_iters, f0.dtype)
 
+    @jax.named_scope("photon.lbfgs/update")
     def body(s: _State) -> _State:
         p = two_loop_direction(s.g, s.s_hist, s.y_hist, s.rho, s.k, m)
         dg = jnp.sum(p * s.g)
@@ -203,6 +205,7 @@ def lbfgs_margin(
             conv, stalled,
             s.loss_hist.at[s.it].set(f_new),
             s.gnorm_hist.at[s.it].set(gnorm),
+            s.n_passes + 1,  # dir_margin(p) and full_g(mw_new, w_new)
         )
 
     def cond(s: _State):
@@ -214,10 +217,12 @@ def lbfgs_margin(
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
+        n_passes=jnp.asarray(1, jnp.int32),  # the caller's m0, and g0
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     return OptimizationResult(
         w=s.w, value=s.f, grad_norm=l2_norm(s.g), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist,
         grad_norm_history=s.gnorm_hist,
+        gather_products=s.n_passes, transpose_products=s.n_passes,
     )

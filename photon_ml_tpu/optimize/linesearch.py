@@ -47,6 +47,7 @@ class _State(NamedTuple):
     ok: jax.Array
 
 
+@jax.named_scope("photon.lbfgs/line_search")
 def strong_wolfe(
     fun_and_grad: Callable,
     w: jax.Array,
@@ -163,6 +164,7 @@ def strong_wolfe(
     return LineSearchResult(alpha, f, g, s.i, (finished | took_step) & ~bad_direction)
 
 
+@jax.named_scope("photon.lbfgs/line_search")
 def backtracking(
     fun: Callable,
     w: jax.Array,

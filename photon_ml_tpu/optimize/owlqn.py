@@ -51,6 +51,8 @@ class _State(NamedTuple):
     stalled: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
+    n_gather: jax.Array  # i32: every evaluation gathers its margins
+    n_transpose: jax.Array  # i32: only an accepted point's gradient transposes
 
 
 def owlqn(
@@ -77,6 +79,7 @@ def owlqn(
     pg0_norm = l2_norm(pseudo_gradient(w0, g0, lam))
     loss_hist, gnorm_hist = init_history(config.max_iters, F0.dtype)
 
+    @jax.named_scope("photon.lbfgs/update")
     def body(s: _State) -> _State:
         pg = pseudo_gradient(s.w, s.g, lam)
         p = two_loop_direction(pg, s.s_hist, s.y_hist, s.rho, s.k, m)
@@ -91,7 +94,7 @@ def owlqn(
             return jnp.where(w_trial * xi > 0, w_trial, 0.0)
 
         alpha0 = jnp.where(s.k > 0, 1.0, 1.0 / jnp.maximum(l2_norm(pg), 1.0))
-        w_new, F_new, _, ok = backtracking(
+        w_new, F_new, n_trials, ok = backtracking(
             full_value, s.w, p, s.F, pg, alpha0=alpha0,
             max_evals=config.max_line_search_steps, project=project,
         )
@@ -112,6 +115,10 @@ def owlqn(
             s_hist, y_hist, rho, conv, ~ok,
             s.loss_hist.at[s.it].set(F_new),
             s.gnorm_hist.at[s.it].set(pg_new_norm),
+            # a trial reads the value alone (its gradient is dead code);
+            # the accepted point is evaluated once more for its gradient
+            s.n_gather + n_trials.astype(jnp.int32) + 1,
+            s.n_transpose + 1,
         )
 
     def cond(s: _State):
@@ -123,10 +130,13 @@ def owlqn(
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
+        n_gather=jnp.asarray(1, jnp.int32),  # (f0, g0)
+        n_transpose=jnp.asarray(1, jnp.int32),
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     final_pg = pseudo_gradient(s.w, s.g, lam)
     return OptimizationResult(
         w=s.w, value=s.F, grad_norm=l2_norm(final_pg), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
+        gather_products=s.n_gather, transpose_products=s.n_transpose,
     )

@@ -39,6 +39,7 @@ class _CGState(NamedTuple):
     done: jax.Array
 
 
+@jax.named_scope("photon.tron/cg")
 def _steihaug_cg(hvp: Callable, g: jax.Array, delta, cg_tol, max_cg: int,
                  m_diag: jax.Array | None = None):
     """Approximately minimize q(s) = g.s + 0.5 s.H.s within a trust region.
@@ -111,6 +112,7 @@ class _State(NamedTuple):
     stalled: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
+    n_products: jax.Array  # i32: evaluations + HVPs (one X v + X^T d each)
 
 
 def tron(
@@ -133,6 +135,9 @@ def tron(
         def hvp(w, v):
             return jax.jvp(grad_only, (w,), (v,))[1]
 
+    hvp = jax.named_scope("photon.tron/hvp")(hvp)
+    if precond is not None:
+        precond = jax.named_scope("photon.tron/precond")(precond)
     max_cg = max_cg_iters if max_cg_iters is not None else max(w0.shape[0], 20)
     f0, g0 = fun_and_grad(w0)
     g0_norm = l2_norm(g0)
@@ -143,10 +148,11 @@ def tron(
         return jnp.maximum(md, jnp.finfo(dtype).eps
                            * jnp.maximum(jnp.max(md), 1.0))
 
+    @jax.named_scope("photon.tron/update")
     def body(s: _State) -> _State:
         cg_tol = 0.1 * l2_norm(s.g)
         m_diag = s.m_diag if precond is not None else None
-        step, r, _ = _steihaug_cg(lambda v: hvp(s.w, v), s.g, s.delta,
+        step, r, n_cg = _steihaug_cg(lambda v: hvp(s.w, v), s.g, s.delta,
                                   cg_tol, max_cg, m_diag=m_diag)
         w_try = s.w + step
         f_try, g_try = fun_and_grad(w_try)
@@ -198,6 +204,9 @@ def tron(
             s.it + 1, w_new, f_new, g_new, delta, m_new, conv, stalled,
             s.loss_hist.at[s.it].set(f_new),
             s.gnorm_hist.at[s.it].set(gnorm),
+            # one HVP a CG step and the trial point's (f, g); the Jacobi
+            # diagonal is a scatter-add of its own, not a product
+            s.n_products + n_cg.astype(jnp.int32) + 1,
         )
 
     def cond(s: _State):
@@ -210,9 +219,11 @@ def tron(
         delta=g0_norm, m_diag=m0,
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
+        n_products=jnp.asarray(1, jnp.int32),  # (f0, g0)
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     return OptimizationResult(
         w=s.w, value=s.f, grad_norm=l2_norm(s.g), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
+        gather_products=s.n_products, transpose_products=s.n_products,
     )

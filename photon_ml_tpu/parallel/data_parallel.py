@@ -13,6 +13,7 @@ the loop. The entire optimizer (L-BFGS/TRON/OWL-QN ``while_loop``) jits
 from __future__ import annotations
 
 import functools
+import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
@@ -21,6 +22,8 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from photon_ml_tpu.obs import metrics as obs_metrics
+from photon_ml_tpu.obs import trace as obs_trace
 from photon_ml_tpu.ops.losses import apply_weights, mask_margins
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
@@ -55,16 +58,23 @@ def distributed_value_and_grad(
         # tracking (check_vma), the AD transpose of "replicated w touches
         # sharded batch" inserts the gradient's all-reduce automatically —
         # psumming g again would multiply it by the axis size.
-        f, g = objective.value_and_grad(w, batch, 0.0)
-        return lax.psum(f, axis), g
+        with jax.named_scope("photon.glm/loss"):
+            f, g = objective.value_and_grad(w, batch, 0.0)
+        with jax.named_scope("photon.allreduce/value"):
+            return lax.psum(f, axis), g
 
     def fg(w, batch, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
         f, g = shard_fg(w, batch, l2)
-        wr = objective._reg_mask(w)
-        return f + 0.5 * l2 * jnp.sum(wr * wr), g + l2 * wr
+        return _add_l2(objective, w, l2, f, g)
 
     return fg
+
+
+@jax.named_scope("photon.glm/reg")
+def _add_l2(objective, w, l2, f, g):
+    wr = objective._reg_mask(w)
+    return f + 0.5 * l2 * jnp.sum(wr * wr), g + l2 * wr
 
 
 def distributed_hvp(objective: GLMObjective, mesh: Mesh, axis: str = "data") -> Callable:
@@ -82,7 +92,8 @@ def distributed_hvp(objective: GLMObjective, mesh: Mesh, axis: str = "data") -> 
         # Like the gradient, the HVP's all-reduce is inserted by the AD
         # transpose (w and v are replicated, batch varies over `axis`).
         grad_data = lambda x: objective.grad(x, batch, 0.0)
-        return jax.jvp(grad_data, (w,), (v,))[1]
+        with jax.named_scope("photon.glm/loss"):
+            return jax.jvp(grad_data, (w,), (v,))[1]
 
     def hvp(w, v, batch, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
@@ -104,7 +115,9 @@ def distributed_diagonal_hessian(objective: GLMObjective, mesh: Mesh,
         out_specs=P(),
     )
     def shard_diag(w, batch):
-        return lax.psum(objective.diagonal_hessian(w, batch, 0.0), axis)
+        d = objective.diagonal_hessian(w, batch, 0.0)
+        with jax.named_scope("photon.allreduce/grad"):
+            return lax.psum(d, axis)
 
     def diag(w, batch, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
@@ -149,13 +162,18 @@ def _runner_cache_for(objective) -> dict:
 
 def cached_jit(objective, key, make_fn, **jit_kwargs):
     """Get-or-create a jitted kernel in the objective's runner cache (the
-    streaming chunk kernels share the fit runners' cache policy).
+    fit runners and the streaming chunk kernels share one cache policy).
+    The program is named ``photon_<key[0]>`` — the key's own first word —
+    so the profiler's ``XLA Modules`` line (``jit_photon_fit_tron``) and
+    ``compiled_kernel_count`` speak of the same programs.
     ``jit_kwargs`` (e.g. ``donate_argnums``) apply only when the kernel is
     first built, so every caller of one key must pass the same ones."""
     cache = _runner_cache_for(objective)
     fn = cache.get(key)
     if fn is None:
-        fn = jax.jit(make_fn(), **jit_kwargs)
+        made = make_fn()
+        made.__name__ = made.__qualname__ = f"photon_{key[0]}"
+        fn = jax.jit(made, **jit_kwargs)
         cache[key] = fn
     return fn
 
@@ -172,6 +190,16 @@ def compiled_kernel_count(objective) -> int:
             if callable(size):
                 total += int(size())
     return total
+
+
+@jax.named_scope("photon.allreduce/value")
+def _psum_value(x, axis):
+    return lax.psum(x, axis)
+
+
+@jax.named_scope("photon.allreduce/grad")
+def _psum_grad(x, axis):
+    return lax.psum(x, axis)
 
 
 def _eff_coeffs(norm, w):
@@ -287,7 +315,8 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
             batch.weights,
             objective.loss.loss(mask_margins(batch.weights, m),
                                 batch.labels)))
-        f, d = jax.value_and_grad(per_ex)(m)
+        with jax.named_scope("photon.glm/loss"):
+            f, d = jax.value_and_grad(per_ex)(m)
         return f, d
 
     # check_vma is disabled on the pallas variant: the interpret-mode kernel
@@ -303,7 +332,7 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         f, d = _margin_value_and_d(w, batch)
         csc = jax.tree.map(lambda a: a[0], csc_sh)
         g = _chain_t(apply_t(csc, d), jnp.sum(d))
-        return lax.psum(f, axis), lax.psum(g, axis)
+        return _psum_value(f, axis), _psum_grad(g, axis)
 
     @functools.partial(
         shard_map, mesh=mesh,
@@ -318,24 +347,25 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         # effective-coefficient map applies to v (no offset term)
         v_eff, v_adjust = _eff(v)
         mv = ell_margins(batch.features, v_eff) + v_adjust
-        d2 = apply_weights(batch.weights,
-                           objective.loss.d2(
-                               mask_margins(batch.weights, m),
-                               batch.labels))
+        with jax.named_scope("photon.glm/loss"):
+            d2 = apply_weights(batch.weights,
+                               objective.loss.d2(
+                                   mask_margins(batch.weights, m),
+                                   batch.labels))
+            dv = d2 * mv
         csc = jax.tree.map(lambda a: a[0], csc_sh)
-        dv = d2 * mv
-        return lax.psum(_chain_t(apply_t(csc, dv), jnp.sum(dv)), axis)
+        return _psum_grad(_chain_t(apply_t(csc, dv), jnp.sum(dv)), axis)
 
     def fg(w, batch, csc, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
         f, g = shard_fg(w, batch, csc)
-        wr = objective._reg_mask(w)
-        return f + 0.5 * l2 * jnp.sum(wr * wr), g + l2 * wr
+        return _add_l2(objective, w, l2, f, g)
 
     def hvp(w, v, batch, csc, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
         hv = shard_hvp(w, v, batch, csc)
-        return hv + l2 * objective._reg_mask(v)
+        with jax.named_scope("photon.glm/reg"):
+            return hv + l2 * objective._reg_mask(v)
 
     return build, fg, hvp
 
@@ -347,6 +377,7 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
 # re-measured since) — the fused Mosaic kernel wins on TPU,
 # while on CPU the XLA scatter-add is ~10x faster than the csc paths.
 _SPARSE_GRAD_DEFAULT = {"cpu": "scatter", "tpu": "csc_pallas"}
+_CSC_GRADS = ("csc", "csc_pallas", "csc_precise", "csc_segment")
 _sparse_grad_warned: set = set()
 
 
@@ -382,9 +413,14 @@ def build_csc(objective: GLMObjective, batch: LabeledBatch, mesh: Mesh,
     fits all share one dataset, so the O(nnz log nnz) device sort should be
     paid per dataset, not per fit. The batch is padded/sharded exactly as
     ``fit_distributed`` will pad it, so the views line up."""
-    batch = shard_batch(batch, mesh, axis)
-    build = make_csc_path(objective, mesh, axis, with_cols=with_cols)[0]
-    return jax.jit(build)(batch)
+    with obs_trace.span("fit.build_csc", cat="train", rows=batch.num_examples,
+              dim=batch.dim, chips=mesh.shape[axis]):
+        batch = shard_batch(batch, mesh, axis)
+        build = cached_jit(
+            objective, ("build_csc", mesh, axis, with_cols),
+            lambda: make_csc_path(objective, mesh, axis,
+                                  with_cols=with_cols)[0])
+        return build(batch)
 
 
 def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
@@ -450,8 +486,10 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     def s_loss_and_dir(m, mp, labels, weights):
         per_ex = lambda mm: jnp.sum(apply_weights(
             weights, loss.loss(mask_margins(weights, mm), labels)))
-        f, d1 = jax.value_and_grad(per_ex)(m)
-        return lax.psum(f, axis), lax.psum(jnp.sum(d1 * mp), axis)
+        with jax.named_scope("photon.glm/loss"):
+            f, d1 = jax.value_and_grad(per_ex)(m)
+            df = jnp.sum(d1 * mp)
+        return _psum_value(f, axis), _psum_value(df, axis)
 
     def loss_and_dir(batch):
         return lambda m, mp: s_loss_and_dir(m, mp, batch.labels, batch.weights)
@@ -469,17 +507,18 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         improvements near convergence, so Wolfe tests on totals become
         coin flips and the fit stalls (observed: hard stop at 16/20 on
         TPU). The derivative is evaluated at the trial point as usual."""
-        mm0 = mask_margins(weights, m)
         per_ex = lambda mm: jnp.sum(apply_weights(
             weights, loss.loss(mask_margins(weights, mm), labels)))
-        m1 = m + alpha * mp
-        d1 = jax.grad(per_ex)(m1)
-        diffs = apply_weights(
-            weights,
-            loss.loss(mask_margins(weights, m1), labels)
-            - loss.loss(mm0, labels))
-        return (lax.psum(jnp.sum(diffs), axis),
-                lax.psum(jnp.sum(d1 * mp), axis))
+        with jax.named_scope("photon.glm/loss"):
+            mm0 = mask_margins(weights, m)
+            m1 = m + alpha * mp
+            d1 = jax.grad(per_ex)(m1)
+            diffs = apply_weights(
+                weights,
+                loss.loss(mask_margins(weights, m1), labels)
+                - loss.loss(mm0, labels))
+            delta, df = jnp.sum(diffs), jnp.sum(d1 * mp)
+        return _psum_value(delta, axis), _psum_value(df, axis)
 
     def delta_and_dir(batch):
         return lambda m, mp, alpha: s_delta_and_dir(
@@ -493,9 +532,10 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     def s_grad_scatter(m, feats, labels, weights):
         per_ex = lambda mm: jnp.sum(apply_weights(
             weights, loss.loss(mask_margins(weights, mm), labels)))
-        d1 = jax.grad(per_ex)(m)
+        with jax.named_scope("photon.glm/loss"):
+            d1 = jax.grad(per_ex)(m)
         g = _norm_chain_t(norm, transpose_apply(feats, d1), jnp.sum(d1))
-        return lax.psum(g, axis)
+        return _psum_grad(g, axis)
 
     @functools.partial(
         shard_map, mesh=mesh,
@@ -506,10 +546,11 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     def s_grad_csc(m, labels, weights, csc_sh):
         per_ex = lambda mm: jnp.sum(apply_weights(
             weights, loss.loss(mask_margins(weights, mm), labels)))
-        d1 = jax.grad(per_ex)(m)
+        with jax.named_scope("photon.glm/loss"):
+            d1 = jax.grad(per_ex)(m)
         csc = jax.tree.map(lambda a: a[0], csc_sh)
         g = _norm_chain_t(norm, apply_t(csc, d1), jnp.sum(d1))
-        return lax.psum(g, axis)
+        return _psum_grad(g, axis)
 
     def make_data_grad(batch, csc=None):
         if csc is None:
@@ -522,38 +563,27 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
             delta_and_dir)
 
 
-def _fit_distributed_margin(
-    objective, batch, mesh, w0, l2, config, axis,
-    transpose: str = "scatter", precomputed_csc=None,
-) -> OptimizationResult:
-    """L-BFGS fit with the margin-space line search: 2 data passes per
-    iteration (one gather, one transpose) regardless of line-search effort.
-    ``transpose`` in {"scatter", "csc", "csc_pallas", "csc_precise",
-    "csc_segment"}; the
-    csc variants sort the nonzeros once (inside the jit but OUTSIDE the
-    optimizer loop), or reuse ``precomputed_csc`` across fits."""
-    from photon_ml_tpu.optimize.lbfgs_margin import lbfgs_margin
+def _margin_fit(objective, mesh, axis, config, transpose, precomputed):
+    """-> (cache key, maker of the program ``run(w0, b, l2v, csc)``): the
+    L-BFGS fit with the margin-space line search, 2 data passes per
+    iteration (one gather, one transpose) regardless of line-search
+    effort. ``transpose`` in {"scatter", "csc", "csc_pallas",
+    "csc_precise", "csc_segment"}; the csc variants sort the nonzeros once
+    (inside the jit but OUTSIDE the optimizer loop), or take a
+    precomputed view."""
+    use_csc = transpose in _CSC_GRADS
+    key = ("fit_lbfgs_margin", mesh, axis, transpose, config, precomputed)
 
-    batch = shard_batch(batch, mesh, axis)
-    use_csc = transpose in ("csc", "csc_pallas", "csc_precise",
-                            "csc_segment")
-    if precomputed_csc is not None and not use_csc:
-        raise ValueError(
-            f"precomputed_csc given but sparse_grad={transpose!r} does not "
-            "use it; pass sparse_grad='csc' (or a csc variant)")
+    def make():
+        from photon_ml_tpu.optimize.lbfgs_margin import lbfgs_margin
 
-    cache = _runner_cache_for(objective)
-    key = ("margin", mesh, axis, transpose, config,
-           precomputed_csc is not None)
-    run = cache.get(key)
-    if run is None:
         (init_margin, dir_margin, loss_and_dir, make_data_grad,
          delta_and_dir) = \
             make_margin_path(objective, mesh, axis, transpose=transpose,
                              precise=(transpose == "csc_precise"))
         reg_mask = objective._reg_mask
         build = None
-        if use_csc and precomputed_csc is None:
+        if use_csc and not precomputed:
             build = make_csc_path(
                 objective, mesh, axis,
                 use_pallas=(transpose == "csc_pallas"),
@@ -561,7 +591,6 @@ def _fit_distributed_margin(
                 segment=(transpose == "csc_segment"),
             )[0]
 
-        @jax.jit
         def run(w0, b, l2v, csc):
             if use_csc and csc is None:
                 csc = build(b)
@@ -572,8 +601,107 @@ def _fit_distributed_margin(
                 loss_delta_and_dir=delta_and_dir(b),
             )
 
-        cache[key] = run
-    return run(w0, batch, l2, precomputed_csc)
+        return run
+
+    return key, make
+
+
+def _full_fit(objective, mesh, axis, optimizer, config):
+    """-> (key, maker) of the black-box fit on the autodiff (scatter)
+    gradient: ``run(w0, b, l2v)``, OWL-QN ``run(w0, b, l2v, l1v)``."""
+    key = (f"fit_{optimizer}", "full", mesh, axis, config)
+
+    def make():
+        fg = distributed_value_and_grad(objective, mesh, axis)
+        opt = get_optimizer(optimizer)
+        if optimizer == "owlqn":
+            # L1 intercept mask (consistent with the L2 mask) is
+            # shape-dependent: derive from the traced w0 so the cached
+            # runner serves any dimension
+            mask_int = (objective.intercept_index
+                        if (objective.intercept_index >= 0
+                            and not objective.regularize_intercept) else -1)
+
+            def run(w0, b, l2v, l1v):
+                l1_mask = (None if mask_int < 0
+                           else jnp.ones_like(w0).at[mask_int].set(0.0))
+                return opt(lambda w: fg(w, b, l2v), w0, l1v, config,
+                           l1_mask=l1_mask)
+
+        elif optimizer == "tron":
+            hvp = distributed_hvp(objective, mesh, axis)
+            diag = distributed_diagonal_hessian(objective, mesh, axis)
+
+            # Jacobi preconditioner: one extra data pass per OUTER
+            # iteration buys fewer CG passes (each CG step is a full pass)
+            def run(w0, b, l2v):
+                return opt(lambda w: fg(w, b, l2v), w0, config,
+                           hvp=lambda w, v: hvp(w, v, b, l2v),
+                           precond=lambda w: diag(w, b, l2v))
+
+        else:
+
+            def run(w0, b, l2v):
+                return opt(lambda w: fg(w, b, l2v), w0, config)
+
+        return run
+
+    return key, make
+
+
+def _csc_fit(objective, mesh, axis, optimizer, config, sparse_grad,
+             precomputed):
+    """-> (key, maker) of the CSC-path fit: ONE program that sorts the
+    shard nonzeros by column (or takes the view :func:`build_csc` made),
+    then runs the whole optimizer loop against the sorted view — sort cost
+    amortizes over every iteration (and over every fit when precomputed).
+    ``run(w0, b, l2v, csc)``, OWL-QN ``run(w0, b, l2v, l1v, csc)``."""
+    use_pallas = sparse_grad == "csc_pallas"
+    precise = sparse_grad == "csc_precise"
+    segment = sparse_grad == "csc_segment"
+    key = (f"fit_{optimizer}", "csc", mesh, axis, config, use_pallas,
+           precise, segment, precomputed)
+
+    def make():
+        build, fg, hvp = make_csc_path(objective, mesh, axis,
+                                       use_pallas=use_pallas,
+                                       precise=precise, segment=segment)
+        opt = get_optimizer(optimizer)
+        if optimizer == "owlqn":
+            # the mask is shape-dependent: derive it from the traced w0 so
+            # the cached runner serves any dimension
+            mask_int = (objective.intercept_index
+                        if (objective.intercept_index >= 0
+                            and not objective.regularize_intercept) else -1)
+
+            def run(w0, b, l2v, l1v, csc):
+                if csc is None:
+                    csc = build(b)
+                l1_mask = (None if mask_int < 0
+                           else jnp.ones_like(w0).at[mask_int].set(0.0))
+                return opt(lambda w: fg(w, b, csc, l2v), w0, l1v, config,
+                           l1_mask=l1_mask)
+
+        elif optimizer == "tron":
+            diag = distributed_diagonal_hessian(objective, mesh, axis)
+
+            def run(w0, b, l2v, csc):
+                if csc is None:
+                    csc = build(b)
+                return opt(lambda w: fg(w, b, csc, l2v), w0, config,
+                           hvp=lambda w, v: hvp(w, v, b, csc, l2v),
+                           precond=lambda w: diag(w, b, l2v))
+
+        else:
+
+            def run(w0, b, l2v, csc):
+                if csc is None:
+                    csc = build(b)
+                return opt(lambda w: fg(w, b, csc, l2v), w0, config)
+
+        return run
+
+    return key, make
 
 
 def fit_distributed(
@@ -610,123 +738,43 @@ def fit_distributed(
 
     ``precomputed_csc``: reuse a ``build_csc(batch, mesh)`` result across
     fits on the same dataset (regularization grids, calibration) so the
-    per-dataset column sort is paid once, not per fit."""
+    per-dataset column sort is paid once, not per fit.
+
+    The call returns once the program is dispatched. It leaves a ``fit``
+    span (with ``fit.shard_batch`` and ``fit.dispatch`` under it) and one
+    record in ``obs.metrics.training_metrics()`` (``record_fit``): the
+    result's pass and product counters stay on the device until that
+    record is read."""
+    t_entry = time.perf_counter()
     sparse_grad = resolve_sparse_grad(sparse_grad, batch.features)
-    if optimizer == "lbfgs" and line_search == "margin":
-        return _fit_distributed_margin(
-            objective, batch, mesh, w0, l2, config, axis,
-            transpose=sparse_grad, precomputed_csc=precomputed_csc,
-        )
-    if sparse_grad in ("csc", "csc_pallas", "csc_precise", "csc_segment"):
-        return _fit_distributed_csc(
-            objective, batch, mesh, w0, l2, l1, optimizer, config, axis,
-            use_pallas=(sparse_grad == "csc_pallas"),
-            precise=(sparse_grad == "csc_precise"),
-            segment=(sparse_grad == "csc_segment"),
-            precomputed_csc=precomputed_csc,
-        )
-    if precomputed_csc is not None:
+    use_csc = sparse_grad in _CSC_GRADS
+    margin = optimizer == "lbfgs" and line_search == "margin"
+    if precomputed_csc is not None and not use_csc:
         raise ValueError(
             f"precomputed_csc given but sparse_grad={sparse_grad!r} does "
             "not use it; pass sparse_grad='csc' (or a csc variant)")
-    batch = shard_batch(batch, mesh, axis)
-    cache = _runner_cache_for(objective)
-    key = ("full", mesh, axis, optimizer, config)
-    run = cache.get(key)
-    if run is None:
-        fg = distributed_value_and_grad(objective, mesh, axis)
-        opt = get_optimizer(optimizer)
-        if optimizer == "owlqn":
-            # L1 intercept mask (consistent with the L2 mask) is
-            # shape-dependent: derive from the traced w0 so the cached
-            # runner serves any dimension
-            mask_int = (objective.intercept_index
-                        if (objective.intercept_index >= 0
-                            and not objective.regularize_intercept) else -1)
-
-            def _owlqn_run(w0, b, l2v, l1v):
-                l1_mask = (None if mask_int < 0
-                           else jnp.ones_like(w0).at[mask_int].set(0.0))
-                return opt(lambda w: fg(w, b, l2v), w0, l1v, config,
-                           l1_mask=l1_mask)
-
-            run = jax.jit(_owlqn_run)
-        elif optimizer == "tron":
-            hvp = distributed_hvp(objective, mesh, axis)
-            diag = distributed_diagonal_hessian(objective, mesh, axis)
-            # Jacobi preconditioner: one extra data pass per OUTER
-            # iteration buys fewer CG passes (each CG step is a full pass)
-            run = jax.jit(
-                lambda w0, b, l2v: opt(
-                    lambda w: fg(w, b, l2v), w0, config,
-                    hvp=lambda w, v: hvp(w, v, b, l2v),
-                    precond=lambda w: diag(w, b, l2v),
-                )
-            )
-        else:
-            run = jax.jit(
-                lambda w0, b, l2v: opt(lambda w: fg(w, b, l2v), w0, config))
-        cache[key] = run
-    if optimizer == "owlqn":
-        return run(w0, batch, l2, l1)
-    return run(w0, batch, l2)
-
-
-def _fit_distributed_csc(
-    objective, batch, mesh, w0, l2, l1, optimizer, config, axis,
-    use_pallas: bool = False, precise: bool = False, segment: bool = False,
-    precomputed_csc=None,
-) -> OptimizationResult:
-    """CSC-path fit: ONE jitted program that sorts the shard nonzeros by
-    column (or reuses ``precomputed_csc`` from :func:`build_csc`), then runs
-    the whole optimizer loop against the sorted view — sort cost amortizes
-    over every iteration (and over every fit when precomputed)."""
-    batch = shard_batch(batch, mesh, axis)
-    cache = _runner_cache_for(objective)
-    key = ("csc", mesh, axis, optimizer, config, use_pallas, precise,
-           segment, precomputed_csc is not None)
-    run = cache.get(key)
-    if run is None:
-        build, fg, hvp = make_csc_path(objective, mesh, axis,
-                                       use_pallas=use_pallas,
-                                       precise=precise, segment=segment)
-        opt = get_optimizer(optimizer)
-        if optimizer == "owlqn":
-            # the mask is shape-dependent: derive it from the traced w0 so
-            # the cached runner serves any dimension
-            mask_int = (objective.intercept_index
-                        if (objective.intercept_index >= 0
-                            and not objective.regularize_intercept) else -1)
-
-            @jax.jit
-            def run(w0, b, l2v, l1v, csc):
-                if csc is None:
-                    csc = build(b)
-                l1_mask = (None if mask_int < 0
-                           else jnp.ones_like(w0).at[mask_int].set(0.0))
-                return opt(lambda w: fg(w, b, csc, l2v), w0, l1v, config,
-                           l1_mask=l1_mask)
-
-        elif optimizer == "tron":
-            diag = distributed_diagonal_hessian(objective, mesh, axis)
-
-            @jax.jit
-            def run(w0, b, l2v, csc):
-                if csc is None:
-                    csc = build(b)
-                return opt(lambda w: fg(w, b, csc, l2v), w0, config,
-                           hvp=lambda w, v: hvp(w, v, b, csc, l2v),
-                           precond=lambda w: diag(w, b, l2v))
-
-        else:
-
-            @jax.jit
-            def run(w0, b, l2v, csc):
-                if csc is None:
-                    csc = build(b)
-                return opt(lambda w: fg(w, b, csc, l2v), w0, config)
-
-        cache[key] = run
-    if optimizer == "owlqn":
-        return run(w0, batch, l2, l1, precomputed_csc)
-    return run(w0, batch, l2, precomputed_csc)
+    precomputed = precomputed_csc is not None
+    if margin:
+        key, make = _margin_fit(objective, mesh, axis, config, sparse_grad,
+                                precomputed)
+    elif use_csc:
+        key, make = _csc_fit(objective, mesh, axis, optimizer, config,
+                             sparse_grad, precomputed)
+    else:
+        key, make = _full_fit(objective, mesh, axis, optimizer, config)
+    compiled = key not in _runner_cache_for(objective)
+    with obs_trace.span("fit", cat="train", optimizer=optimizer,
+              sparse_grad=sparse_grad, rows=batch.num_examples,
+              dim=batch.dim, chips=mesh.shape[axis], compiled=compiled):
+        with obs_trace.span("fit.shard_batch", cat="train"):
+            batch = shard_batch(batch, mesh, axis)
+        run = cached_jit(objective, key, make)
+        args = (w0, batch, l2) + ((l1,) if optimizer == "owlqn" else ())
+        if margin or use_csc:
+            args += (precomputed_csc,)
+        with obs_trace.span("fit.dispatch", cat="train"):
+            res = run(*args)
+    obs_metrics.training_metrics().record_fit(
+        optimizer=optimizer, sparse_grad=sparse_grad, compiled=compiled,
+        dispatch_s=time.perf_counter() - t_entry, result=res)
+    return res

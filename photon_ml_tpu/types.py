@@ -72,12 +72,16 @@ def _vector_gather_rows(table2d: jax.Array, idx: jax.Array) -> jax.Array:
     # element (~12% of the pass on the v5e); table_gather's indices are
     # in-bounds by construction (idx < d => idx>>7 < rows), so clamping
     # is semantically a no-op and results stay bit-identical
-    rows = jnp.take(table2d, jnp.right_shift(idx, 7), axis=0, mode="clip")
-    lane = jnp.bitwise_and(idx, 127)
-    onehot = lane[:, None] == jnp.arange(_LANES, dtype=idx.dtype)[None, :]
-    return jnp.sum(jnp.where(onehot, rows, 0), axis=-1)
+    with jax.named_scope("photon.table_gather/rows"):
+        rows = jnp.take(table2d, jnp.right_shift(idx, 7), axis=0,
+                        mode="clip")
+    with jax.named_scope("photon.table_gather/select"):
+        lane = jnp.bitwise_and(idx, 127)
+        onehot = lane[:, None] == jnp.arange(_LANES, dtype=idx.dtype)[None, :]
+        return jnp.sum(jnp.where(onehot, rows, 0), axis=-1)
 
 
+@jax.named_scope("photon.table_gather")
 def table_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
     """``table[idx]`` for a 1-D table, vectorized for TPU when profitable.
 
@@ -192,6 +196,7 @@ class CSCTranspose:
     cols: Optional[jax.Array] = None
 
 
+@jax.named_scope("photon.csc/build")
 def build_csc_transpose(indices: jax.Array, values: Optional[jax.Array],
                         dim: int, with_cols: bool = True) -> CSCTranspose:
     """Sort the padded ELL nonzeros by column (pure jax; jit/shard_map safe).
@@ -257,13 +262,15 @@ def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
         return jnp.zeros((csc.col_starts.shape[0] - 1,), d.dtype)
     T = min(block, nnz)
     B = -(-nnz // T)
-    padded = jnp.pad(contrib, (0, B * T - nnz)).reshape(B, T)
-    local = jnp.cumsum(padded, axis=1)  # [B, T] inclusive, block-local
-    bt = local[:, -1]  # [B] block totals
+    with jax.named_scope("photon.csc/prefix_sum"):
+        padded = jnp.pad(contrib, (0, B * T - nnz)).reshape(B, T)
+        local = jnp.cumsum(padded, axis=1)  # [B, T] inclusive, block-local
+        bt = local[:, -1]  # [B] block totals
     return blocked_boundary_combine(local.reshape(-1), bt, csc.col_starts,
                                     T).astype(d.dtype)
 
 
+@jax.named_scope("photon.csc/boundary_combine")
 def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
                              col_starts: jax.Array, T: int) -> jax.Array:
     """Column sums from BLOCK-LOCAL inclusive prefixes.
@@ -283,14 +290,20 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
     cs = col_starts.astype(jnp.int32)
     b, r = cs // T, cs % T
     # local exclusive prefix at each boundary: local[b, r-1], 0 at r == 0
-    lp = jnp.where(r > 0, local_flat[jnp.maximum(cs - 1, 0)],
-                   jnp.zeros((), local_flat.dtype))
+    with jax.named_scope("lp"):
+        lp = jnp.where(r > 0, local_flat[jnp.maximum(cs - 1, 0)],
+                       jnp.zeros((), local_flat.dtype))
     b0, b1 = b[:-1], b[1:]
     lp0, lp1 = lp[:-1], lp[1:]
     same = b0 == b1
     # bt[b0] is only used on the spanning branch, where b0 < B always
-    suffix0 = bt[jnp.minimum(b0, B - 1)] - lp0
-    mid = BP[b1] - BP[jnp.minimum(b0 + 1, B)]  # exact 0 when b1 == b0 + 1
+    with jax.named_scope("bt"):
+        suffix0 = bt[jnp.minimum(b0, B - 1)] - lp0
+    with jax.named_scope("bp_hi"):
+        bp_hi = BP[b1]
+    with jax.named_scope("bp_lo"):
+        bp_lo = BP[jnp.minimum(b0 + 1, B)]
+    mid = bp_hi - bp_lo  # exact 0 when b1 == b0 + 1
     return jnp.where(same, lp1 - lp0, suffix0 + mid + lp1)
 
 

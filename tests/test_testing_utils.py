@@ -84,16 +84,16 @@ def test_avro_fixture_roundtrip(tmp_path):
 def test_profile_trace_writes_output(tmp_path):
     import jax.numpy as jnp
 
-    from photon_ml_tpu.utils import annotate, profile_trace
+    from photon_ml_tpu.obs.trace import profile, span
 
     out = str(tmp_path / "trace")
-    with profile_trace(out):
-        with annotate("tiny-op"):
+    with profile(out):
+        with span("tiny-op"):
             (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
     found = []
     for root, _, files in os.walk(out):
         found += files
     assert found, "profiler trace produced no files"
     # no-op path
-    with profile_trace(None):
+    with profile(None):
         pass
