@@ -242,7 +242,8 @@ def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
     a block-total prefix difference, but only columns wider than a whole
     block (>= ``block`` nonzeros) ever take it — and for those the
     interior sum *is* the dominant term, so no cancellation. Cost: the
-    same one pass of cumsum traffic, plus O(dim) boundary gathers.
+    same one pass of cumsum traffic, plus one gather over the dim + 1
+    column boundaries and B-long ones for the <= B spanning columns.
 
     ``precise=True`` keeps the old full-f64 global prefix (meaningful
     only under jax_enable_x64; without it, f64 silently degrades to f32,
@@ -281,30 +282,40 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
     A column inside one block differences local prefixes only; a spanning
     column takes first-block suffix + interior block totals + last-block
     head, so no difference ever cancels against a prefix that outgrew the
-    column's own sum (see ``csc_transpose_apply``)."""
+    column's own sum (see ``csc_transpose_apply``).
+
+    The columns' ranges are sorted and disjoint, so each of the B block
+    boundaries ``k*T`` lies inside at most one column: at most B columns
+    span. One gather runs over the ``dim + 1`` column boundaries (``lp``);
+    the block totals are read for the <= B spanning columns alone, which B
+    binary searches in ``col_starts`` find."""
     B = bt.shape[0]
+    dim = col_starts.shape[0] - 1
     # exclusive prefix of block totals; only consulted for columns spanning
     # >= 1 full interior block
     BP = jnp.concatenate([jnp.zeros((1,), bt.dtype), jnp.cumsum(bt)])
 
     cs = col_starts.astype(jnp.int32)
-    b, r = cs // T, cs % T
     # local exclusive prefix at each boundary: local[b, r-1], 0 at r == 0
     with jax.named_scope("lp"):
-        lp = jnp.where(r > 0, local_flat[jnp.maximum(cs - 1, 0)],
+        lp = jnp.where(cs % T > 0, local_flat[jnp.maximum(cs - 1, 0)],
                        jnp.zeros((), local_flat.dtype))
-    b0, b1 = b[:-1], b[1:]
-    lp0, lp1 = lp[:-1], lp[1:]
-    same = b0 == b1
-    # bt[b0] is only used on the spanning branch, where b0 < B always
-    with jax.named_scope("bt"):
-        suffix0 = bt[jnp.minimum(b0, B - 1)] - lp0
-    with jax.named_scope("bp_hi"):
-        bp_hi = BP[b1]
-    with jax.named_scope("bp_lo"):
-        bp_lo = BP[jnp.minimum(b0 + 1, B)]
-    mid = bp_hi - bp_lo  # exact 0 when b1 == b0 + 1
-    return jnp.where(same, lp1 - lp0, suffix0 + mid + lp1)
+    # every column that starts and ends in one block, the empty ones too
+    out = lp[1:] - lp[:-1]
+    with jax.named_scope("span"):
+        # boundary k*T lies inside column j iff cs[j] < k*T <= cs[j+1]: j
+        # is the last start below k*T, where there is one and it starts a
+        # column (cs[dim] < B*T is the padding's, not a column's)
+        edges = jnp.arange(1, B + 1, dtype=jnp.int32) * T
+        j = jnp.searchsorted(cs, edges, side="left").astype(jnp.int32) - 1
+        spans = (j >= 0) & (j < dim)
+        j = jnp.where(spans, j, 0)
+        b0, b1 = cs[j] // T, cs[j + 1] // T
+        suffix0 = bt[jnp.minimum(b0, B - 1)] - lp[j]
+        mid = BP[b1] - BP[jnp.minimum(b0 + 1, B)]  # exact 0 at b1 == b0 + 1
+        # a column over several boundaries is written the same value again
+        return out.at[jnp.where(spans, j, dim)].set(
+            suffix0 + mid + lp[j + 1], mode="drop")
 
 
 def csc_segment_apply(csc: CSCTranspose, d: jax.Array) -> jax.Array:

@@ -17,6 +17,7 @@ from photon_ml_tpu.parallel.data_parallel import (
 )
 from photon_ml_tpu.parallel.mesh import make_mesh, shard_batch
 from photon_ml_tpu.types import (
+    blocked_boundary_combine,
     build_csc_transpose,
     csc_transpose_apply,
     make_batch,
@@ -299,3 +300,91 @@ def test_pallas_blocked_accuracy_all_positive(rng):
                                          precise=True))
     rel = np.abs(got - ref) / np.maximum(ref, 1e-30)
     assert float(rel.max()) < 1e-4, float(rel.max())
+
+
+# -- the boundary combine against the four-gather formula (ISSUE 27) --------
+def _four_gather_combine(local_flat, bt, col_starts, T):
+    """The oracle: ``blocked_boundary_combine`` as it stood up to PR 26,
+    which read the block totals for every column (``bt[b0]``, ``BP[b1]``,
+    ``BP[b0 + 1]``: three gathers over dim) and chose per column."""
+    B = bt.shape[0]
+    BP = jnp.concatenate([jnp.zeros((1,), bt.dtype), jnp.cumsum(bt)])
+    cs = col_starts.astype(jnp.int32)
+    b, r = cs // T, cs % T
+    lp = jnp.where(r > 0, local_flat[jnp.maximum(cs - 1, 0)],
+                   jnp.zeros((), local_flat.dtype))
+    b0, b1 = b[:-1], b[1:]
+    lp0, lp1 = lp[:-1], lp[1:]
+    suffix0 = bt[jnp.minimum(b0, B - 1)] - lp0
+    mid = BP[b1] - BP[jnp.minimum(b0 + 1, B)]
+    return jnp.where(b0 == b1, lp1 - lp0, suffix0 + mid + lp1)
+
+
+def _spread(r, nnz, dim):
+    return r.multinomial(nnz, np.full(dim, 1.0 / dim))
+
+
+# name -> (T, nonzeros per column, drawn from the generator handed in)
+COMBINE_CASES = {
+    "nnz_multiple_of_T": (8, lambda r: _spread(r, 64, 40)),
+    "nnz_not_multiple_of_T": (8, lambda r: _spread(r, 61, 40)),
+    # column 5 holds [3, 33): a suffix, three whole blocks, a head of one
+    "column_wider_than_two_blocks":
+        (8, lambda r: [1, 2, 0, 0, 0, 30, 3, 0, 4, 1, 0, 2]),
+    # column 3 holds [5, 16): spans block 0 to the very end of block 1
+    "column_ends_on_block_boundary": (8, lambda r: [2, 3, 0, 11, 5, 0, 3]),
+    # columns 3-5 are empty at 8, columns 8-9 at 16 == nnz == B * T
+    "empty_columns_on_boundary":
+        (8, lambda r: [3, 0, 5, 0, 0, 0, 6, 2, 0, 0]),
+    "dim_1": (8, lambda r: [29]),
+    "dim_below_B": (4, lambda r: _spread(r, 203, 7)),  # B = 51 blocks
+    "zipf_columns": (16, lambda r: np.bincount(
+        np.minimum(r.zipf(1.3, 1000) - 1, 299), minlength=300)),
+}
+
+
+def _combine_problem(case, seed):
+    """Block-local f32 prefixes, block totals and column starts of one
+    draw of ``case``; the values differ from seed to seed, and the
+    columns too where the case draws them."""
+    T, counts = COMBINE_CASES[case]
+    r = np.random.default_rng(seed)
+    counts = np.asarray(counts(r))
+    col_starts = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    nnz = int(col_starts[-1])
+    B = -(-nnz // T)
+    contrib = np.pad(r.normal(size=nnz).astype(np.float32), (0, B * T - nnz))
+    local = np.cumsum(contrib.reshape(B, T), axis=1, dtype=np.float32)
+    return (jnp.asarray(local.reshape(-1)), jnp.asarray(local[:, -1]),
+            jnp.asarray(col_starts), T)
+
+
+@pytest.mark.parametrize("under", ["jit", "shard_map"])
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+def test_boundary_combine_bit_equal_to_four_gathers(case, under):
+    if under == "jit":
+        local, bt, cs, T = _combine_problem(case, 0)
+        got = jax.jit(blocked_boundary_combine, static_argnums=3)(
+            local, bt, cs, T)
+        want = _four_gather_combine(local, bt, cs, T)
+        spanning = np.asarray(cs[:-1] // T != cs[1:] // T)
+        assert spanning.any()  # every case has the branch to get wrong
+        if case == "dim_below_B":
+            assert spanning.sum() >= cs.shape[0] - 2
+    else:
+        # four different draws, one a device of a 4-device mesh
+        from jax.sharding import PartitionSpec as P
+
+        mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
+        draws = [_combine_problem(case, seed) for seed in range(4)]
+        T = draws[0][3]
+        local, bt, cs = (jnp.stack(leaf) for leaf in zip(
+            *(draw[:3] for draw in draws)))
+        got = jax.jit(jax.shard_map(
+            lambda l, b, c: blocked_boundary_combine(l[0], b[0], c[0],
+                                                     T)[None],
+            mesh=mesh, in_specs=P("data"), out_specs=P("data")))(
+                local, bt, cs)
+        want = jnp.stack([_four_gather_combine(*draw) for draw in draws])
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

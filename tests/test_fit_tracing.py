@@ -175,8 +175,7 @@ def _criteo_like(n=512, k=39, d=256):
 
 _KERNEL_SCOPES = [
     "photon.table_gather/rows", "photon.table_gather/select",
-    "photon.csc/boundary_combine/lp", "photon.csc/boundary_combine/bt",
-    "photon.csc/boundary_combine/bp_hi", "photon.csc/boundary_combine/bp_lo",
+    "photon.csc/boundary_combine/lp", "photon.csc/boundary_combine/span",
     "photon.csc/prefix_sum", "photon.csc/build", "photon.glm/loss",
     "photon.allreduce/grad", "photon.allreduce/value"]
 _LBFGS_SCOPES = ["photon.lbfgs/two_loop", "photon.lbfgs/line_search",
@@ -203,7 +202,7 @@ LOWERED = {
 def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
     optimizer, line_search, sparse_grad = fit
     program, optimizer_scopes, transposes = LOWERED[fit]
-    batch, d = _criteo_like()
+    batch, d = _criteo_like(n=2048)  # three tiles of the prefix sum
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     obj = make_objective("logistic")
     cfg = OptimizerConfig(max_iters=3, tolerance=0.0)
@@ -223,6 +222,23 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
     lp = {s for s in stacks
           if s.endswith("photon.csc/boundary_combine/lp/gather")}
     assert len(lp) == transposes, sorted(lp)
+    # block totals are read where a column spans a block, not per column:
+    # at each call site one gather runs over the dim + 1 column boundaries
+    # (`lp`) and every other over the B block boundaries at most
+    B = -(-batch.features.indices.size // (256 * 128))  # the smaller tile
+    assert 1 < B < d
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    sites = collections.defaultdict(list)
+    for indices, loc in re.findall(
+            r'"stablehlo\.gather"\(.*: \(tensor<[^>]*>, '
+            r'tensor<(\d+)x1xi\d+>\).* loc\((#loc\d+)\)', text):
+        site, scope, _ = names[loc].partition("photon.csc/boundary_combine/")
+        if scope:
+            sites[site].append(int(indices))
+    assert len(sites) == transposes, sorted(sites)
+    for site, gathers in sites.items():
+        *short, longest = sorted(gathers)
+        assert longest == d + 1 and short and short[-1] <= B, (site, gathers)
     if sparse_grad == "csc_pallas":
         assert any("photon_multiply_prefix_sum" in s for s in stacks)
     if optimizer == "owlqn":
@@ -546,14 +562,14 @@ def test_kernels_table_of_the_recorded_trace(capsys):
                   "photon.table_gather/select", "photon.lbfgs/two_loop",
                   "photon.lbfgs/update", "photon.glm/loss"):
         assert rollup[scope]["device_s"] > 0, scope
-    # at this size XLA fuses some of the four boundary gathers into one
-    # instruction, which keeps one gather's name stack
-    gathers = [g for g in ("lp", "bt", "bp_hi", "bp_lo")
-               if f"photon.csc/boundary_combine/{g}" in scopes]
-    assert "lp" in gathers and len(gathers) >= 2, gathers
-    for g in gathers:
-        row = scopes[f"photon.csc/boundary_combine/{g}"]
+    # the one gather over the column boundaries, and the block totals of
+    # the spanning columns: no `bt`, `bp_hi`, `bp_lo` over dim any more
+    combine = {s.rpartition("/")[2]: row for s, row in scopes.items()
+               if s.startswith("photon.csc/boundary_combine/")}
+    assert sorted(combine) == ["lp", "span"]
+    for row in combine.values():
         assert row["executions"] > 0 and row["instructions"]
+    assert combine["span"]["device_s"] < 0.1 * combine["lp"]["device_s"]
     assert sum(r["share"] for r in scopes.values()) == pytest.approx(1.0)
     assert sum(r["device_s"] for r in scopes.values()) == pytest.approx(
         table["busy_s"])
