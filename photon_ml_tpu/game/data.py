@@ -211,13 +211,132 @@ class RandomEffectTrainData:
         return total
 
 
+# A bucket's row capacity follows a power-of-two ladder of active-row
+# counts: entities with (N/2, N] rows share a rung, so fewer than half of a
+# rung's slots are padding whatever the skew (about 30% expected). The rung
+# pads to its largest member, rounded up to this many rows.
+_ROW_ROUND = 8
+
+
+def ladder_splits(counts_sorted: np.ndarray, num_buckets: int) -> List[int]:
+    """Where to cut entities, sorted by ascending row count, into buckets:
+    one bucket per occupied rung of the power-of-two ladder, and where
+    that gives more than ``num_buckets``, the adjacent pair whose merge
+    adds the fewest padded slots is merged until it does not. Returns
+    the cut positions, first 0 and last ``len(counts_sorted)``."""
+    E = len(counts_sorted)
+    if E == 0:
+        return [0, 0]
+    rung = np.ceil(np.log2(np.maximum(counts_sorted, 1))).astype(np.int64)
+    cuts = [0] + (np.flatnonzero(np.diff(rung)) + 1).tolist() + [E]
+    while len(cuts) - 1 > max(num_buckets, 1):
+        # merging bucket i into i + 1 pads i's entities to i + 1's rows
+        cost = [(cuts[i + 1] - cuts[i])
+                * int(counts_sorted[cuts[i + 2] - 1]
+                      - counts_sorted[cuts[i + 1] - 1])
+                for i in range(len(cuts) - 2)]
+        del cuts[int(np.argmin(cost)) + 1]
+    return cuts
+
+
+def _padded_rows(max_count: int) -> int:
+    n = max(int(max_count), 1)
+    return n if n < _ROW_ROUND else -(-n // _ROW_ROUND) * _ROW_ROUND
+
+
+class _SubspaceIndex:
+    """(entity, global feature id) -> the entity's local slot, for all
+    entities of one effect at once. Slots follow ascending feature id
+    within an entity. A dense table where entities x dim is small (one
+    gather a lookup), a sorted key list with binary search elsewhere."""
+
+    _TABLE_MAX = 1 << 25
+
+    def __init__(self, keys_sorted: np.ndarray, num_entities: int, dim: int):
+        # keys_sorted: unique ascending entity * dim + feature id
+        self.dim = int(dim)
+        self.keys = keys_sorted
+        ent = keys_sorted // self.dim
+        self.starts = np.searchsorted(ent, np.arange(num_entities + 1))
+        self.table = None
+        if num_entities * self.dim <= self._TABLE_MAX:
+            self.table = np.full(num_entities * self.dim, -1, np.int32)
+            self.table[keys_sorted] = (
+                np.arange(len(keys_sorted)) - self.starts[ent])
+
+    @classmethod
+    def from_rows(cls, ent_of_row, indices, values, num_entities, dim):
+        if num_entities * dim <= cls._TABLE_MAX:
+            # 32-bit keys, no masked copy: half the memory traffic
+            keys = indices + (ent_of_row.astype(np.int32)
+                              * np.int32(dim))[:, None]
+            seen = np.zeros(num_entities * dim + 1, bool)
+            keys[values == 0] = num_entities * dim  # the spare slot
+            seen[keys.ravel()] = True
+            uniq = np.flatnonzero(seen[:-1])
+        else:
+            keys = ent_of_row[:, None].astype(np.int64) * dim + indices
+            uniq = np.unique(keys[values != 0])
+        return cls(uniq, num_entities, dim)
+
+    @classmethod
+    def from_projections(cls, projections: Sequence[np.ndarray], dim: int):
+        """From the buckets' ``projection`` arrays, entities numbered
+        bucket by bucket."""
+        keys, base = [], 0
+        for proj in projections:
+            proj = np.asarray(proj)
+            e, _ = np.nonzero(proj >= 0)
+            keys.append((e + base).astype(np.int64) * dim + proj[proj >= 0])
+            base += proj.shape[0]
+        keys = (np.concatenate(keys) if keys else np.zeros(0, np.int64))
+        # slots ascend with the feature id, so the keys come out sorted
+        return cls(keys, base, dim)
+
+    def local_dims(self) -> np.ndarray:
+        return np.diff(self.starts)
+
+    def remap(self, ent_of_row, indices, values):
+        """Local slots and values of rows [m, k]; an entry whose feature
+        the entity never saw, or whose value is 0, becomes (0, 0.0)."""
+        if indices.size == 0:
+            return np.zeros_like(indices), np.zeros(indices.shape)
+        if self.table is not None:
+            keys = indices + (ent_of_row.astype(np.int32)
+                              * np.int32(self.dim))[:, None]
+            slot = self.table[keys]
+            dead = slot < 0
+        elif len(self.keys) == 0:
+            slot = np.zeros(indices.shape, np.int64)
+            dead = np.ones(indices.shape, bool)
+        else:
+            ent = ent_of_row[:, None].astype(np.int64)
+            keys = ent * self.dim + indices
+            pos = np.minimum(np.searchsorted(self.keys, keys),
+                             len(self.keys) - 1)
+            dead = self.keys[pos] != keys
+            slot = pos - self.starts[ent]
+        dead |= values == 0
+        slot = slot.astype(indices.dtype, copy=False)
+        slot[dead] = 0
+        val = np.array(values, copy=True)
+        val[dead] = 0
+        return slot, val
+
+
+def _slot_positions(counts: np.ndarray) -> np.ndarray:
+    """0, 1, ..., c-1 for each count c, concatenated."""
+    starts = np.cumsum(counts) - counts
+    return np.arange(int(counts.sum())) - np.repeat(starts, counts)
+
+
 def build_random_effect_data(
     features,
     labels: np.ndarray,
     weights: np.ndarray,
     entity_ids: Sequence,
     effect_name: str = "random",
-    num_buckets: int = 4,
+    num_buckets: int = 16,
     active_cap: Optional[int] = None,
     seed: int = 0,
     projection: str = "subspace",
@@ -226,6 +345,14 @@ def build_random_effect_data(
     entity_shard=None,
 ) -> RandomEffectTrainData:
     """Group rows by entity, split active/passive, project, bucket, pad.
+
+    Entities are bucketed by size, not by rank: one bucket per occupied
+    rung of a power-of-two ladder of active-row counts, so that padding
+    is under half of all slots at any skew (``ladder_splits``).
+    ``num_buckets`` is the most buckets — distinct ``(N, D)`` shapes,
+    hence compiled solvers — the effect may have; rungs are merged,
+    cheapest first, only where the ladder has more, and ``1`` pads every
+    entity to the largest.
 
     ``projection``: "subspace" builds exact per-entity feature maps (the
     LinearSubspaceProjector role); "random" uses a shared count-sketch of
@@ -245,6 +372,7 @@ def build_random_effect_data(
     labels = np.asarray(labels, np.float64)
     weights = np.asarray(weights, np.float64)
     n = sp.num_rows
+    k = sp.indices.shape[1]
     ent = np.asarray(entity_ids)
     uniq, codes = np.unique(ent, return_inverse=True)
     rng = np.random.default_rng(seed)
@@ -267,68 +395,84 @@ def build_random_effect_data(
             rows.sort()
         active_rows.append(rows)
     uniq = uniq[keep]
+    E_all = len(uniq)
+    counts = np.array([len(r) for r in active_rows], np.int64)
+    rows_flat = (np.concatenate(active_rows) if E_all
+                 else np.zeros(0, np.int64))
+    ent_of_row = np.repeat(np.arange(E_all), counts)
+    row_idx = sp.indices[rows_flat]
+    row_val = sp.values[rows_flat]
 
-    # per-entity local feature maps from active data
+    # every active row's features in its entity's local space, at once
+    sketch = index = None
     if projection == "random":
         if not projection_dim or projection_dim <= 0:
             raise ValueError("projection='random' needs a positive "
                              "projection_dim")
         sketch = SketchProjection(projection_dim, projection_seed)
-        local_maps = [sketch] * len(uniq)
+        loc, val = _remap_to_local(row_idx, row_val, sketch)
+        local_dims = np.full(E_all, projection_dim, np.int64)
     elif projection == "subspace":
-        local_maps = []
-        for e in range(len(uniq)):
-            rows = active_rows[e]
-            feats = sp.indices[rows][sp.values[rows] != 0]
-            ids = np.unique(feats)
-            local_maps.append({int(g): i for i, g in enumerate(ids)})
+        index = _SubspaceIndex.from_rows(ent_of_row, row_idx, row_val,
+                                         E_all, sp.dim)
+        loc, val = index.remap(ent_of_row, row_idx, row_val)
+        local_dims = index.local_dims()
     else:
         raise ValueError(f"unknown projection '{projection}' "
                          "(subspace|random)")
 
     # bucket entities by active-row count
-    counts = np.array([len(r) for r in active_rows])
     ent_order = np.argsort(counts, kind="mergesort")
-    num_buckets = max(1, min(num_buckets, len(uniq)))
-    splits = np.array_split(ent_order, num_buckets)
-    splits = [s for s in splits if len(s)]
+    cuts = ladder_splits(counts[ent_order], num_buckets)
+    bucket_of_ent = np.zeros(E_all, np.int64)
+    rank_of_ent = np.zeros(E_all, np.int64)
+    for b in range(len(cuts) - 1):
+        members = ent_order[cuts[b]:cuts[b + 1]]
+        bucket_of_ent[members] = b
+        rank_of_ent[members] = np.arange(len(members))
+    bucket_of_row = bucket_of_ent[ent_of_row]
+    rank_of_row = rank_of_ent[ent_of_row]
+    pos_of_row = _slot_positions(counts)
+    if index is not None:
+        key_ent = index.keys // sp.dim
+        key_gid = (index.keys % sp.dim).astype(np.int32)
+        key_slot = _slot_positions(local_dims)
 
     buckets: List[REBucket] = []
     entity_to_slot: Dict = {}
-    for b, members in enumerate(splits):
+    for b in range(len(cuts) - 1):
+        members = ent_order[cuts[b]:cuts[b + 1]]
         E = len(members)
-        N = max(int(counts[members].max()), 1)
-        if projection == "random":
-            D = projection_dim
-        else:
-            D = max(max(len(local_maps[e]) for e in members), 1)
-        k = sp.indices.shape[1]
+        if E == 0:
+            continue
+        N = _padded_rows(counts[members].max())
+        D = max(int(local_dims[members].max()), 1)
         indices = np.zeros((E, N, k), np.int32)
         values = np.zeros((E, N, k))
         lab = np.zeros((E, N))
         wts = np.zeros((E, N))
         sidx = np.full((E, N), -1, np.int32)
         proj = np.full((E, D), -1, np.int32)
-        eids = []
-        for r, e in enumerate(members):
-            rows = active_rows[e]
-            m = len(rows)
-            lm = local_maps[e]
-            loc, row_val = _remap_to_local(sp.indices[rows], sp.values[rows], lm)
-            indices[r, :m] = loc
-            values[r, :m] = row_val
-            lab[r, :m] = labels[rows]
-            wts[r, :m] = weights[rows]
-            sidx[r, :m] = rows
-            if not isinstance(lm, SketchProjection):
-                for gid, slot in lm.items():
-                    proj[r, slot] = gid
-            eids.append(uniq[e])
-            entity_to_slot[uniq[e]] = (b, r)
+        sel = np.flatnonzero(bucket_of_row == b)
+        r, p = rank_of_row[sel], pos_of_row[sel]
+        indices[r, p] = loc[sel]
+        values[r, p] = val[sel]
+        lab[r, p] = labels[rows_flat[sel]]
+        wts[r, p] = weights[rows_flat[sel]]
+        sidx[r, p] = rows_flat[sel]
+        if index is not None:
+            ksel = np.flatnonzero(bucket_of_ent[key_ent] == b)
+            proj[rank_of_ent[key_ent[ksel]], key_slot[ksel]] = key_gid[ksel]
+            local_maps = [
+                dict(zip(g[g >= 0].tolist(), range(int((g >= 0).sum()))))
+                for g in proj]
+        else:
+            local_maps = [sketch] * E
+        eids = [uniq[e] for e in members]
+        for r_e, eid in enumerate(eids):
+            entity_to_slot[eid] = (len(buckets), r_e)
         buckets.append(
-            REBucket(eids, indices, values, lab, wts, sidx, proj,
-                     [local_maps[e] for e in members])
-        )
+            REBucket(eids, indices, values, lab, wts, sidx, proj, local_maps))
     return RandomEffectTrainData(effect_name, buckets, n, entity_to_slot)
 
 
@@ -378,18 +522,66 @@ def build_score_buckets(
     return out
 
 
+def _entity_slots(train_data: RandomEffectTrainData, entity_ids):
+    """Each row's entity as its number among the training entities,
+    counted bucket by bucket; -1 for an entity training never saw. Ids
+    are matched as they are, then as strings (a loaded model keys its
+    entities as ``str``), as ``group_rows_by_slot`` does."""
+    ids = np.asarray(entity_ids)
+    known = [np.asarray(b.entity_ids) for b in train_data.buckets
+             if b.num_entities]
+    if not known or not len(ids):
+        return np.full(len(ids), -1, np.int64)
+    known = np.concatenate(known)
+    if known.dtype.kind != ids.dtype.kind and "U" in (known.dtype.kind,
+                                                      ids.dtype.kind):
+        known, ids = known.astype(str), ids.astype(str)
+    sorter = np.argsort(known, kind="mergesort")
+    pos = np.minimum(np.searchsorted(known, ids, sorter=sorter),
+                     len(known) - 1)
+    hit = known[sorter[pos]] == ids
+    return np.where(hit, sorter[pos], -1)
+
+
 def build_score_view(
     train_data: RandomEffectTrainData, features, entity_ids: Sequence
 ) -> List[REScoreBucket]:
     """Project any dataset onto the training-time entity subspaces for
     device-side scoring. Rows of entities unseen in training contribute no
     score; features outside an entity's subspace are dropped (their
-    coefficient is structurally zero — projector semantics)."""
-    sp = host_sparse_from_features(features)
-    per_bucket_rows = group_rows_by_slot(
-        entity_ids, train_data.entity_to_slot,
-        [b.num_entities for b in train_data.buckets],
-    )
-    return build_score_buckets(
-        sp, per_bucket_rows, [b.local_maps for b in train_data.buckets]
-    )
+    coefficient is structurally zero — projector semantics). Whole-array
+    numpy: one sort of the rows by entity, one lookup of every feature."""
+    sp = materialize_ones(host_sparse_from_features(features))
+    buckets = train_data.buckets
+    sizes = np.array([b.num_entities for b in buckets], np.int64)
+    ends = np.cumsum(sizes)
+    slot_of_row = _entity_slots(train_data, entity_ids)
+    rows = np.flatnonzero(slot_of_row >= 0)
+    rows = rows[np.argsort(slot_of_row[rows], kind="mergesort")]
+    ent_of_row = slot_of_row[rows]
+    counts = np.bincount(ent_of_row, minlength=int(sizes.sum()))
+    pos_of_row = _slot_positions(counts)
+    row_idx, row_val = sp.indices[rows], sp.values[rows]
+    lm0 = next((b.local_maps[0] for b in buckets if b.num_entities), None)
+    if isinstance(lm0, SketchProjection):
+        loc, val = _remap_to_local(row_idx, row_val, lm0)
+    else:
+        index = _SubspaceIndex.from_projections(
+            [b.projection for b in buckets], sp.dim)
+        loc, val = index.remap(ent_of_row, row_idx, row_val)
+    bucket_of_row = np.searchsorted(ends, ent_of_row, side="right")
+    k = sp.indices.shape[1]
+    out: List[REScoreBucket] = []
+    for b, E in enumerate(sizes):
+        base = int(ends[b] - E)
+        M = _padded_rows(counts[base:base + E].max() if E else 0)
+        indices = np.zeros((E, M, k), np.int32)
+        values = np.zeros((E, M, k))
+        sidx = np.full((E, M), -1, np.int32)
+        sel = np.flatnonzero(bucket_of_row == b)
+        r, p = ent_of_row[sel] - base, pos_of_row[sel]
+        indices[r, p] = loc[sel]
+        values[r, p] = val[sel]
+        sidx[r, p] = rows[sel]
+        out.append(REScoreBucket(indices, values, sidx))
+    return out

@@ -25,6 +25,7 @@ on raw features match the normalized-training margins exactly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -48,6 +49,10 @@ from photon_ml_tpu.game.data import (
     host_sparse_from_features,
 )
 from photon_ml_tpu.game.random_effect import (
+    upload,
+    fetch,
+    place_random_effect,
+    place_score_view,
     score_random_effect,
     train_random_effect,
 )
@@ -60,6 +65,7 @@ from photon_ml_tpu.models import (
     RandomEffectBucket,
     RandomEffectModel,
 )
+from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.obs import metrics as obs_metrics
 from photon_ml_tpu.obs import trace as obs_trace
@@ -68,6 +74,7 @@ from photon_ml_tpu.ops.regularization import RegularizationContext, Regularizati
 from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
 from photon_ml_tpu.parallel import fault_injection
 from photon_ml_tpu.parallel.data_parallel import (
+    cached_jit,
     distributed_hvp,
     distributed_value_and_grad,
 )
@@ -258,6 +265,11 @@ def _device_features(sp: HostSparse, dtype) -> SparseFeatures:
 # across chunks and CD iterations)
 _margins_jit = jax.jit(_margins)
 
+
+@jax.named_scope("photon.cd/fe_rescore")
+def _fe_rescore(features, w_model):
+    return _margins(features, w_model)
+
 _log = logging.getLogger(__name__)
 
 
@@ -303,15 +315,51 @@ class _ResidualTotal:
             "cd.residual_resync", self._recompute, scores)
 
     def _recompute(self, scores: Dict[str, jax.Array]):
-        return self.base + sum(scores.values())
+        return _residual_sum(self.base, tuple(scores.values()))
 
     def excluding(self, name: str, scores: Dict[str, jax.Array]):
         """Residual offsets for one coordinate: everything but its own
         scores."""
-        return self.total - scores[name]
+        return _residual_excluding(self.total, scores[name])
 
-    def replace(self, old_scores, new_scores) -> None:
-        self.total = self.total - old_scores + new_scores
+    def replace(self, old_scores, new_scores):
+        """Swap one coordinate's scores in the total. Returns the
+        largest absolute change of a score, as a device scalar (one
+        program does both)."""
+        if not old_scores.shape[0]:
+            return jnp.zeros((), old_scores.dtype)
+        self.total, delta = _residual_replace(self.total, old_scores,
+                                              new_scores)
+        return delta
+
+
+@functools.lru_cache(maxsize=None)
+def _train_loss_program(task: str):
+    loss = get_loss(task)
+
+    @jax.named_scope("photon.cd/train_loss")
+    def photon_cd_train_loss(total, labels, weights):
+        return jnp.sum(weights * loss.loss(total, labels))
+
+    return jax.jit(photon_cd_train_loss)
+
+
+@jax.jit
+@jax.named_scope("photon.cd/residual")
+def _residual_sum(base, scores):
+    return base + sum(scores)
+
+
+@jax.jit
+@jax.named_scope("photon.cd/residual")
+def _residual_excluding(total, own):
+    return total - own
+
+
+@jax.jit
+@jax.named_scope("photon.cd/residual")
+def _residual_replace(total, old, new):
+    return total - old + new, jnp.max(jnp.abs(new - old))
 
 
 def _drift_active_masks(buckets, frozen, offs_np: np.ndarray,
@@ -503,12 +551,15 @@ class _FixedState:
                 )
                 # sorted once here; offsets change per CD iteration, the
                 # sparsity pattern never does
-                csc = jax.jit(build)(
+                csc = cached_jit(self.obj, ("cd_build_csc", sparse_grad),
+                                 lambda: build)(
                     LabeledBatch(feats, labels, jnp.zeros_like(labels), weights)
                 )
+                fit_data = (feats, labels, weights, csc)
 
                 def _make_fit(run_cfg):
-                    def _fit(w0, offs, l2, l1):
+                    def _fit(w0, offs, l2, l1, data):
+                        feats, labels, weights, csc = data
                         batch = LabeledBatch(feats, labels, offs, weights)
                         fg = lambda w: fg_csc(w, batch, csc, l2)
                         if optimizer == "owlqn":
@@ -522,9 +573,11 @@ class _FixedState:
                 fg_dist = distributed_value_and_grad(self.obj, mesh)
                 hvp_dist = distributed_hvp(self.obj, mesh) if optimizer == "tron" else None
 
+                fit_data = (feats, labels, weights)
+
                 def _make_fit(run_cfg):
-                    def _fit(w0, offs, l2, l1):
-                        batch = LabeledBatch(feats, labels, offs, weights)
+                    def _fit(w0, offs, l2, l1, data):
+                        batch = LabeledBatch(data[0], data[1], offs, data[2])
                         fg = lambda w: fg_dist(w, batch, l2)
                         if optimizer == "owlqn":
                             return opt(fg, w0, l1, run_cfg, l1_mask=l1_mask)
@@ -535,10 +588,11 @@ class _FixedState:
                     return _fit
         else:
             self._offset_sharding = None
+            fit_data = (feats, labels, weights)
 
             def _make_fit(run_cfg):
-                def _fit(w0, offs, l2, l1):
-                    batch = LabeledBatch(feats, labels, offs, weights)
+                def _fit(w0, offs, l2, l1, data):
+                    batch = LabeledBatch(data[0], data[1], offs, data[2])
                     fg = lambda w: self.obj.value_and_grad(w, batch, l2)
                     if optimizer == "owlqn":
                         return opt(fg, w0, l1, run_cfg, l1_mask=l1_mask)
@@ -553,6 +607,8 @@ class _FixedState:
         else:
             self.full_features = _device_features(sp, dtype)
         self._batch_parts = (feats, labels, weights)
+        self._fit_data = fit_data
+        self._fit_name = f"cd_fit_{optimizer}"
         self._install_fit(_make_fit, cfg_opt, needs_jit=True)
 
     def _init_out_of_core(self, cfg: CoordinateConfig, data: GameDataset,
@@ -665,7 +721,9 @@ class _FixedState:
         for in-memory paths, jitted) fit functions are memoized per config
         so an inexact-CD tolerance schedule pays one compile per distinct
         tolerance — a bounded set, since the schedule clamps at the final
-        tolerance (optimize.ToleranceSchedule)."""
+        tolerance (optimize.ToleranceSchedule). A jitted fit takes the
+        resident batch (and its CSC view) as an argument, not as a
+        closure: the rows are no constants of the program."""
         self._make_fit = make_fit
         self._base_opt_config = base_config
         self._fit_needs_jit = needs_jit
@@ -678,9 +736,23 @@ class _FixedState:
         if fn is None:
             fn = self._make_fit(run_cfg)
             if self._fit_needs_jit:
-                fn = jax.jit(fn)
+                jitted = cached_jit(self.obj, (self._fit_name, run_cfg),
+                                    lambda: fn)
+                fn = lambda *args: jitted(*args, self._fit_data)
             self._fit_cache[run_cfg] = fn
         return fn
+
+    def rebind(self, cfg: CoordinateConfig) -> None:
+        """Start another run over the same resident batch: a grid point
+        differs in what the fit is handed (regularisation weights, the
+        iteration cap), so everything built stays."""
+        reg = cfg.reg_context()
+        self.cfg = cfg
+        self.l2 = reg.l2_weight(cfg.reg_weight)
+        self.l1 = reg.l1_weight(cfg.reg_weight)
+        self._base_opt_config = cfg.opt_config()
+        self.w = None
+        self.variances = None
 
     def fit(self, offsets_full: jax.Array, opt_config=None):
         offs = jnp.take(offsets_full, self.train_rows, axis=0).astype(self.dtype)
@@ -736,7 +808,9 @@ class _FixedState:
         compute, and the device->host fetch of chunk i-1 overlaps chunk
         i's dispatch — so no device-resident feature copy ever exists."""
         if not self.streaming:
-            return _margins(self.full_features, w_model)
+            return cached_jit(self.obj, ("fe_rescore",),
+                              lambda: _fe_rescore)(self.full_features,
+                                                   w_model)
         from photon_ml_tpu.parallel.multihost import (
             allgather_spans,
             allgather_varspans,
@@ -820,13 +894,31 @@ class _RandomState:
             self.train_view = build_score_view(self.train_data, sp, ids)
             if cache is not None:
                 cache[key] = (data, self.train_data, self.train_view)
+        # the tables and their score view live in device memory for as
+        # long as the cache does: placed once, read by every sweep of
+        # every run over this data
+        placed_key = key + ("placed", jnp.dtype(dtype).name)
+        if cache is not None and placed_key in cache:
+            self.placed, self.placed_view, self._rows = cache[placed_key]
+        else:
+            self.placed = place_random_effect(self.train_data, dtype)
+            self.placed_view = place_score_view(
+                self.train_view, data.num_samples, dtype)
+            # real rows an entity, for the sweep record's slot counts
+            self._rows = [(b.sample_idx >= 0).sum(axis=1)
+                          for b in self.train_data.buckets]
+            if cache is not None:
+                cache[placed_key] = (self.placed, self.placed_view,
+                                     self._rows)
         # fail BEFORE the first sweep when the local entity table is over
         # the per-process budget (points at --entity-shards)
         check_table_budget(
             self.train_data.table_bytes(), table_budget_bytes,
             coordinate=cfg.name,
             num_shards=1 if entity_shard is None else entity_shard.num_shards)
-        self.coeffs: Optional[List[np.ndarray]] = None
+        # per-bucket [E, D] device arrays from sweep to sweep; host copies
+        # are made where a model is built or a snapshot taken
+        self.coeffs: Optional[List[jax.Array]] = None
         self.variances = None
         # active-set tracking across sweeps: per-bucket boolean masks of
         # FROZEN entities (solver reported converged at their last solve);
@@ -841,6 +933,18 @@ class _RandomState:
         # assembled GLOBAL vector on every process.
         self.local_scores: Optional[jax.Array] = None
         self.local_val_scores: Optional[jax.Array] = None
+
+    def row_slots(self, active=None) -> Tuple[int, int]:
+        """(real rows, row slots) of the entities a solve reads: all, or
+        those of the per-bucket ``active`` masks."""
+        real = slots = 0
+        for b, bucket in enumerate(self.train_data.buckets):
+            rows = self._rows[b]
+            if active is not None and active[b] is not None:
+                rows = rows[np.asarray(active[b], bool)]
+            real += int(rows.sum())
+            slots += len(rows) * bucket.sample_idx.shape[1]
+        return real, slots
 
 
 class CoordinateDescent:
@@ -932,7 +1036,7 @@ class CoordinateDescent:
         val_feats: Dict[str, SparseFeatures] = {}
         for cfg in self.configs:
             if cfg.coordinate_type == "fixed":
-                states[cfg.name] = _FixedState(cfg, train, dtype, self.task, self.mesh)
+                states[cfg.name] = self._fixed_state(cfg, train)
                 if validation is not None:
                     val_feats[cfg.name] = _device_features(
                         validation.features[cfg.feature_shard], dtype
@@ -974,7 +1078,7 @@ class CoordinateDescent:
                             jnp.zeros((val_n,), dtype) if has_val
                             else val_scores[cfg.name]))
 
-        base = jnp.asarray(train.offsets, dtype)
+        base = upload(train.offsets, dtype)
         history: List[dict] = []
         evaluators = [get_evaluator(e) for e in self.evaluator_names]
         entity_mesh = (self.mesh if self.mesh is not None
@@ -1020,20 +1124,35 @@ class CoordinateDescent:
         _eps = float(jnp.finfo(dtype).eps)
         stop_reason = "max_iterations"
 
+        tm = obs_metrics.training_metrics()
+        labels_dev, weights_dev = self._device_labels(train)
+
         def _one_sweep(it: int) -> bool:
             # One full CD sweep; True means the cd_tolerance early exit
             # fired. A closure (not a plain loop body) so the recovery
             # wrapper below can re-run a sweep from a restored snapshot.
             nonlocal stop_reason
+            t_sweep = time.time()
+            moved0 = tm.transfer_counts()
             rt.resync(scores)
             if vt is not None:
                 vt.resync(val_scores)
             sweep_deltas: Dict[str, float] = {}
+            steps: List[dict] = []
             for cfg in self.configs:
                 st = states[cfg.name]
                 t0 = time.time()
                 offs = rt.excluding(cfg.name, scores)
                 record = {"iteration": it, "coordinate": cfg.name}
+                # what the sweep record keeps of this step (obs.metrics)
+                step = {"name": cfg.name, "type": cfg.coordinate_type,
+                        "fit_seconds": 0.0, "rescore_seconds": 0.0}
+                if cfg.coordinate_type == "random":
+                    # a locked or wholly frozen coordinate solves nothing
+                    step.update(entities_solved=0, iterations_sum=0,
+                                iterations_max=0, real_slots=0,
+                                padded_slots=0, blocks=0,
+                                buckets=len(st.train_data.buckets))
                 run_cfg = None
                 if self.solver_tol_schedule is not None:
                     run_cfg = dataclasses.replace(
@@ -1056,10 +1175,14 @@ class CoordinateDescent:
                     if cfg.name not in locked:
                         if cfg.coordinate_type == "fixed":
                             res = st.fit(offs, opt_config=run_cfg)
+                            # the fetch waits for the fit
                             record.update(
-                                loss=float(res.value), converged=bool(res.converged),
-                                optimizer_iterations=int(res.iterations),
+                                loss=float(fetch(res.value)),
+                                converged=bool(fetch(res.converged)),
+                                optimizer_iterations=int(
+                                    fetch(res.iterations)),
                             )
+                            step["fit_seconds"] = time.time() - t0
                             if res.stream_stats is not None:
                                 # streamed fixed effects: per-fit pipeline
                                 # stall breakdown (decode-wait / transfer /
@@ -1068,10 +1191,14 @@ class CoordinateDescent:
                                 record["comm_seconds"] = (
                                     res.stream_stats.get("comm_s", 0.0))
                             w_model = st.model_space_w()
-                            new_scores = st.train_scores(w_model)
-                            score_delta = float(jnp.max(jnp.abs(
-                                new_scores - scores[cfg.name]))) if n else 0.0
-                            rt.replace(scores[cfg.name], new_scores)
+                            t_r = time.time()
+                            with obs_trace.span("fe.rescore", cat="train",
+                                                coordinate=cfg.name,
+                                                iteration=it):
+                                new_scores = st.train_scores(w_model)
+                                score_delta = float(fetch(rt.replace(
+                                    scores[cfg.name], new_scores)))
+                            step["rescore_seconds"] = time.time() - t_r
                             scores[cfg.name] = new_scores
                             if validation is not None:
                                 new_v = _margins(val_feats[cfg.name], w_model)
@@ -1082,7 +1209,7 @@ class CoordinateDescent:
                             score_delta = self._random_step(
                                 cfg, st, it, offs, run_cfg, scores,
                                 val_scores, val_states, rt, vt, n, val_n,
-                                validation, entity_mesh, _eps, record)
+                                validation, entity_mesh, _eps, record, step)
                     # comm_seconds rides every record (next to the solve/
                     # eval split): cross-shard score-exchange seconds for
                     # sharded random coordinates, the streamed pass's
@@ -1109,14 +1236,29 @@ class CoordinateDescent:
                     record["seconds"] = time.time() - t0
                     record["score_delta"] = score_delta
                     sweep_deltas[cfg.name] = score_delta
-                obs_metrics.training_metrics().record_step(
+                tm.record_step(
                     cfg.name, record["solve_seconds"],
                     record["eval_seconds"], record["comm_seconds"])
+                step["seconds"] = record["seconds"]
+                steps.append(step)
                 # coordinate identity rides the record dict + the
                 # obs.logging rank/trace stamps, not a hand-rolled prefix
                 _log.log(logging.INFO if self.verbose else logging.DEBUG,
                          "cd.step %s", record)
                 history.append(record)
+            # the weighted training loss at the sweep's end: the one number
+            # every block's step should have lowered
+            train_loss = float(fetch(_train_loss_program(self.task)(
+                rt.total, labels_dev, weights_dev)))
+            if history:
+                history[-1]["train_loss"] = train_loss
+            moved1 = tm.transfer_counts()
+            tm.record_sweep({
+                "iteration": it, "seconds": time.time() - t_sweep,
+                "train_loss": train_loss,
+                "h2d_bytes": moved1[0] - moved0[0],
+                "d2h_bytes": moved1[1] - moved0[1],
+                "compiles": moved1[2] - moved0[2], "coordinates": steps})
             if checkpoint_callback is not None:
                 # coarse-grained per-outer-iteration checkpoint (the
                 # reference's per-stage HDFS writes — SURVEY.md §5.4)
@@ -1148,7 +1290,8 @@ class CoordinateDescent:
                     # every survivor agrees on the committed sweep)
                     recovery.commit(it, lambda: self._recovery_payload(
                         states, scores, val_scores, validation))
-                stop = _one_sweep(it)
+                with obs_trace.span("cd.sweep", cat="train", iteration=it):
+                    stop = _one_sweep(it)
                 it += 1
                 if stop:
                     break
@@ -1181,6 +1324,45 @@ class CoordinateDescent:
         return model, history
 
     # -- helpers ---------------------------------------------------------
+    def _device_labels(self, train: GameDataset):
+        """Labels and weights in device memory, once a data set where
+        there is a ``dataset_cache``."""
+        cache = self.dataset_cache
+        key = ("cd_labels", id(train), jnp.dtype(self.dtype).name)
+        if cache is not None and key in cache:
+            return cache[key][1:]
+        placed = (upload(train.labels, self.dtype),
+                  upload(train.weights, self.dtype))
+        if cache is not None:
+            cache[key] = (train,) + placed
+        return placed
+
+    def _fixed_state(self, cfg: CoordinateConfig, train: GameDataset):
+        """The coordinate's ``_FixedState``, from ``dataset_cache`` where a
+        run over the same data has built it: the device copy of the shard,
+        its CSC view and the jitted fits are a grid point's to reuse, not
+        to rebuild. Keyed by everything the built state depends on;
+        streamed and out-of-core shards hold no such state."""
+        cache = self.dataset_cache
+        if (cache is None or cfg.streaming
+                or (train.feature_sources or {}).get(cfg.feature_shard)
+                is not None):
+            return _FixedState(cfg, train, self.dtype, self.task, self.mesh)
+        reg = cfg.reg_context()
+        key = ("fixed_state", id(train), cfg.name, cfg.feature_shard,
+               cfg.optimizer, reg.l1_weight(cfg.reg_weight) > 0,
+               cfg.sparse_grad, cfg.down_sampling_rate, cfg.intercept_index,
+               id(cfg.normalization), jnp.dtype(self.dtype).name, self.task,
+               id(self.mesh))
+        if key in cache:
+            state = cache[key][-1]
+            state.rebind(cfg)
+            return state
+        state = _FixedState(cfg, train, self.dtype, self.task, self.mesh)
+        # the entry pins what the key names by id() against reuse
+        cache[key] = (train, cfg.normalization, self.mesh, state)
+        return state
+
     def _build_random_states(self, train, validation, states, val_states):
         """(Re)build every random coordinate's ``_RandomState`` and
         validation score view against the CURRENT ``self.entity_shard``.
@@ -1196,14 +1378,17 @@ class CoordinateDescent:
                 table_budget_bytes=self.entity_table_budget_bytes)
             if validation is not None:
                 st: _RandomState = states[cfg.name]
-                key = ("val_view", id(validation), id(st.train_data))
+                key = ("val_view", id(validation), id(st.train_data),
+                       jnp.dtype(self.dtype).name)
                 cache = self.dataset_cache
                 if cache is not None and key in cache:
                     val_states[cfg.name] = cache[key][2]
                 else:
                     sp = validation.features[cfg.feature_shard]
                     ids = validation.entity_ids[cfg.entity_column]
-                    val_states[cfg.name] = build_score_view(st.train_data, sp, ids)
+                    val_states[cfg.name] = place_score_view(
+                        build_score_view(st.train_data, sp, ids),
+                        validation.num_samples, self.dtype)
                     if cache is not None:
                         # pin both keyed objects against id() recycling
                         cache[key] = (validation, st.train_data,
@@ -1211,7 +1396,7 @@ class CoordinateDescent:
 
     def _random_step(self, cfg, st, it, offs, run_cfg, scores, val_scores,
                      val_states, rt, vt, n, val_n, validation, entity_mesh,
-                     eps, record) -> float:
+                     eps, record, step) -> float:
         """One random-effect coordinate step with active-set freezing and
         incremental rescoring. Returns the coordinate's score delta.
 
@@ -1231,7 +1416,7 @@ class CoordinateDescent:
         offs_np = None
         solve = True
         if not refresh:
-            offs_np = np.asarray(offs)
+            offs_np = fetch(offs)
             tol = (cfg.active_tol if cfg.active_tol is not None else 0.0)
             # floor at a few ulps of the working dtype: comparing offsets
             # for bit-stability at a tolerance below the arithmetic noise
@@ -1255,21 +1440,39 @@ class CoordinateDescent:
         new_val_local = None
         if solve:
             reg = cfg.reg_context()
-            fit = train_random_effect(
-                st.train_data, offs, task=self.task,
-                l2=reg.l2_weight(cfg.reg_weight),
-                l1=reg.l1_weight(cfg.reg_weight),
-                optimizer=cfg.optimizer,
-                config=run_cfg if run_cfg is not None else cfg.opt_config(),
-                w0=st.coeffs, mesh=entity_mesh,
-                compute_variance=cfg.compute_variance, dtype=self.dtype,
-                normalization=cfg.normalization,
-                active=active, prev_variances=st.variances,
-            )
+            t_s = time.time()
+            with obs_trace.span("re.solve", cat="train", coordinate=cfg.name,
+                                iteration=it,
+                                buckets=len(st.train_data.buckets)) as sp:
+                fit = train_random_effect(
+                    st.train_data, offs, task=self.task,
+                    l2=reg.l2_weight(cfg.reg_weight),
+                    l1=reg.l1_weight(cfg.reg_weight),
+                    optimizer=cfg.optimizer,
+                    config=run_cfg if run_cfg is not None
+                    else cfg.opt_config(),
+                    w0=st.coeffs, mesh=entity_mesh,
+                    compute_variance=cfg.compute_variance, dtype=self.dtype,
+                    normalization=cfg.normalization,
+                    active=active, prev_variances=st.variances,
+                    placed=st.placed,
+                )
+                # the span closes on a fetched value: the solves have run
+                counts = fit.counts()
+                sp.set(entities=fit.entities_solved, blocks=fit.blocks,
+                       iterations=counts["iterations_sum"])
+            real, slots = st.row_slots(active)
+            step["fit_seconds"] = time.time() - t_s
+            step.update(
+                entities_solved=fit.entities_solved,
+                iterations_sum=counts["iterations_sum"],
+                iterations_max=counts["iterations_max"],
+                real_slots=real, padded_slots=slots - real,
+                buckets=len(st.train_data.buckets), blocks=fit.blocks)
             if cfg.active_set:
-                st.frozen = [np.asarray(c) for c in fit.converged]
+                st.frozen = [fetch(c) for c in fit.converged]
                 if offs_np is None:
-                    offs_np = np.asarray(offs)
+                    offs_np = fetch(offs)
                 if active is None or st.offs_snap is None:
                     st.offs_snap = np.array(offs_np, copy=True)
                 else:
@@ -1284,8 +1487,11 @@ class CoordinateDescent:
             st.coeffs = fit.coefficients
             st.variances = fit.variances
             record.update(
-                converged_fraction=fit.converged_fraction,
-                mean_optimizer_iterations=fit.mean_iterations,
+                converged_fraction=(counts["converged"]
+                                    / max(fit.entities, 1)),
+                mean_optimizer_iterations=(
+                    counts["iterations_sum"]
+                    / max(fit.entities_solved, 1)),
                 entities_solved=fit.entities_solved,
                 refresh=bool(refresh),
             )
@@ -1293,20 +1499,24 @@ class CoordinateDescent:
             # by re-solved entities are recomputed and scatter-overwritten
             # into the previous score vector (the LOCAL vector when
             # sharded — unowned rows stay zero there)
-            new_local = score_random_effect(
-                st.train_view, st.coeffs, n, self.dtype,
-                prev=None if active is None else prev_local,
-                changed=active)
-            if validation is not None and cfg.name in val_states:
-                new_val_local = score_random_effect(
-                    val_states[cfg.name], st.coeffs, val_n, self.dtype,
-                    prev=None if active is None else prev_val_local,
+            t_r = time.time()
+            with obs_trace.span("re.rescore", cat="train",
+                                coordinate=cfg.name, iteration=it):
+                new_local = score_random_effect(
+                    st.placed_view, st.coeffs, n, self.dtype,
+                    prev=None if active is None else prev_local,
                     changed=active)
+                if validation is not None and cfg.name in val_states:
+                    new_val_local = score_random_effect(
+                        val_states[cfg.name], st.coeffs, val_n, self.dtype,
+                        prev=None if active is None else prev_val_local,
+                        changed=active)
+                if not sharded:
+                    delta = float(fetch(
+                        rt.replace(scores[cfg.name], new_local)))
+            step["rescore_seconds"] = time.time() - t_r
 
         if not sharded:
-            delta = (float(jnp.max(jnp.abs(new_local - scores[cfg.name])))
-                     if n else 0.0)
-            rt.replace(scores[cfg.name], new_local)
             scores[cfg.name] = new_local
             if new_val_local is not None:
                 if vt is not None:
@@ -1325,9 +1535,7 @@ class CoordinateDescent:
                 val_scores[cfg.name]))
         record["comm_seconds"] = comm_s
         record["comm_bytes"] = comm_bytes
-        delta = (float(jnp.max(jnp.abs(new_global - scores[cfg.name])))
-                 if n else 0.0)
-        rt.replace(scores[cfg.name], new_global)
+        delta = float(fetch(rt.replace(scores[cfg.name], new_global)))
         scores[cfg.name] = new_global
         if has_val:
             if vt is not None:
@@ -1396,9 +1604,10 @@ class CoordinateDescent:
                     buckets.append(
                         RandomEffectBucket(
                             entity_ids=bucket.entity_ids,
-                            coefficients=st.coeffs[b],
+                            coefficients=fetch(st.coeffs[b]),
                             projection=bucket.projection,
-                            variances=None if st.variances is None else st.variances[b],
+                            variances=(None if st.variances is None
+                                       else fetch(st.variances[b])),
                             sketch=lm0 if isinstance(lm0, SketchProjection) else None,
                         )
                     )
@@ -1595,7 +1804,7 @@ class CoordinateDescent:
                 st.offs_snap = merged_offs
             if self._sharded:
                 st.local_scores = score_random_effect(
-                    st.train_view, st.coeffs, n, dtype)
+                    st.placed_view, st.coeffs, n, dtype)
                 st.local_val_scores = (
                     score_random_effect(val_states[cfg.name], st.coeffs,
                                         val_n, dtype)
@@ -1638,7 +1847,7 @@ class CoordinateDescent:
                 coeffs = _coeffs_from_prev(prev, st.train_data)
                 st.coeffs = coeffs
                 scores[cfg.name] = score_random_effect(
-                    st.train_view, coeffs, train.num_samples, self.dtype
+                    st.placed_view, coeffs, train.num_samples, self.dtype
                 )
                 if validation is not None and cfg.name in val_states:
                     val_scores[cfg.name] = score_random_effect(

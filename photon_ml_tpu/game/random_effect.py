@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import List, Optional, Sequence
 
 import jax
@@ -25,6 +24,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.analysis.sanitizers import nan_guard_check
 from photon_ml_tpu.game.data import RandomEffectTrainData, REScoreBucket
+from photon_ml_tpu.obs import trace as obs_trace
+from photon_ml_tpu.obs.metrics import training_metrics
 from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.objective import make_objective
@@ -32,19 +33,10 @@ from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
 from photon_ml_tpu.types import LabeledBatch, SparseFeatures
 
 
-@dataclasses.dataclass(frozen=True)
-class RandomEffectFitResult:
-    coefficients: List[np.ndarray]  # per bucket [E, D]
-    variances: Optional[List[np.ndarray]]
-    converged_fraction: float
-    mean_iterations: float  # over the entities actually solved this call
-    # per-entity detail (one array per bucket): the active-set CD loop uses
-    # these to decide which entities to freeze between sweeps. Entities not
-    # re-solved this call (active-set frozen) report converged=True and
-    # iterations=0 — their objective was not touched.
-    converged: Optional[List[np.ndarray]] = None  # bool [E] per bucket
-    iterations: Optional[List[np.ndarray]] = None  # int32 [E] per bucket
-    entities_solved: int = 0
+# f32 contractions in full precision: the TPU's default rounds the
+# operands of an f32 matmul to bfloat16, which a Newton step on D <= 128
+# columns cannot afford and does not need to (the MXU work is tiny)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _newton_dense_solver(local_dim: int, task: str,
@@ -54,11 +46,14 @@ def _newton_dense_solver(local_dim: int, task: str,
 
     Per-entity dims are small (subspace-projected, typically ≤ 64), so the
     whole bucket solves as BATCHED DENSE linear algebra instead of a
-    ``vmap`` of sparse L-BFGS loops: rows densify once to ``X [E, N, D]``
+    ``vmap`` of sparse L-BFGS loops: rows densify once to ``X [E, D, N]``
     (a k-step scan, no scatter), every Newton iteration is two einsums
     (gradient ``X^T d1``, Hessian ``X^T diag(d2) X`` — MXU contractions)
     plus one batched SPD solve, and a 4-level per-entity step-halving
-    safeguard keeps descent monotone. A vmapped L-BFGS executes all
+    safeguard keeps descent monotone. The returned ``solve`` carries its
+    two halves, ``solve.densify`` and ``solve.solve_dense``, so a caller
+    that keeps ``X`` between sweeps runs the second alone. A vmapped
+    L-BFGS executes all
     entities' line searches in lockstep on the VPU; this formulation puts
     the FLOPs where the TPU wants them (same trade the reference's local
     Breeze Newton solvers make per executor, batched instead of mapped).
@@ -71,43 +66,47 @@ def _newton_dense_solver(local_dim: int, task: str,
     loss = get_loss(task)
     tol = config.tolerance
     max_iters = config.max_iters
+    # match_vma: under the entity-axis shard_map the data varies over
+    # the mesh axis but fresh zeros/True carries do not; align every
+    # loop carry or scan/while_loop reject the carry types (no-op
+    # outside shard_map)
+    from photon_ml_tpu.optimize.common import match_vma, match_vma_tree
 
-    def solve(indices, values, labels, weights, offs, w0, f_loc, s_loc,
-              l2, l1):
-        del l1  # caller guarantees 0 (owlqn route)
-        E, N, kk = indices.shape
-        dt = values.dtype
-
-        # densify: X[e, n, idx[e, n, j]] += val[e, n, j], as a k-step scan
-        # of masked adds (no scatter — TPU scatter serializes). Padding
+    @jax.named_scope("photon.re/densify")
+    def densify(indices_t, values_t, f_loc, s_loc):
+        """Slot-major sparse rows ``[E, k, N]`` -> ``X [E, D, N]``: rows
+        along the minor axis, where the TPU keeps 128 lanes (a minor axis
+        of ``k`` or ``D`` entries would be padded to 128 in HBM)."""
+        E, kk, N = indices_t.shape
+        # X[e, idx[e, j, n], n] += val[e, j, n], as a k-step scan of
+        # masked adds (no scatter — TPU scatter serializes). Padding
         # slots carry value 0 and add nothing wherever they point.
-        iota = jnp.arange(D, dtype=indices.dtype)
+        iota = jnp.arange(D, dtype=indices_t.dtype)[None, :, None]
 
         def add_slot(X, j):
-            idx_j = jnp.take(indices, j, axis=2)[..., None]  # [E, N, 1]
-            val_j = jnp.take(values, j, axis=2)[..., None]
+            idx_j = jnp.take(indices_t, j, axis=1)[:, None, :]  # [E, 1, N]
+            val_j = jnp.take(values_t, j, axis=1)[:, None, :]
             return X + jnp.where(idx_j == iota, val_j, 0.0), None
 
-        # match_vma: under the entity-axis shard_map the data varies over
-        # the mesh axis but fresh zeros/True carries do not; align every
-        # loop carry or scan/while_loop reject the carry types (no-op
-        # outside shard_map)
-        from photon_ml_tpu.optimize.common import match_vma, match_vma_tree
-
-        X, _ = jax.lax.scan(add_slot,
-                            match_vma(jnp.zeros((E, N, D), dt), values),
-                            jnp.arange(kk))
+        X, _ = jax.lax.scan(
+            add_slot,
+            match_vma(jnp.zeros((E, D, N), values_t.dtype), values_t),
+            jnp.arange(kk))
         # normalization in data space: x' = (x - s) * f per local slot
         # (exactly the sparse path's effective-coefficient fold)
         if norm_mode == 2:
-            X = (X - s_loc[:, None, :]) * f_loc[:, None, :]
+            X = (X - s_loc[:, :, None]) * f_loc[:, :, None]
         elif norm_mode == 1:
-            X = X * f_loc[:, None, :]
+            X = X * f_loc[:, :, None]
+        return X
 
+    def solve_dense(X, labels, weights, offs, w0, l2):
+        E = X.shape[0]
+        dt = X.dtype
         live = weights != 0  # [E, N]; padding rows are inert
 
         def margins(W):
-            m = jnp.einsum("end,ed->en", X, W) + offs
+            m = jnp.einsum("edn,ed->en", X, W, precision=_EXACT) + offs
             return jnp.where(live, m, 0.0)  # mask BEFORE the loss
 
         def fval(W):
@@ -117,14 +116,41 @@ def _newton_dense_solver(local_dim: int, task: str,
 
         d1_fn = jax.grad(lambda m, y: jnp.sum(loss.loss(m, y)))
 
+        @jax.named_scope("photon.re/newton/grad_hess")
         def grad_hess(W):
             m = margins(W)
             wd1 = jnp.where(live, weights * d1_fn(m, labels), 0.0)
             wd2 = jnp.where(live, weights * loss.d2(m, labels), 0.0)
-            g = jnp.einsum("end,en->ed", X, wd1) + l2 * W
-            H = (jnp.einsum("end,en,enf->edf", X, wd2, X)
+            g = jnp.einsum("edn,en->ed", X, wd1, precision=_EXACT) + l2 * W
+            H = (jnp.einsum("edn,en,efn->edf", X, wd2, X, precision=_EXACT)
                  + l2 * jnp.eye(D, dtype=dt))
             return g, H
+
+        @jax.named_scope("photon.re/newton/solve")
+        def newton_step(H, g):
+            return jnp.linalg.solve(H, g[..., None])[..., 0]  # SPD batched
+
+        @jax.named_scope("photon.re/newton/halving")
+        def halving(W, f, step):
+            # per-entity step-halving: try alpha in {1, 1/2, 1/4, 1/8},
+            # keep the largest that does not increase f (batched, static).
+            # "Does not increase" to within the rounding of f itself: next
+            # to the optimum a Newton step lowers f by less than one ulp of
+            # it, and which way the sum's last bit falls is no verdict on
+            # the step
+            alphas = jnp.asarray([1.0, 0.5, 0.25, 0.125], dt)
+            f_tries = jnp.stack(
+                [fval(W - a * step) for a in alphas])  # [4, E]
+            noise = 4 * jnp.finfo(dt).eps * jnp.maximum(jnp.abs(f), 1.0)
+            ok = f_tries <= (f + noise)[None, :]
+            first_ok = jnp.argmax(ok, axis=0)  # first True, else 0
+            any_ok = jnp.any(ok, axis=0)
+            a_sel = jnp.where(any_ok, alphas[first_ok], 0.0)  # 0 = stall
+            f_new = jnp.where(any_ok,
+                              jnp.take_along_axis(
+                                  f_tries, first_ok[None, :], axis=0)[0],
+                              f)
+            return any_ok, a_sel, f_new
 
         f0 = fval(w0)
         g0, _ = grad_hess(w0)
@@ -142,20 +168,8 @@ def _newton_dense_solver(local_dim: int, task: str,
         def body(state):
             W, f, active, conv_seen, iters = state
             g, H = grad_hess(W)
-            step = jnp.linalg.solve(H, g[..., None])[..., 0]  # SPD batched
-            # per-entity step-halving: try alpha in {1, 1/2, 1/4, 1/8},
-            # keep the largest that does not increase f (batched, static)
-            alphas = jnp.asarray([1.0, 0.5, 0.25, 0.125], dt)
-            f_tries = jnp.stack(
-                [fval(W - a * step) for a in alphas])  # [4, E]
-            ok = f_tries <= f[None, :]
-            first_ok = jnp.argmax(ok, axis=0)  # first True, else 0
-            any_ok = jnp.any(ok, axis=0)
-            a_sel = jnp.where(any_ok, alphas[first_ok], 0.0)  # 0 = stall
-            f_new = jnp.where(any_ok,
-                              jnp.take_along_axis(
-                                  f_tries, first_ok[None, :], axis=0)[0],
-                              f)
+            step = newton_step(H, g)
+            any_ok, a_sel, f_new = halving(W, f, step)
             gnorm = jnp.linalg.norm(g, axis=1)
             # converged_check semantics, batched: |f_prev - f| <= tol *
             # max(|f_prev|, 1) OR gnorm <= tol * max(||g0||, 1). The
@@ -189,27 +203,36 @@ def _newton_dense_solver(local_dim: int, task: str,
 
         state = match_vma_tree(
             (jnp.asarray(w0, dt), f0, jnp.ones((E,), bool),
-             jnp.zeros((E,), bool), jnp.zeros((E,), jnp.int32)), values)
+             jnp.zeros((E,), bool), jnp.zeros((E,), jnp.int32)), X)
         W, f, active, conv_seen, iters = jax.lax.while_loop(cond, body,
                                                             state)
         converged = conv_seen
-        _, H_fin = grad_hess(W)
         if compute_variance:
             if compute_variance == "full":
+                _, H_fin = grad_hess(W)
                 Hinv = jnp.linalg.solve(
                     H_fin, jnp.broadcast_to(jnp.eye(D, dtype=dt),
                                             (E, D, D)))
                 var = jnp.diagonal(Hinv, axis1=1, axis2=2)
             else:
-                diag = jnp.einsum("end,en,end->ed", X,
+                diag = jnp.einsum("edn,en,edn->ed", X,
                                   jnp.where(live, weights
                                             * loss.d2(margins(W), labels),
-                                            0.0), X) + l2
+                                            0.0), X, precision=_EXACT) + l2
                 var = 1.0 / jnp.maximum(diag, jnp.finfo(dt).tiny)
         else:
             var = jnp.zeros((E, 0), dt)
         return W, var, converged, iters
 
+    def solve(indices, values, labels, weights, offs, w0, f_loc, s_loc,
+              l2, l1):
+        del l1  # caller guarantees 0 (owlqn route)
+        X = densify(jnp.swapaxes(indices, 1, 2), jnp.swapaxes(values, 1, 2),
+                    f_loc, s_loc)
+        return solve_dense(X, labels, weights, offs, w0, l2)
+
+    solve.densify = densify
+    solve.solve_dense = solve_dense
     return solve
 
 
@@ -259,8 +282,8 @@ def _solver_for_bucket(local_dim: int, task: str, optimizer: str,
     return jax.vmap(solve_one, in_axes=(0,) * 8 + (None, None))
 
 
-# Every jitted bucket solver ever built (both cached builders below append
-# exactly once per cache key). ``re_solver_compile_count`` sums their
+# Every jitted bucket solver ever built (the cached builders below append
+# theirs exactly once per cache key). ``re_solver_compile_count`` sums their
 # per-shape executable counts — the bench/test invariant that the active-set
 # path's power-of-two sub-bucket ladder stops compiling once warmed.
 _SOLVER_REGISTRY: List = []
@@ -277,15 +300,46 @@ def re_solver_compile_count() -> int:
     return total
 
 
+def _named(fn, name: str):
+    """``fn`` under a ``photon_*`` program name (the trace's ``XLA
+    Modules`` line shows ``jit_<name>``)."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 @functools.lru_cache(maxsize=256)
-def _jitted_solver(local_dim, task, optimizer, config, compute_variance,
-                   norm_mode=0):
-    """Cache the jitted per-bucket solver so repeated coordinate-descent
-    steps with identical shapes reuse one XLA compilation."""
-    fn = jax.jit(_solver_for_bucket(local_dim, task, optimizer, config,
-                                    compute_variance, norm_mode))
+def _jitted_placed_solver(local_dim, task, optimizer, config,
+                          compute_variance, norm_mode=0):
+    """The jitted per-bucket solver over a placed bucket's slot-major
+    tables ``[E, k, N]`` (cached, so repeated coordinate-descent steps with
+    identical shapes reuse one XLA compilation): row-major inside the
+    program, where the compiler is free to fuse the transpose away."""
+    solver = _solver_for_bucket(local_dim, task, optimizer, config,
+                                compute_variance, norm_mode)
+
+    def placed(indices_t, values_t, *rest):
+        return solver(jnp.swapaxes(indices_t, 1, 2),
+                      jnp.swapaxes(values_t, 1, 2), *rest)
+
+    fn = jax.jit(_named(placed, f"photon_re_solve_{optimizer}"))
     _SOLVER_REGISTRY.append(fn)
     return fn
+
+
+@functools.lru_cache(maxsize=256)
+def _jitted_newton_halves(local_dim, task, config, compute_variance,
+                          norm_mode=0):
+    """The dense-Newton solver as two programs, for a caller that keeps
+    ``X``: (densify, solve_dense)."""
+    solver = _newton_dense_solver(local_dim, task, config, compute_variance,
+                                  norm_mode)
+    densify = jax.jit(_named(solver.densify, "photon_re_densify"))
+    solve = jax.jit(_named(solver.solve_dense, "photon_re_solve_newton"))
+    _SOLVER_REGISTRY.extend((densify, solve))
+    return densify, solve
 
 
 @functools.lru_cache(maxsize=256)
@@ -301,7 +355,7 @@ def _jitted_sharded_solver(local_dim, task, optimizer, config, compute_variance,
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
         check_vma=False,
     )
-    fn = jax.jit(sharded)
+    fn = jax.jit(_named(sharded, f"photon_re_solve_{optimizer}_sharded"))
     _SOLVER_REGISTRY.append(fn)
     return fn
 
@@ -345,24 +399,22 @@ def _local_normalization(buckets, norm: NormalizationContext):
     return out
 
 
-def _re_to_training_space(W_raw: np.ndarray, f_loc, s_loc, pos) -> np.ndarray:
+def _re_to_training_space(W_raw, f_loc, s_loc, pos):
     """Per-entity inverse of the model-space fold (warm starts)."""
-    W = np.array(W_raw, np.float64, copy=True)
-    E = W.shape[0]
+    W = jnp.asarray(W_raw, f_loc.dtype)
     if s_loc is not None:
-        w_noint = W.copy()
-        w_noint[np.arange(E), pos] = 0.0
-        W[np.arange(E), pos] += np.sum(s_loc * w_noint, axis=1)
+        rows = jnp.arange(W.shape[0])
+        w_noint = W.at[rows, pos].set(0.0)
+        W = W.at[rows, pos].add(jnp.sum(s_loc * w_noint, axis=1))
     return W / f_loc
 
 
-def _re_to_model_space(W_opt: np.ndarray, f_loc, s_loc, pos) -> np.ndarray:
+def _re_to_model_space(W_opt, f_loc, s_loc, pos):
     """Optimizer-space bucket coefficients -> raw-feature space."""
-    W = np.asarray(W_opt, np.float64) * f_loc
+    W = W_opt * f_loc
     if s_loc is not None:
-        E = W.shape[0]
-        adjust = -np.sum(s_loc * W, axis=1)  # s_loc is 0 at the intercept
-        W[np.arange(E), pos] += adjust
+        adjust = -jnp.sum(s_loc * W, axis=1)  # s_loc is 0 at the intercept
+        W = W.at[jnp.arange(W.shape[0]), pos].add(adjust)
     return W
 
 
@@ -382,11 +434,164 @@ _RE_SOLVER_DEFAULT = {"cpu": "lbfgs", "tpu": "newton"}
 _RE_SOLVER_MEASURED = {"cpu", "tpu"}
 _warned_unmeasured = set()
 
-# Max entities per vmapped solver execution (env-overridable). 100k in one
-# program exhausted v5e HBM and hard-crashed the TPU worker; 16k keeps the
-# solver intermediates bounded with the per-block dispatch cost amortized
-# over tens of thousands of while_loop iterations.
-_RE_BLOCK_ENTITIES = int(os.environ.get("PHOTON_RE_BLOCK_ENTITIES", 16384))
+# What one solver execution may hold on the device, in bytes: the block's
+# rows, their dense form and the solver's intermediates. 100k entities in
+# one program exhausted v5e HBM and hard-crashed the TPU worker; a block of
+# 1 GiB leaves the resident tables and the fixed effect their room, and is
+# large enough that every bucket of a MovieLens-shaped effect is one block.
+_RE_BLOCK_BYTES = 1 << 30
+# The dense ``X`` of an effect's buckets, which no sweep changes, stays on
+# the device up to this many bytes an effect; past it a bucket densifies
+# again in every solve.
+_RE_DENSE_KEEP_BYTES = 2 << 30
+
+
+def _tiled(minor: int, second: int) -> int:
+    """Elements a ``[second, minor]`` slab takes in device memory: the
+    TPU pads the minor axis to 128 lanes and the next to 8 sublanes."""
+    return (-(-max(minor, 1) // 128) * 128) * (-(-max(second, 1) // 8) * 8)
+
+
+def entity_bytes(N: int, k: int, D: int, itemsize: int, optimizer: str,
+                 history: int = 5) -> int:
+    """Device bytes one entity of a ``[N, k]``-row, ``D``-wide bucket
+    takes inside a solver execution, tiles counted: its sparse rows
+    (slot-major, ``[k, N]``) and six row vectors and, for the dense
+    Newton, ``X [D, N]`` with one temporary of its size, the four trial
+    points' margins, the ``[D, D]`` Hessian with the solve's workspace;
+    for a vmapped optimizer, a row-major copy of the rows and its
+    history."""
+    rows = _tiled(N, k) * (4 + itemsize) + 6 * _tiled(N, 1) * itemsize // 8
+    if optimizer == "newton":
+        return rows + itemsize * (2 * _tiled(N, D) + 4 * _tiled(N, 1) // 8
+                                  + 4 * _tiled(D, D) + 8 * D)
+    return rows + itemsize * (2 * N * max(k, 128) + (2 * history + 8) * D)
+
+
+def block_entities(n_entities: int, per_entity: int, n_dev: int = 1,
+                   budget: Optional[int] = None) -> int:
+    """Entities a solver execution takes: as many as ``budget`` bytes
+    hold (at least one), no more than there are, rounded up to the
+    device count of an entity mesh."""
+    budget = _RE_BLOCK_BYTES if budget is None else budget
+    bs = max(1, min(int(budget) // max(int(per_entity), 1), n_entities))
+    return -(-bs // n_dev) * n_dev
+
+
+def upload(a, dtype=None) -> jax.Array:
+    """Host array -> device, counted (``photon_train_h2d_bytes_total``).
+    A device array passes through, cast if asked."""
+    if isinstance(a, jax.Array):
+        return a if dtype is None or a.dtype == dtype else a.astype(dtype)
+    a = np.asarray(a) if dtype is None else np.asarray(a, dtype)
+    training_metrics().count_h2d(a.nbytes)
+    return jnp.asarray(a)
+
+
+def fetch(a) -> np.ndarray:
+    """Device array -> host numpy, counted
+    (``photon_train_d2h_bytes_total``); the call waits for the value."""
+    out = np.asarray(a)
+    if isinstance(a, jax.Array):
+        training_metrics().count_d2h(out.nbytes)
+    return out
+
+
+@dataclasses.dataclass
+class PlacedBucket:
+    """One bucket's training arrays in device memory, in the working
+    dtype: what ``REBucket`` holds on the host, plus the dense ``X`` per
+    normalization once a Newton solve has built it."""
+
+    indices: jax.Array  # int32 [E, k, N]: slot-major, rows on the lanes
+    values: jax.Array  # [E, k, N]
+    labels: jax.Array  # [E, N]
+    weights: jax.Array  # [E, N]
+    sample_idx: jax.Array  # int32 [E, N], -1 pad
+    dense: dict = dataclasses.field(default_factory=dict)
+
+    def arrays(self):
+        return (self.indices, self.values, self.labels, self.weights,
+                self.sample_idx)
+
+
+@dataclasses.dataclass
+class PlacedRandomEffect:
+    """A ``RandomEffectTrainData`` placed on the device once, for every
+    sweep of every run over it (``CoordinateDescent`` keeps it in its
+    ``dataset_cache``)."""
+
+    buckets: List[PlacedBucket]
+    dtype: object
+    nbytes: int
+    dense_bytes: int = 0
+    # id(normalization) -> (normalization, per-bucket (f_loc, s_loc, pos))
+    norms: dict = dataclasses.field(default_factory=dict)
+
+    def local_norm(self, data: RandomEffectTrainData,
+                   normalization: NormalizationContext):
+        entry = self.norms.get(id(normalization))
+        if entry is None or entry[0] is not normalization:
+            placed = [
+                (upload(f, self.dtype),
+                 None if s is None else upload(s, self.dtype),
+                 None if pos is None else upload(pos))
+                for f, s, pos in _local_normalization(data.buckets,
+                                                      normalization)]
+            entry = self.norms[id(normalization)] = (normalization, placed)
+        return entry[1]
+
+
+def _slot_major(a: np.ndarray) -> np.ndarray:
+    """Host ``[E, N, k]`` -> ``[E, k, N]``, contiguous."""
+    return np.ascontiguousarray(np.swapaxes(np.asarray(a), 1, 2))
+
+
+def place_random_effect(data: RandomEffectTrainData,
+                        dtype=jnp.float32) -> PlacedRandomEffect:
+    """Upload every bucket's training arrays (span ``re.place``)."""
+    dtype = jnp.dtype(dtype)
+    with obs_trace.span("re.place", cat="train", effect=data.effect_name,
+                        what="train", buckets=len(data.buckets)) as sp:
+        buckets = [
+            PlacedBucket(upload(_slot_major(b.indices), np.int32),
+                         upload(_slot_major(b.values), dtype),
+                         upload(b.labels, dtype), upload(b.weights, dtype),
+                         upload(b.sample_idx, np.int32))
+            for b in data.buckets]
+        nbytes = sum(a.nbytes for b in buckets for a in b.arrays())
+        sp.set(bytes=nbytes)
+    return PlacedRandomEffect(buckets, dtype, nbytes)
+
+
+@dataclasses.dataclass
+class PlacedScoreView:
+    """A score view in device memory: per bucket (indices ``[E, k, M]``,
+    values ``[E, k, M]``, target ``[E, M]``), slot-major as the training
+    tables are, ``target`` the row each slot scores, padding pointed at
+    the spare slot ``num_samples``."""
+
+    buckets: List[tuple]
+    num_samples: int
+    dtype: object
+    nbytes: int
+
+
+def place_score_view(score_view: Sequence[REScoreBucket], num_samples: int,
+                     dtype=jnp.float32) -> PlacedScoreView:
+    dtype = jnp.dtype(dtype)
+    with obs_trace.span("re.place", cat="train", what="score_view",
+                        buckets=len(score_view)) as sp:
+        buckets = []
+        for view in score_view:
+            sidx = np.asarray(view.sample_idx)
+            target = np.where(sidx >= 0, sidx, num_samples).astype(np.int32)
+            buckets.append((upload(_slot_major(view.indices), np.int32),
+                            upload(_slot_major(view.values), dtype),
+                            upload(target)))
+        nbytes = sum(a.nbytes for b in buckets for a in b)
+        sp.set(bytes=nbytes)
+    return PlacedScoreView(buckets, int(num_samples), dtype, nbytes)
 
 
 def _pad_entities(a: jax.Array, width: int) -> jax.Array:
@@ -413,10 +618,9 @@ def _active_width(n_active: int, block: int, n_dev: int) -> int:
 
 
 # "auto" only picks the dense-Newton solver up to this per-entity dim:
-# its [block, d, d] Hessians are 16k x d^2 x 4 B per block (1 GB at
-# d=128, 8 GB at the d=351 CD bucket that crashed the Mosaic batched-
-# Cholesky compile — builder-measured on a v5e, 2026-07-31, not
-# re-measured since);
+# its [block, d, d] Hessians are d^2 x 4 B an entity (8 GB at the d=351 CD
+# bucket that crashed the Mosaic batched-Cholesky compile — builder-
+# measured on a v5e, 2026-07-31, not re-measured since);
 # the vmapped L-BFGS memory is O(d) per entity and handles wide
 # subspaces fine.
 _RE_NEWTON_MAX_DIM = 128
@@ -446,34 +650,93 @@ def resolve_re_optimizer(optimizer: str, local_dim: int = None) -> str:
     return choice
 
 
-def _run_entity_blocks(run, args, n_entities: int, bs: int,
-                       compute_variance):
-    """Drive the bucket solver over fixed-width entity blocks and fetch
-    per-entity results. ``args`` is the 10-tuple of device arrays (8
-    per-entity + 2 scalars); blocks are padded to ``bs`` with
-    ``_pad_entities`` so every block shares one compiled shape."""
-    W_parts, V_parts, conv_parts, iter_parts = [], [], [], []
-    for s in range(0, n_entities, bs):
-        e = min(s + bs, n_entities)
-        if s == 0 and e == n_entities == bs:
-            blk = args  # single full block: no slice/pad device copies
-        else:
-            blk = tuple(
-                _pad_entities(a[s:e], bs) if i < 8 else a
-                for i, a in enumerate(args)
-            )
-        Wb, Vb, convb, itersb = run(*blk)
-        W_parts.append(np.asarray(Wb)[: e - s])
-        V_parts.append(np.asarray(Vb)[: e - s] if compute_variance else None)
-        conv_parts.append(np.asarray(convb)[: e - s])
-        iter_parts.append(np.asarray(itersb)[: e - s])
+@functools.partial(jax.jit, static_argnames=("width",))
+def _take_block(arrays, start, width: int):
+    return tuple(jax.lax.dynamic_slice_in_dim(a, start, width, axis=0)
+                 for a in arrays)
 
-    def cat(parts):
-        return np.concatenate(parts) if len(parts) > 1 else parts[0]
 
-    W = cat(W_parts)
-    V = cat(V_parts) if compute_variance else None
-    return W, V, cat(conv_parts).astype(bool), cat(iter_parts)
+@jax.jit
+def _take_entities(arrays, sel):
+    """Rows ``sel`` of every array; an index past the end gives a padding
+    entity (zeros: weight-0 rows, and sample index 0 under weight 0)."""
+    return tuple(jnp.take(a, sel, axis=0, mode="fill", fill_value=0)
+                 for a in arrays)
+
+
+@jax.jit
+@jax.named_scope("photon.cd/residual")
+def _gather_offsets(offsets, sample_idx):
+    # padding rows (sample_idx == -1) carry weight 0, offset value irrelevant
+    return jnp.where(sample_idx >= 0,
+                     jnp.take(offsets, jnp.maximum(sample_idx, 0), axis=0),
+                     0.0)
+
+
+def _run_entity_blocks(run, per_entity, shared, n_entities: int, bs: int):
+    """Drive a bucket solver over entity blocks of ``bs`` and put the
+    per-entity results together, all on the device. ``per_entity`` are the
+    arrays with a leading entity axis, ``shared`` the scalars every block
+    gets. One block: the arrays as they are (padded where ``bs`` exceeds
+    them). More: every block is a ``bs``-wide window, the last one moved
+    back to end with the bucket — the entities it shares with the block
+    before are solved twice, to the same result — so one compiled shape
+    serves all and nothing is padded."""
+    if bs >= n_entities:
+        blk = tuple(_pad_entities(a, bs) for a in per_entity)
+        return tuple(o[:n_entities] for o in run(*blk, *shared)), 1
+    parts, starts = [], list(range(0, n_entities, bs))
+    for s in starts:
+        s0 = min(s, n_entities - bs)
+        out = run(*_take_block(per_entity, np.int32(s0), width=bs), *shared)
+        parts.append(tuple(o[s - s0:] for o in out))
+    return tuple(jnp.concatenate(p) for p in zip(*parts)), len(starts)
+
+
+@dataclasses.dataclass(frozen=True)
+class RandomEffectFitResult:
+    """Per-entity arrays stay on the device; the three summary numbers
+    are fetched when read."""
+
+    coefficients: List[jax.Array]  # per bucket [E, D]
+    variances: Optional[List[jax.Array]]
+    # per-entity detail (one array per bucket): the active-set CD loop uses
+    # these to decide which entities to freeze between sweeps. Entities not
+    # re-solved this call (active-set frozen) report converged=True and
+    # iterations=0 — their objective was not touched.
+    converged: List[jax.Array]  # bool [E] per bucket
+    iterations: List[jax.Array]  # int32 [E] per bucket
+    entities: int = 0
+    entities_solved: int = 0
+    blocks: int = 0
+
+    def counts(self) -> dict:
+        """``converged`` (entities), ``iterations_sum``, ``iterations_max``
+        over every bucket: one fetch, which waits for the solves."""
+        if not any(c.shape[0] for c in self.converged):
+            return {"converged": 0, "iterations_sum": 0, "iterations_max": 0}
+        got = fetch(_fit_counts(tuple(self.converged),
+                                tuple(self.iterations)))
+        return {"converged": int(got[0]), "iterations_sum": int(got[1]),
+                "iterations_max": int(got[2])}
+
+    @property
+    def converged_fraction(self) -> float:
+        return self.counts()["converged"] / max(self.entities, 1)
+
+    @property
+    def mean_iterations(self) -> float:
+        """Over the entities actually solved this call."""
+        return (self.counts()["iterations_sum"]
+                / max(self.entities_solved, 1))
+
+
+@jax.jit
+def _fit_counts(converged, iterations):
+    conv = sum(jnp.sum(c.astype(jnp.int32)) for c in converged)
+    its = [i.astype(jnp.int32) for i in iterations if i.shape[0]]
+    return jnp.stack([conv, sum(jnp.sum(i) for i in its),
+                      jnp.max(jnp.stack([jnp.max(i) for i in its]))])
 
 
 def train_random_effect(
@@ -492,10 +755,17 @@ def train_random_effect(
     normalization: Optional[NormalizationContext] = None,
     active: Optional[Sequence[Optional[np.ndarray]]] = None,
     prev_variances: Optional[List[Optional[np.ndarray]]] = None,
+    placed: Optional[PlacedRandomEffect] = None,
 ) -> RandomEffectFitResult:
     """Solve every entity's local GLM. ``offsets`` is the full-dataset
     residual-offset vector [n] from the coordinate-descent loop. L1 weight
     requires (and auto-routes to) the OWL-QN optimizer.
+
+    ``placed`` is ``data`` in device memory (``place_random_effect``):
+    with it the call uploads nothing but what is new (``offsets`` and
+    ``w0`` where they are host arrays, the scalars); without it the
+    tables are placed for this call alone. Coefficients, variances and
+    the per-entity flags come back as device arrays.
 
     ``normalization`` (the shard's global context) is applied inside each
     per-entity objective via gathered local factor/shift vectors; incoming
@@ -503,10 +773,10 @@ def train_random_effect(
     happens here), so scoring/saving/warm-start paths are unchanged.
 
     ``active`` (the active-set CD path): one boolean mask [E] per bucket —
-    only masked entities are re-solved. Their rows are gathered on the host
-    into a power-of-two-padded sub-bucket (``_active_width``), solved with
-    the same shape-bucketed jitted solver, and scattered back; frozen
-    entities carry their ``w0`` coefficients (and ``prev_variances``)
+    only masked entities are re-solved. Their rows are gathered on the
+    device into a power-of-two-padded sub-bucket (``_active_width``),
+    solved with the same shape-bucketed jitted solver, and scattered back;
+    frozen entities carry their ``w0`` coefficients (and ``prev_variances``)
     untouched and report converged=True / iterations=0. Requires ``w0``.
     A ``None`` mask entry means "solve the whole bucket"."""
     if np.asarray(l1).item() > 0 and optimizer != "owlqn":
@@ -514,34 +784,37 @@ def train_random_effect(
     if active is not None and w0 is None:
         raise ValueError("active-set training needs w0 (frozen entities "
                          "carry their previous coefficients)")
+    dtype = jnp.dtype(dtype)
+    if placed is None or placed.dtype != dtype:
+        placed = place_random_effect(data, dtype)
     # "auto" stays unresolved here: the per-bucket local_dim feeds the
     # dense-Newton dimension gate inside the loop
-    offsets = jnp.asarray(offsets, dtype)
+    offsets = upload(offsets, dtype)
     local_norm = (None if normalization is None
-                  else _local_normalization(data.buckets, normalization))
+                  else placed.local_norm(data, normalization))
     norm_mode = 0
     if normalization is not None:
         norm_mode = 2 if normalization.shifts is not None else 1
+    l2_dev, l1_dev = upload(l2, dtype), upload(l1, dtype)
+    itemsize = dtype.itemsize
     coeffs, variances = [], []
     conv_list, iter_list = [], []
-    # integer accumulators (PN501): these are counts — summing them as
-    # floats would be exact anyway below 2^53, but keeping them int makes
-    # the order-independence self-evident to the reader and the lint
-    conv_sum, iter_sum, total, solved_total = 0, 0, 0, 0
-    for b, bucket in enumerate(data.buckets):
+    total, solved_total, blocks_total = 0, 0, 0
+    for b, (bucket, dev) in enumerate(zip(data.buckets, placed.buckets)):
         E, D = bucket.num_entities, bucket.local_dim
+        N, k = bucket.indices.shape[1:]
+        w0_b = None if w0 is None else upload(w0[b], dtype)
         if E == 0:
             # degenerate bucket (no entities): nothing to solve — emit the
             # empty [0, D] shapes downstream consumers expect (scoring,
-            # model building, warm start) and keep the convergence
-            # accounting untouched rather than tripping range(step=0) /
-            # W_parts[0] in the blocked loop below
-            coeffs.append(np.zeros((0, D), np.dtype(dtype)))
-            variances.append(np.zeros((0, D), np.dtype(dtype))
+            # model building, warm start)
+            coeffs.append(jnp.zeros((0, D), dtype))
+            variances.append(jnp.zeros((0, D), dtype)
                              if compute_variance else None)
-            conv_list.append(np.zeros(0, bool))
-            iter_list.append(np.zeros(0, np.int32))
+            conv_list.append(jnp.zeros(0, bool))
+            iter_list.append(jnp.zeros(0, jnp.int32))
             continue
+        total += E
         mask = None if active is None else active[b]
         if mask is not None:
             mask = np.asarray(mask, bool)
@@ -551,143 +824,186 @@ def train_random_effect(
                     f"expected ({E},)")
             if mask.all():
                 mask = None  # full solve — take the unsliced path
+        prev_var = None
+        if compute_variance and mask is not None:
+            prev_var = (upload(prev_variances[b], dtype)
+                        if prev_variances is not None
+                        and prev_variances[b] is not None else None)
         if mask is not None and not mask.any():
             # fully frozen bucket: nothing touches the device at all
-            coeffs.append(np.array(np.asarray(w0[b]), copy=True))
+            coeffs.append(w0_b)
             variances.append(
                 None if not compute_variance else
-                (np.array(prev_variances[b], copy=True)
-                 if prev_variances is not None and prev_variances[b]
-                 is not None else np.zeros((E, D), np.dtype(dtype))))
-            conv_list.append(np.ones(E, bool))
-            iter_list.append(np.zeros(E, np.int32))
-            conv_sum += E
-            total += E
+                (prev_var if prev_var is not None
+                 else jnp.zeros((E, D), dtype)))
+            conv_list.append(jnp.ones(E, bool))
+            iter_list.append(jnp.zeros(E, jnp.int32))
             continue
         sel = None if mask is None else np.flatnonzero(mask)
         n_solve = E if sel is None else len(sel)
         opt_b = resolve_re_optimizer(optimizer, D)
+        n_dev = 1 if mesh is None else mesh.shape[axis]
+        # Entities are independent, so a bucket too large for one
+        # execution is solved in blocks of one shape (single compile)
+        # whose size follows the bytes a block may hold
+        bs = block_entities(
+            n_solve, entity_bytes(N, k, D, itemsize, opt_b, config.history),
+            n_dev)
+        # dense Newton off the mesh keeps X between calls and runs the
+        # solve alone; everything else goes through the one 10-argument
+        # solver (densifying inside, where it is Newton)
+        keep_dense = opt_b == "newton" and mesh is None
         if mesh is not None:
-            n_dev = mesh.shape[axis]
             run = _jitted_sharded_solver(D, task, opt_b, config,
                                          compute_variance, mesh, axis,
                                          norm_mode)
+        elif keep_dense:
+            densify, run = _jitted_newton_halves(D, task, config,
+                                                 compute_variance, norm_mode)
         else:
-            n_dev = 1
-            run = _jitted_solver(D, task, opt_b, config, compute_variance,
-                                 norm_mode)
-        # Bound the vmapped width: one program over ~100k entities
-        # exhausted HBM on the v5e and hard-crashed the TPU worker
-        # ("kernel fault"; builder-measured on a v5e, 2026-07-31, not
-        # re-measured since), and the slowdown was superlinear well
-        # before the crash. Entities are
-        # independent, so solve fixed-width blocks: every block padded to
-        # one shape (single compile), results fetched per block so HBM
-        # only ever holds one block's solver intermediates.
-        bs = -(-min(_RE_BLOCK_ENTITIES, E) // n_dev) * n_dev
-        # active-set sub-bucket: gather the unconverged entities ON THE
-        # HOST (the frozen majority's arrays never transfer), pad to the
-        # power-of-two ladder width, and solve that
-        width = bs if sel is None else _active_width(n_solve, bs, n_dev)
-        idx_np = bucket.indices if sel is None else bucket.indices[sel]
-        val_np = bucket.values if sel is None else bucket.values[sel]
-        lab_np = bucket.labels if sel is None else bucket.labels[sel]
-        wts_np = bucket.weights if sel is None else bucket.weights[sel]
-        sidx_np = (bucket.sample_idx if sel is None
-                   else bucket.sample_idx[sel])
-        ln_b = None
+            run = _jitted_placed_solver(D, task, opt_b, config,
+                                        compute_variance, norm_mode)
         if local_norm is not None:
-            f_np, s_np, pos_np = local_norm[b]
-            if sel is not None:
-                f_np = f_np[sel]
-                s_np = None if s_np is None else s_np[sel]
-                pos_np = None if pos_np is None else pos_np[sel]
-            ln_b = (f_np, s_np, pos_np)
-        sidx = jnp.asarray(sidx_np)
-        # padding rows (sidx == -1) carry weight 0, offset value irrelevant
-        off = jnp.take(offsets, jnp.maximum(sidx, 0), axis=0) * (sidx >= 0)
-        if w0 is not None:
-            w_init = np.asarray(w0[b])
-            if sel is not None:
-                w_init = w_init[sel]
-            if ln_b is not None:
-                w_init = _re_to_training_space(w_init, *ln_b)
-            w_init = jnp.asarray(w_init, dtype)
-        else:
-            w_init = jnp.zeros((n_solve, D), dtype)
-        if ln_b is not None:
-            f_loc = jnp.asarray(ln_b[0], dtype)
-            s_loc = (jnp.zeros((n_solve, 1), dtype) if ln_b[1] is None
-                     else jnp.asarray(ln_b[1], dtype))
+            f_loc, s_loc, pos = local_norm[b]
+            s_arg = jnp.zeros((E, 1), dtype) if s_loc is None else s_loc
         else:  # unused dummies (dead-code-eliminated under jit)
-            f_loc = jnp.zeros((n_solve, 1), dtype)
-            s_loc = jnp.zeros((n_solve, 1), dtype)
-        args = (
-            jnp.asarray(idx_np),
-            jnp.asarray(val_np, dtype),
-            jnp.asarray(lab_np, dtype),
-            jnp.asarray(wts_np, dtype),
-            off.astype(dtype),
-            w_init,
-            f_loc,
-            s_loc,
-            jnp.asarray(l2, dtype),
-            jnp.asarray(l1, dtype),
-        )
-        W, V, conv, iters = _run_entity_blocks(run, args, n_solve, width,
-                                               compute_variance)
-        if ln_b is not None:
-            W = _re_to_model_space(W, *ln_b)
-        if sel is None:
-            conv_arr, iter_arr = conv, iters.astype(np.int32)
+            f_loc = s_arg = jnp.zeros((E, 1), dtype)
+            s_loc = pos = None
+        w_init = jnp.zeros((E, D), dtype) if w0_b is None else w0_b
+        if local_norm is not None and w0_b is not None:
+            w_init = _re_to_training_space(w_init, f_loc, s_loc, pos)
+        offs = _gather_offsets(offsets, dev.sample_idx)
+        if keep_dense:
+            X = dev.dense.get((norm_mode, id(normalization)))
+            if X is None:
+                X = _densify_bucket(densify, dev, f_loc, s_arg, bs)
+                if placed.dense_bytes + X.nbytes <= _RE_DENSE_KEEP_BYTES:
+                    placed.dense_bytes += X.nbytes
+                    dev.dense[(norm_mode, id(normalization))] = X
+            per_entity = (X, dev.labels, dev.weights, offs, w_init)
+            shared = (l2_dev,)
         else:
+            tables = (dev.indices, dev.values)
+            if mesh is not None:  # the sharded solver reads row-major
+                tables = tuple(jnp.swapaxes(a, 1, 2) for a in tables)
+            per_entity = tables + (dev.labels, dev.weights, offs, w_init,
+                                   f_loc, s_arg)
+            shared = (l2_dev, l1_dev)
+        if sel is not None:
+            # active-set sub-bucket: gather the unconverged entities on
+            # the device, padded to the power-of-two ladder width
+            width = _active_width(n_solve, bs, n_dev)
+            sel_pad = np.full(max(width, n_solve), E, np.int32)
+            sel_pad[:n_solve] = sel
+            per_entity = _take_entities(per_entity, upload(sel_pad))
+            bs = min(bs, len(sel_pad))
+        with obs_trace.span("re.solve.bucket", cat="train",
+                            effect=data.effect_name, bucket=b,
+                            entities=n_solve, N=N, D=D, optimizer=opt_b):
+            (W, V, conv, iters), n_blocks = _run_entity_blocks(
+                run, per_entity, shared,
+                n_solve if sel is None else len(sel_pad), bs)
+        blocks_total += n_blocks
+        if local_norm is not None:
+            f_s, s_s, pos_s = f_loc, s_loc, pos
+            if sel is not None:
+                f_s, s_s, pos_s = (None if a is None else a[sel]
+                                   for a in (f_loc, s_loc, pos))
+                W = W[:n_solve]
+            W = _re_to_model_space(W, f_s, s_s, pos_s)
+        if sel is not None:
             # scatter solved entities back; frozen rows carry over
-            W_full = np.array(np.asarray(w0[b]), copy=True)
-            W_full[sel] = W
-            W = W_full
+            sel_dev = upload(sel.astype(np.int32))
+            W = w0_b.at[sel_dev].set(W[:n_solve])
             if compute_variance:
-                V_full = (np.array(prev_variances[b], copy=True)
-                          if prev_variances is not None
-                          and prev_variances[b] is not None
-                          else np.zeros((E, np.asarray(V).shape[1]),
-                                        np.asarray(V).dtype))
-                V_full[sel] = V
-                V = V_full
-            conv_arr = np.ones(E, bool)
-            conv_arr[sel] = conv
-            iter_arr = np.zeros(E, np.int32)
-            iter_arr[sel] = iters
+                V_full = (prev_var if prev_var is not None
+                          else jnp.zeros((E, V.shape[1]), V.dtype))
+                V = V_full.at[sel_dev].set(V[:n_solve])
+            conv = jnp.ones(E, bool).at[sel_dev].set(conv[:n_solve])
+            iters = jnp.zeros(E, jnp.int32).at[sel_dev].set(
+                iters[:n_solve].astype(jnp.int32))
         # opt-in NaN trap at the batched per-entity solver's host
         # boundary (no-op unless a NaNGuard context is armed)
         nan_guard_check(f"re_solver:bucket{b}", W)
-        if compute_variance and V is not None:
+        if compute_variance:
             nan_guard_check(f"re_solver:bucket{b}:variances", V)
         coeffs.append(W)
-        variances.append(V)
-        conv_list.append(conv_arr)
-        iter_list.append(iter_arr)
-        conv_sum += int(conv_arr.sum())
-        iter_sum += int(iter_arr.sum())
-        total += E
+        variances.append(V if compute_variance else None)
+        conv_list.append(conv)
+        iter_list.append(iters.astype(jnp.int32))
         solved_total += n_solve
     return RandomEffectFitResult(
         coefficients=coeffs,
         variances=variances if compute_variance else None,
-        converged_fraction=conv_sum / max(total, 1),
-        mean_iterations=iter_sum / max(solved_total, 1),
         converged=conv_list,
         iterations=iter_list,
+        entities=total,
         entities_solved=solved_total,
+        blocks=blocks_total,
     )
 
 
-def _margins_one(w_e, idx_e, val_e):
-    return jnp.sum(val_e * w_e[idx_e], axis=-1)  # [M]
+def _densify_bucket(densify, dev: PlacedBucket, f_loc, s_loc, bs: int):
+    """``X [E, D, N]`` of a whole bucket, built ``bs`` entities at a time
+    (the scan's temporaries are of the block's size)."""
+    (X,), _ = _run_entity_blocks(
+        lambda *block: (densify(*block),),
+        (dev.indices, dev.values, f_loc, s_loc), (), dev.indices.shape[0], bs)
+    return X
+
+
+# RE local dimensions are small by design; up to this width a row's
+# margin is taken by comparing its slots with 0..D-1 (the densify's own
+# form, element-wise and fused), past it by a gather per slot
+_RE_COMPARE_MAX_DIM = 128
+
+
+def _bucket_margins(W, idx_t, val_t):
+    """[E, M] margins of a bucket's rows (slot-major ``[E, k, M]``) under
+    their entities' models ``W [E, D]``."""
+    D = W.shape[1]
+    if D > _RE_COMPARE_MAX_DIM:
+        return jax.vmap(lambda w, i, v: jnp.sum(v * w[i], axis=0))(
+            W, idx_t, val_t)
+    iota = jnp.arange(D, dtype=idx_t.dtype)[None, :, None]
+
+    def add_slot(m, j):
+        idx_j = jnp.take(idx_t, j, axis=1)[:, None, :]  # [E, 1, M]
+        w_j = jnp.sum(jnp.where(idx_j == iota, W[:, :, None], 0.0), axis=1)
+        return m + jnp.take(val_t, j, axis=1) * w_j, None
+
+    E, _, M = idx_t.shape
+    m, _ = jax.lax.scan(add_slot, jnp.zeros((E, M), W.dtype),
+                        jnp.arange(idx_t.shape[1]))
+    return m
+
+
+# the rescoring programs hang on this key in ``data_parallel``'s runner
+# cache, beside the fits (they belong to no objective)
+_RESCORE_PROGRAMS = object()
+
+
+def _rescore_program(n: int):
+    def make():
+        @jax.named_scope("photon.re/rescore")
+        def rescore(buckets, coefficients):
+            dt = coefficients[0].dtype
+            scores = jnp.zeros((n + 1,), dt)  # slot n swallows padding
+            for (idx, val, target), W in zip(buckets, coefficients):
+                m = _bucket_margins(W, idx, val)
+                scores = scores.at[target.reshape(-1)].add(
+                    jnp.where(target < n, m, 0.0).reshape(-1))
+            return scores[:n]
+        return rescore
+
+    from photon_ml_tpu.parallel.data_parallel import cached_jit
+
+    return cached_jit(_RESCORE_PROGRAMS, ("re_rescore", n), make)
 
 
 def score_random_effect(
-    score_view: Sequence[REScoreBucket],
-    coefficients: Sequence[np.ndarray],
+    score_view,
+    coefficients: Sequence,
     num_samples: int,
     dtype=jnp.float32,
     prev: Optional[jax.Array] = None,
@@ -695,33 +1011,33 @@ def score_random_effect(
 ) -> jax.Array:
     """Margins of every sample under its entity's model, scattered into a
     full-dataset score vector (the reference's CoordinateDataScores role,
-    SURVEY.md §3.2). Samples with no entity model score 0.
+    SURVEY.md §3.2). Samples with no entity model score 0. ``score_view``
+    is a list of ``REScoreBucket`` (uploaded for this call) or the same
+    placed once (``place_score_view``); the full form is one jitted
+    program over all buckets.
 
     Incremental mode (``prev`` + ``changed``): recompute margins only for
     the rows owned by re-solved entities and scatter-overwrite them into
     the previous score vector — every row belongs to at most one entity
     per coordinate, so a plain set is exact. ``changed`` holds one boolean
     mask [E] per bucket (None = whole bucket changed); the changed rows
-    are gathered on the host and padded to a power-of-two entity width so
+    are gathered on the device and padded to a power-of-two entity width so
     the margin kernel's shape ladder stays bounded as active sets shrink."""
+    dtype = jnp.dtype(dtype)
+    if not isinstance(score_view, PlacedScoreView):
+        score_view = place_score_view(score_view, num_samples, dtype)
+    coefficients = [upload(W, dtype) for W in coefficients]
     if prev is None or changed is None:
-        scores = jnp.zeros((num_samples + 1,), dtype)  # slot n swallows pad
-        for view, W in zip(score_view, coefficients):
-            Wd = jnp.asarray(W, dtype)
-            idx = jnp.asarray(view.indices)
-            val = jnp.asarray(view.values, dtype)
-            sidx = jnp.asarray(view.sample_idx)
-            m = jax.vmap(_margins_one)(Wd, idx, val)  # [E, M]
-            target = jnp.where(sidx >= 0, sidx, num_samples)
-            scores = scores.at[target.reshape(-1)].add(
-                jnp.where(sidx >= 0, m, 0.0).reshape(-1)
-            )
-        return scores[:num_samples]
+        if not score_view.buckets:
+            return jnp.zeros((num_samples,), dtype)
+        return _rescore_program(num_samples)(tuple(score_view.buckets),
+                                             tuple(coefficients))
 
     scores = jnp.concatenate(
         [jnp.asarray(prev, dtype), jnp.zeros((1,), dtype)])
-    for view, W, mask in zip(score_view, coefficients, changed):
-        E = view.sample_idx.shape[0]
+    for (idx, val, target), W, mask in zip(score_view.buckets, coefficients,
+                                           changed):
+        E = idx.shape[0]
         if E == 0:
             continue
         if mask is None:
@@ -730,28 +1046,17 @@ def score_random_effect(
             sel = np.flatnonzero(np.asarray(mask, bool))
             if len(sel) == 0:
                 continue
-        width = _active_width(len(sel), E, 1)
-        pad = width - len(sel)
-        W_np = np.asarray(W)[sel]
-        idx_np = view.indices[sel]
-        val_np = view.values[sel]
-        sidx_np = view.sample_idx[sel]
-        if pad:
-            W_np = np.concatenate([W_np, np.zeros((pad,) + W_np.shape[1:],
-                                                  W_np.dtype)])
-            idx_np = np.concatenate(
-                [idx_np, np.zeros((pad,) + idx_np.shape[1:], idx_np.dtype)])
-            val_np = np.concatenate(
-                [val_np, np.zeros((pad,) + val_np.shape[1:], val_np.dtype)])
-            sidx_np = np.concatenate(
-                [sidx_np, np.full((pad,) + sidx_np.shape[1:], -1,
-                                  sidx_np.dtype)])
-        sidx = jnp.asarray(sidx_np)
-        m = jax.vmap(_margins_one)(jnp.asarray(W_np, dtype),
-                                   jnp.asarray(idx_np),
-                                   jnp.asarray(val_np, dtype))
-        target = jnp.where(sidx >= 0, sidx, num_samples)
+        sel_pad = np.full(_active_width(len(sel), E, 1), E, np.int32)
+        sel_pad[:len(sel)] = sel
+        sel_dev = upload(sel_pad)
+        W_s, idx_s, val_s = _take_entities((W, idx, val), sel_dev)
+        tgt_s = jnp.take(target, sel_dev, axis=0, mode="fill",
+                         fill_value=num_samples)
+        m = _bucket_margins_jit(W_s, idx_s, val_s)
         # overwrite, don't add: these rows' previous margins are stale
-        scores = scores.at[target.reshape(-1)].set(
-            jnp.where(sidx >= 0, m, 0.0).reshape(-1), mode="drop")
+        scores = scores.at[tgt_s.reshape(-1)].set(
+            jnp.where(tgt_s < num_samples, m, 0.0).reshape(-1), mode="drop")
     return scores[:num_samples]
+
+
+_bucket_margins_jit = jax.jit(_bucket_margins)

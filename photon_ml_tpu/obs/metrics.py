@@ -253,10 +253,21 @@ class TrainingMetrics:
         fits ran, as their programs counted them. A fit's counters stay
         device scalars in its record (:meth:`record_fit`) until a read
         (:meth:`fit_records`, :meth:`snapshot`, :meth:`render`) or until
-        the record leaves the ring of ``FIT_RECORDS``.
+        the record leaves the ring of ``FIT_RECORDS``;
+      sweep_total / sweep_seconds — CD sweeps and their host seconds;
+      re_entities_solved_total / re_newton_iterations_total /
+        re_row_slots_total{kind=real|padded}{coordinate} — what the
+        random-effect solves of those sweeps did;
+      h2d_bytes_total / d2h_bytes_total — bytes the GAME path uploaded
+        and fetched (:meth:`count_h2d` / :meth:`count_d2h`, called where
+        the program moves them); compiles_total — XLA compiles the
+        process made since the first sweep record was asked for. A
+        sweep's own share of the three is in its record
+        (:meth:`record_sweep`, read by :meth:`sweep_records`).
     """
 
     FIT_RECORDS = 64
+    SWEEP_RECORDS = 64
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -324,6 +335,27 @@ class TrainingMetrics:
             bounds=DEFAULT_SECONDS_BUCKETS)
         self._fit_lock = threading.Lock()
         self._fit_ring: collections.deque = collections.deque()
+        self._sweeps = r.counter("photon_train_sweep_total", "CD sweeps")
+        self._sweep_s = r.histogram("photon_train_sweep_seconds",
+                                    bounds=DEFAULT_SECONDS_BUCKETS)
+        self._re_entities = r.counter(
+            "photon_train_re_entities_solved_total",
+            "random-effect entities solved")
+        self._re_newton = r.counter(
+            "photon_train_re_newton_iterations_total",
+            "solver iterations summed over those entities")
+        self._re_slots = r.counter(
+            "photon_train_re_row_slots_total",
+            "row slots the solves read: real rows and padding")
+        self._h2d = r.counter("photon_train_h2d_bytes_total",
+                              "bytes the GAME path uploaded")
+        self._d2h = r.counter("photon_train_d2h_bytes_total",
+                              "bytes the GAME path fetched")
+        self._compiles = r.counter("photon_train_compiles_total",
+                                   "XLA compiles since the first sweep")
+        self._compile_listener = False
+        self._sweep_ring: collections.deque = collections.deque(
+            maxlen=self.SWEEP_RECORDS)
 
     def record_step(self, coordinate: str, solve_s: float, eval_s: float,
                     comm_s: float) -> None:
@@ -331,6 +363,53 @@ class TrainingMetrics:
         self._solve.observe(solve_s, coordinate=coordinate)
         self._eval.observe(eval_s, coordinate=coordinate)
         self._comm.observe(comm_s, coordinate=coordinate)
+
+    def count_h2d(self, nbytes: int) -> None:
+        self._h2d.inc(int(nbytes))
+
+    def count_d2h(self, nbytes: int) -> None:
+        self._d2h.inc(int(nbytes))
+
+    def transfer_counts(self) -> Tuple[float, float, float]:
+        """(bytes uploaded, bytes fetched, compiles) so far; the first
+        call starts the count of compiles (a ``jax.monitoring``
+        listener, which cannot be taken off again)."""
+        if not self._compile_listener:
+            self._compile_listener = True
+            import jax.monitoring as mon
+
+            def on_duration(event, secs, **_):
+                if event.endswith("backend_compile_duration"):
+                    self._compiles.inc(1)
+
+            mon.register_event_duration_secs_listener(on_duration)
+        return self._h2d.get(), self._d2h.get(), self._compiles.get()
+
+    def record_sweep(self, record: dict) -> None:
+        """One CD sweep, at its end. ``record``: ``iteration``,
+        ``seconds``, ``h2d_bytes`` / ``d2h_bytes`` / ``compiles`` (the
+        sweep's share of :meth:`transfer_counts`) and ``coordinates``,
+        one dict a coordinate step (``name``, ``type``, ``seconds``,
+        ``fit_seconds``, ``rescore_seconds``, each closed by a fetched
+        value; for a random effect also ``entities_solved``,
+        ``iterations_sum``, ``iterations_max``, ``real_slots``,
+        ``padded_slots``, ``buckets``, ``blocks``)."""
+        self._sweeps.inc(1)
+        self._sweep_s.observe(record["seconds"])
+        for c in record["coordinates"]:
+            if c["type"] != "random":
+                continue
+            name = c["name"]
+            self._re_entities.inc(c["entities_solved"], coordinate=name)
+            self._re_newton.inc(c["iterations_sum"], coordinate=name)
+            self._re_slots.inc(c["real_slots"], coordinate=name, kind="real")
+            self._re_slots.inc(c["padded_slots"], coordinate=name,
+                               kind="padded")
+        self._sweep_ring.append(record)
+
+    def sweep_records(self) -> List[dict]:
+        """The last ``SWEEP_RECORDS`` sweeps, oldest first."""
+        return list(self._sweep_ring)
 
     def record_chunk_cache_pass(self, kind: str) -> None:
         c = self._cache.get(kind)

@@ -595,7 +595,7 @@ def test_train_random_effect_blocked_matches_unblocked(rng, monkeypatch,
     mesh = make_mesh({"entity": 4}) if use_mesh else None
     want = train_random_effect(data, np.zeros(n), l2=0.4, dtype=jnp.float64,
                                config=cfg, mesh=mesh)
-    monkeypatch.setattr(re_mod, "_RE_BLOCK_ENTITIES", 5)  # forces blocks
+    monkeypatch.setattr(re_mod, "_RE_BLOCK_BYTES", 20_000)  # forces blocks
     got = train_random_effect(data, np.zeros(n), l2=0.4, dtype=jnp.float64,
                               config=cfg, mesh=mesh)
     for a, b in zip(want.coefficients, got.coefficients):

@@ -144,11 +144,14 @@ def test_newton_re_solver_compiles_at_one_block(topo):
     """One real block of the batched dense-Newton solver: the widest
     program the GAME phase can hand the batched-Cholesky compile."""
     from photon_ml_tpu.game.random_effect import (
-        _RE_BLOCK_ENTITIES,
         _jitted_sharded_solver,
+        block_entities,
+        entity_bytes,
     )
 
-    E, D_loc, rows = _RE_BLOCK_ENTITIES, 32, 64
+    D_loc, rows = 32, 64
+    # as many entities as the block's byte budget holds at this shape
+    E = block_entities(1 << 20, entity_bytes(rows, D_loc, D_loc, 4, "newton"))
     mesh = make_mesh({"entity": 1}, devices=topo.devices[:1])
     run = _jitted_sharded_solver(
         D_loc, "logistic", "newton",
