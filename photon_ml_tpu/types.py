@@ -26,15 +26,15 @@ from flax import struct
 # ---------------------------------------------------------------------------
 # 1-D table gather for the sparse hot path.
 #
-# XLA:TPU lowers a word-granular gather (slice size 1) to a serial loop —
-# ~1 element/cycle. Builder-measured on a v5e, 2026-07-31, not re-measured
-# since: the 82M-element margin gather ran at ~1 GB/s, 0.1% of HBM peak, and
-# the whole L-BFGS iteration was 2x that gather. The fix is the standard TPU
-# embedding-lookup shape: reshape the table to [d/128, 128] so each gathered
-# element is a full 128-lane row (a vectorizable (1,128)-slice gather), then
-# select the wanted lane with a one-hot multiply+reduce on the VPU. The sum
-# adds exactly one real value and 127 zeros, so the result is bit-identical
-# to ``table[idx]``.
+# XLA:TPU lowers a word-granular gather (slice size 1) to a serial loop:
+# 7.5-18.5 ns an index on the v5e (PERF.md section 6, PR 29: the boundary
+# combine's ``lp`` before it took this form), where the form below reads
+# 2.8 ns an element. It is the standard TPU embedding-lookup
+# shape: reshape the table to [d/128, 128] so each gathered element is a
+# full 128-lane row (a vectorizable (1,128)-slice gather), then select the
+# wanted lane with a one-hot multiply+reduce on the VPU. The sum adds
+# exactly one real value and 127 zeros, so the result is bit-identical to
+# ``table[idx]``.
 #
 # The row-gather materializes a [m, 128] intermediate; for large m it runs
 # under ``lax.map`` over fixed-size chunks so the intermediate stays ~128 MB
@@ -44,6 +44,12 @@ from flax import struct
 _LANES = 128
 _GATHER_CHUNK = 1 << 18  # rows per lax.map step: [2^18, 128] f32 = 128 MB
 _GATHER_MIN_SIZE = 1 << 14  # below this, the serial gather costs < ~20 us
+# bytes a table may have and still be gathered whole. A row costs 1.8 ns to
+# fetch out of a table the TPU compiler keeps in its fast memory space
+# (``S(1)`` in a compiled layout) and 9-15 ns out of one it leaves in HBM;
+# the largest table seen kept there has 159,744 rows of 128 f32 (PERF.md
+# section 7.7; tests/test_tpu_compile.py holds the placement)
+_GATHER_TABLE_BYTES = 80 << 20
 _gather_mode = os.environ.get("PHOTON_GATHER", "auto")
 
 
@@ -67,18 +73,75 @@ def gather_mode() -> str:
     return _gather_mode
 
 
-def _vector_gather_rows(table2d: jax.Array, idx: jax.Array) -> jax.Array:
+def _vector_gather_rows(table2d: jax.Array, idx: jax.Array,
+                        scope: str) -> jax.Array:
     # mode="clip": the default 'fill' pays an out-of-bounds select per
     # element (~12% of the pass on the v5e); table_gather's indices are
     # in-bounds by construction (idx < d => idx>>7 < rows), so clamping
     # is semantically a no-op and results stay bit-identical
-    with jax.named_scope("photon.table_gather/rows"):
+    with jax.named_scope(scope + "rows"):
         rows = jnp.take(table2d, jnp.right_shift(idx, 7), axis=0,
                         mode="clip")
-    with jax.named_scope("photon.table_gather/select"):
+    with jax.named_scope(scope + "select"):
         lane = jnp.bitwise_and(idx, 127)
         onehot = lane[:, None] == jnp.arange(_LANES, dtype=idx.dtype)[None, :]
         return jnp.sum(jnp.where(onehot, rows, 0), axis=-1)
+
+
+def _gather_1d(table: jax.Array, idx: jax.Array, scope: str) -> jax.Array:
+    """``table_gather``'s body: the choice of form, both forms, and the
+    vector form's reading of a long table run by run. The vector form's
+    row gather and lane select run under the scopes
+    ``scope + "rows"`` and ``scope + "select"``: ``photon-trace kernels``
+    files an op under the innermost ``photon.*`` scope of its name stack,
+    so a caller that keeps its gather's time under its own scope passes
+    ``""`` (the boundary combine's ``lp``) and ``photon.table_gather/*``
+    holds the product gathers alone."""
+    mode = _gather_mode
+    if mode == "auto":
+        # TPU only: the serial-gather pathology is a TPU lowering property
+        # (PERF.md section 5); GPUs and CPUs gather words natively and
+        # would only pay the [m, 128] expansion
+        mode = "vector" if jax.default_backend() == "tpu" else "scalar"
+    if (mode == "scalar" or table.ndim != 1
+            or idx.size < _GATHER_MIN_SIZE or table.shape[0] < _LANES):
+        return table[idx]
+    d = table.shape[0]
+    dp = -(-d // _LANES) * _LANES
+    table2d = jnp.pad(table, (0, dp - d)).reshape(dp // _LANES, _LANES)
+    flat = idx.reshape(-1).astype(jnp.int32)
+    runs = -(-table2d.size * table2d.dtype.itemsize // _GATHER_TABLE_BYTES)
+    if runs == 1:
+        return _gather_chunks(table2d, flat, scope).reshape(idx.shape)
+    # a longer table is read in equal runs of rows that fit, one after the
+    # other: every index is gathered from every run (clamped into it) and
+    # kept from the run that holds it. `runs` times the work, at a fifth
+    # to an eighth of the cost a row
+    per_run = -(-table2d.shape[0] // runs)
+    for r in range(runs):
+        if r:  # cut a run out when the one before is done: one is live
+            table2d, out = jax.lax.optimization_barrier((table2d, out))
+        part = table2d[r * per_run:(r + 1) * per_run]
+        local = flat - r * per_run * _LANES
+        got = _gather_chunks(
+            part, jnp.clip(local, 0, part.shape[0] * _LANES - 1), scope)
+        out = got if r == 0 else jnp.where(local >= 0, got, out)
+    return out.reshape(idx.shape)
+
+
+def _gather_chunks(table2d: jax.Array, flat: jax.Array,
+                   scope: str) -> jax.Array:
+    """Rows and lanes of ``flat`` out of ``table2d``, ``_GATHER_CHUNK``
+    indices at a time."""
+    m = flat.shape[0]
+    if m <= _GATHER_CHUNK:
+        return _vector_gather_rows(table2d, flat, scope)
+    c = -(-m // _GATHER_CHUNK)
+    flat = jnp.pad(flat, (0, c * _GATHER_CHUNK - m))  # pad idx 0: valid
+    return jax.lax.map(
+        lambda ix: _vector_gather_rows(table2d, ix, scope),
+        flat.reshape(c, _GATHER_CHUNK),
+    ).reshape(-1)[:m]
 
 
 @jax.named_scope("photon.table_gather")
@@ -96,30 +159,7 @@ def table_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
     where the serial gather is the bottleneck but loses on CPU where the
     serial gather is already fast.
     """
-    mode = _gather_mode
-    if mode == "auto":
-        # TPU only: the serial-gather pathology is a TPU lowering property
-        # (builder-measured on a v5e, 2026-07-31); GPUs and CPUs gather
-        # words natively and would only pay the [m, 128] expansion
-        mode = "vector" if jax.default_backend() == "tpu" else "scalar"
-    if (mode == "scalar" or table.ndim != 1
-            or idx.size < _GATHER_MIN_SIZE or table.shape[0] < _LANES):
-        return table[idx]
-    d = table.shape[0]
-    dp = -(-d // _LANES) * _LANES
-    table2d = jnp.pad(table, (0, dp - d)).reshape(dp // _LANES, _LANES)
-    flat = idx.reshape(-1).astype(jnp.int32)
-    m = flat.shape[0]
-    if m <= _GATHER_CHUNK:
-        out = _vector_gather_rows(table2d, flat)
-    else:
-        c = -(-m // _GATHER_CHUNK)
-        flat = jnp.pad(flat, (0, c * _GATHER_CHUNK - m))  # pad idx 0: valid
-        out = jax.lax.map(
-            lambda ix: _vector_gather_rows(table2d, ix),
-            flat.reshape(c, _GATHER_CHUNK),
-        ).reshape(-1)[:m]
-    return out.reshape(idx.shape)
+    return _gather_1d(table, idx, "photon.table_gather/")
 
 
 @struct.dataclass
@@ -242,8 +282,10 @@ def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
     a block-total prefix difference, but only columns wider than a whole
     block (>= ``block`` nonzeros) ever take it — and for those the
     interior sum *is* the dominant term, so no cancellation. Cost: the
-    same one pass of cumsum traffic, plus one gather over the dim + 1
-    column boundaries and B-long ones for the <= B spanning columns.
+    same one pass of cumsum traffic, plus one gather over the column
+    boundaries (``dim`` of them: the first is always 0; in
+    ``table_gather``'s form, so 128-lane rows on a TPU) and B-long ones
+    for the <= B spanning columns.
 
     ``precise=True`` keeps the old full-f64 global prefix (meaningful
     only under jax_enable_x64; without it, f64 silently degrades to f32,
@@ -286,9 +328,18 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
 
     The columns' ranges are sorted and disjoint, so each of the B block
     boundaries ``k*T`` lies inside at most one column: at most B columns
-    span. One gather runs over the ``dim + 1`` column boundaries (``lp``);
-    the block totals are read for the <= B spanning columns alone, which B
-    binary searches in ``col_starts`` find."""
+    span. One gather runs over the column boundaries (``lp``), in the form
+    ``table_gather`` chooses from what it can observe: on a TPU, from
+    ``dim >= 2^14`` on, ``local_flat`` is read as 128-lane rows with a
+    one-hot lane select (2.8 ns an index for each 80 MiB of prefixes,
+    where the scalar gather took 7.5-18.5: PERF.md section 5), under
+    ``lp/rows`` and ``lp/select``; elsewhere, or under
+    ``PHOTON_GATHER=scalar``, as ``local_flat[...]``.
+    Column 0 starts at nonzero 0 (``col_starts[0] == 0``: nothing sorts
+    below column 0), so ``lp[0]`` is the constant 0 and the gather runs
+    over the ``dim`` other boundaries, a whole number of chunks at a
+    power-of-two ``dim``. The block totals are read for the <= B spanning
+    columns alone, which B binary searches in ``col_starts`` find."""
     B = bt.shape[0]
     dim = col_starts.shape[0] - 1
     # exclusive prefix of block totals; only consulted for columns spanning
@@ -297,9 +348,13 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
 
     cs = col_starts.astype(jnp.int32)
     # local exclusive prefix at each boundary: local[b, r-1], 0 at r == 0
+    # (and at boundary 0, which is nonzero 0: r == 0 there)
     with jax.named_scope("lp"):
-        lp = jnp.where(cs % T > 0, local_flat[jnp.maximum(cs - 1, 0)],
-                       jnp.zeros((), local_flat.dtype))
+        zero = jnp.zeros((), local_flat.dtype)
+        ends = cs[1:]
+        lp = jnp.concatenate([zero[None], jnp.where(
+            ends % T > 0, _gather_1d(local_flat, jnp.maximum(ends - 1, 0),
+                                     ""), zero)])
     # every column that starts and ends in one block, the empty ones too
     out = lp[1:] - lp[:-1]
     with jax.named_scope("span"):

@@ -2,6 +2,8 @@
 scatter path for values, gradients, HVPs, and full fits across optimizers
 (the TPU hot-loop alternative — types.CSCTranspose)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -359,11 +361,87 @@ def _combine_problem(case, seed):
             jnp.asarray(col_starts), T)
 
 
+# -- where `lp` takes the vector form (ISSUE 29) -----------------------------
+# On a TPU the gather over the column boundaries reads 128-lane rows once
+# dim >= _GATHER_MIN_SIZE. Here: gather mode "vector", dim = 2^14 + 37 and
+# _GATHER_CHUNK = 2^12, so `lax.map` runs four whole chunks and a ragged
+# fifth. name -> (T, nonzeros per column of the named layout's head; the
+# columns after it share what is left of VECTOR_NNZ, some none of it)
+VECTOR_DIM = (1 << 14) + 37
+VECTOR_CHUNK = 1 << 12
+VECTOR_NNZ = (1 << 16) + 61
+VECTOR_COMBINE_CASES = {
+    # columns 1-4, 6-7 and 9-11 are empty: 6-7 at a block edge (256)
+    "vector_empty_columns":
+        (128, lambda r: [5, 0, 0, 0, 0, 251, 0, 0, 7, 0, 0, 0]),
+    # column 2 holds [9, 700): a suffix, four whole blocks, a head of 60
+    "vector_column_spanning_several_blocks": (128, lambda r: [4, 5, 691, 3]),
+    # column 1 ends on 256 == 2 * T; column 3 starts and ends on edges
+    "vector_boundary_on_a_block_edge":
+        (128, lambda r: [100, 156, 0, 128, 1]),
+    # prefixes that only grow: what d2 * mv of the HVP path looks like
+    "vector_all_positive_d": (128, lambda r: r.integers(0, 4, 64)),
+    # columns wider than T, and wider than 3 T, among the first 300
+    "vector_zipf_columns":
+        (64, lambda r: np.minimum(r.zipf(1.3, 300), 200)),
+}
+
+
+def _vector_combine_problem(case, seed):
+    T, head = VECTOR_COMBINE_CASES[case]
+    r = np.random.default_rng(seed)
+    head = np.asarray(head(r))
+    counts = np.concatenate([head, _spread(
+        r, VECTOR_NNZ - head.sum(), VECTOR_DIM - head.shape[0])])
+    col_starts = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    nnz = int(col_starts[-1])
+    B = -(-nnz // T)
+    d = (r.random(nnz) + 0.5 if case == "vector_all_positive_d"
+         else r.normal(size=nnz))
+    contrib = np.pad(d.astype(np.float32), (0, B * T - nnz))
+    local = np.cumsum(contrib.reshape(B, T), axis=1, dtype=np.float32)
+    return (jnp.asarray(local.reshape(-1)), jnp.asarray(local[:, -1]),
+            jnp.asarray(col_starts), T)
+
+
+@pytest.fixture
+def gather_form(request, monkeypatch):
+    """The TPU's gather form, for the cases named ``vector_*``."""
+    from photon_ml_tpu import types
+
+    if not request.node.callspec.params["case"].startswith("vector_"):
+        yield None
+        return
+    monkeypatch.setattr(types, "_GATHER_CHUNK", VECTOR_CHUNK)
+    before = types.gather_mode()
+    types.set_gather_mode("vector")
+    yield "vector"
+    types.set_gather_mode(before)
+
+
 @pytest.mark.parametrize("under", ["jit", "shard_map"])
-@pytest.mark.parametrize("case", list(COMBINE_CASES))
-def test_boundary_combine_bit_equal_to_four_gathers(case, under):
+@pytest.mark.parametrize(
+    "case", list(COMBINE_CASES) + list(VECTOR_COMBINE_CASES))
+def test_boundary_combine_bit_equal_to_four_gathers(case, under, gather_form):
+    problem = _combine_problem
+    if gather_form == "vector":
+        from photon_ml_tpu.types import _GATHER_MIN_SIZE
+
+        problem = _vector_combine_problem
+        assert VECTOR_DIM + 1 >= _GATHER_MIN_SIZE
+        assert VECTOR_DIM % VECTOR_CHUNK  # a ragged last chunk
+        # the form engaged: 128-wide rows, chunk by chunk, and no gather
+        # of single words over the column boundaries
+        local, bt, cs, T = problem(case, 0)
+        text = jax.jit(blocked_boundary_combine, static_argnums=3).lower(
+            local, bt, cs, T).as_text()
+        assert "slice_sizes = array<i64: 1, 128>" in text
+        assert f"tensor<{VECTOR_CHUNK}x128xf32>" in text
+        assert not re.search(
+            rf"tensor<{VECTOR_DIM}(x1)?xi32>\) -> tensor<{VECTOR_DIM}xf32>",
+            text)
     if under == "jit":
-        local, bt, cs, T = _combine_problem(case, 0)
+        local, bt, cs, T = problem(case, 0)
         got = jax.jit(blocked_boundary_combine, static_argnums=3)(
             local, bt, cs, T)
         want = _four_gather_combine(local, bt, cs, T)
@@ -376,7 +454,7 @@ def test_boundary_combine_bit_equal_to_four_gathers(case, under):
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
-        draws = [_combine_problem(case, seed) for seed in range(4)]
+        draws = [problem(case, seed) for seed in range(4)]
         T = draws[0][3]
         local, bt, cs = (jnp.stack(leaf) for leaf in zip(
             *(draw[:3] for draw in draws)))

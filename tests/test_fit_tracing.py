@@ -197,12 +197,10 @@ LOWERED = {
 }
 
 
-@pytest.mark.parametrize("fit", list(LOWERED),
-                         ids=["-".join(f) for f in LOWERED])
-def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
+def _lower_fit(fit, n, d):
+    """-> (the fit's lowering on one device, the batch)."""
     optimizer, line_search, sparse_grad = fit
-    program, optimizer_scopes, transposes = LOWERED[fit]
-    batch, d = _criteo_like(n=2048)  # three tiles of the prefix sum
+    batch, d = _criteo_like(n=n, d=d)
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     obj = make_objective("logistic")
     cfg = OptimizerConfig(max_iters=3, tolerance=0.0)
@@ -213,7 +211,36 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
                                 sparse_grad, False)
     args = (jnp.zeros(d, jnp.float32), shard_batch(batch, mesh), 1.0)
     args += (0.1, None) if optimizer == "owlqn" else (None,)
-    lowered = dp.cached_jit(obj, key, make).lower(*args)
+    return dp.cached_jit(obj, key, make).lower(*args), batch
+
+
+# (indices, result type, location) of every gather in a lowering's text
+_GATHER = re.compile(
+    r'"stablehlo\.gather"\(.*: \(tensor<[^>]*>, '
+    r'tensor<(\d+)x1xi\d+>\) -> tensor<([^>]*)> loc\((#loc\d+)\)')
+
+
+def _combine_gathers(text):
+    """{call site: [(indices, result type, name stack below the combine's
+    scope)]} of the ``stablehlo.gather``s under the boundary combine."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    sites = collections.defaultdict(list)
+    for indices, result, loc in _GATHER.findall(text):
+        site, scope, below = names[loc].partition(
+            "photon.csc/boundary_combine/")
+        if scope:
+            sites[site].append((int(indices), result, below))
+    return sites
+
+
+@pytest.mark.parametrize("fit", list(LOWERED),
+                         ids=["-".join(f) for f in LOWERED])
+def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
+    optimizer, line_search, sparse_grad = fit
+    program, optimizer_scopes, transposes = LOWERED[fit]
+    d = 256
+    # three tiles of the prefix sum
+    lowered, batch = _lower_fit(fit, n=2048, d=d)
     text = lowered.as_text(debug_info=True)
     assert f"@jit_{program}" in text
     stacks = set(re.findall(r'loc\("([^"]*photon\.[^"]*)"', text))
@@ -223,22 +250,17 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
           if s.endswith("photon.csc/boundary_combine/lp/gather")}
     assert len(lp) == transposes, sorted(lp)
     # block totals are read where a column spans a block, not per column:
-    # at each call site one gather runs over the dim + 1 column boundaries
-    # (`lp`) and every other over the B block boundaries at most
+    # at each call site one gather runs over the dim column boundaries
+    # after the first (`lp`: words here, dim < 2^14) and every other over
+    # the B block boundaries at most
     B = -(-batch.features.indices.size // (256 * 128))  # the smaller tile
     assert 1 < B < d
-    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    sites = collections.defaultdict(list)
-    for indices, loc in re.findall(
-            r'"stablehlo\.gather"\(.*: \(tensor<[^>]*>, '
-            r'tensor<(\d+)x1xi\d+>\).* loc\((#loc\d+)\)', text):
-        site, scope, _ = names[loc].partition("photon.csc/boundary_combine/")
-        if scope:
-            sites[site].append(int(indices))
+    sites = _combine_gathers(text)
     assert len(sites) == transposes, sorted(sites)
     for site, gathers in sites.items():
         *short, longest = sorted(gathers)
-        assert longest == d + 1 and short and short[-1] <= B, (site, gathers)
+        assert longest == (d, f"{d}xf32", "lp/gather"), (site, gathers)
+        assert short and short[-1][0] <= B, (site, gathers)
     if sparse_grad == "csc_pallas":
         assert any("photon_multiply_prefix_sum" in s for s in stacks)
     if optimizer == "owlqn":
@@ -247,6 +269,76 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
             r'op_name="([^"]*boundary_combine/lp/gather)"',
             lowered.compile().as_text()))
         assert len(compiled) == 2, sorted(compiled)
+
+
+def test_lowered_fit_reads_the_column_boundaries_as_rows(vector_gather):
+    """From dim = 2^14 on, `lp` takes ``table_gather``'s vector form:
+    128-lane rows of the block-local prefixes and a lane select, under
+    ``lp/rows`` and ``lp/select``; no gather of single words over the
+    column boundaries is left in the fit."""
+    d = types._GATHER_MIN_SIZE
+    lowered, batch = _lower_fit(("lbfgs", "margin", "csc_pallas"), n=2048,
+                                d=d)
+    text = lowered.as_text(debug_info=True)
+    B = -(-batch.features.indices.size // (256 * 128))
+    rows = B * 256  # of the block-local prefixes, seen as [rows, 128]
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    # the row gather is `jnp.take`, a function of its own in the text:
+    # follow the call under `lp/rows` to the gather in its body
+    calls = collections.defaultdict(list)
+    for callee, types_, loc in re.findall(
+            r'call @(_take\w*)\(.*\) : (\(.*\) -> tensor<[^>]*>) '
+            r'loc\((#loc\d+)\)', text):
+        site, scope, below = names[loc].partition(
+            "photon.csc/boundary_combine/")
+        if scope:
+            calls[site].append((callee, types_, below))
+    assert len(calls) == 2, sorted(calls)  # g0, a pass
+    for site, ((callee, types_, below),) in calls.items():
+        assert types_ == (f"(tensor<{rows}x128xf32>, tensor<{d}xi32>) -> "
+                          f"tensor<{d}x128xf32>"), (site, types_)
+        assert below == "lp/rows/jit(_take)", (site, below)
+        body = text[text.index(f"func.func private @{callee}("):]
+        body = body[:body.index("\n  }")]
+        gather, = re.findall(r'"stablehlo\.gather".*', body)
+        assert "slice_sizes = array<i64: 1, 128>" in gather
+        assert f"tensor<{d}x1xi32>) -> tensor<{d}x128xf32>" in gather
+    # what is left under the combine's scope reads <= B words: `span`
+    sites = _combine_gathers(text)
+    assert sorted(sites) == sorted(calls)
+    for site, gathers in sites.items():
+        assert gathers and max(gathers)[0] <= B, (site, gathers)
+        assert {below for _, _, below in gathers} <= {
+            "span/gather", "span/jit(searchsorted)/gather"}, gathers
+    stacks = set(re.findall(r'loc\("([^"]*photon\.[^"]*)"', text))
+    assert any("photon.csc/boundary_combine/lp/select/" in s for s in stacks)
+    # the product gathers keep their scopes, and the combine has none of them
+    assert any("photon.table_gather/rows/" in s for s in stacks)
+    assert not any("boundary_combine" in s and "photon.table_gather" in
+                   s.partition("boundary_combine")[2] for s in stacks)
+    # no gather of single floats over dim or dim + 1 indices is left (the
+    # binary search that builds `col_starts` reads int32 column ids)
+    for indices, result, _ in _GATHER.findall(text):
+        assert not (int(indices) in (d, d + 1) and "x128x" not in result
+                    and re.search(r"xf\d+$", result)), (indices, result)
+
+
+def test_scalar_mode_leaves_no_row_gather_in_the_fit():
+    """``PHOTON_GATHER=scalar`` is ``set_gather_mode("scalar")`` at import:
+    the same fit then reads words everywhere, `lp` among them."""
+    before = types.gather_mode()
+    types.set_gather_mode("scalar")
+    try:
+        d = types._GATHER_MIN_SIZE
+        lowered, _ = _lower_fit(("lbfgs", "margin", "csc_pallas"), n=2048,
+                                d=d)
+        text = lowered.as_text(debug_info=True)
+    finally:
+        types.set_gather_mode(before)
+    assert "stablehlo.gather" in text
+    assert "slice_sizes = array<i64: 1, 128>" not in text
+    for site, gathers in _combine_gathers(text).items():
+        assert max(gathers) == (d, f"{d}xf32", "lp/gather"), (site, gathers)
 
 
 def test_build_csc_is_one_named_cached_program():
@@ -572,19 +664,35 @@ def test_kernels_table_of_the_recorded_trace(capsys):
                   "photon.table_gather/select", "photon.lbfgs/two_loop",
                   "photon.lbfgs/update", "photon.glm/loss"):
         assert rollup[scope]["device_s"] > 0, scope
-    # the one gather over the column boundaries, and the block totals of
-    # the spanning columns: no `bt`, `bp_hi`, `bp_lo` over dim any more
-    combine = {s.rpartition("/")[2]: row for s, row in scopes.items()
-               if s.startswith("photon.csc/boundary_combine/")}
-    assert sorted(combine) == ["lp", "span"]
+    # the one gather over the column boundaries, in the vector form (2^14
+    # buckets: 128-lane rows, a lane select, and `lp`'s own index
+    # arithmetic and `where`), and the block totals of the spanning
+    # columns; "" is the function's own `lp[1:] - lp[:-1]`
+    prefix = "photon.csc/boundary_combine"
+    combine = {s[len(prefix):].lstrip("/"): row for s, row in scopes.items()
+               if s.startswith(prefix)}
+    assert sorted(combine) == ["", "lp", "lp/rows", "lp/select", "span"]
     for row in combine.values():
         assert row["executions"] > 0 and row["instructions"]
-    assert combine["span"]["device_s"] < 0.1 * combine["lp"]["device_s"]
+    lp_s = sum(row["device_s"] for s, row in combine.items()
+               if s.startswith("lp"))
+    assert combine["lp/rows"]["device_s"] > combine["lp/select"]["device_s"]
+    assert combine["lp/rows"]["device_s"] > 10 * combine["lp"]["device_s"]
+    assert combine["span"]["device_s"] < 0.5 * lp_s
+    # `photon.table_gather/*` counts the product gathers alone: two fits of
+    # 4 `X p` and 4 `d[rows]` each; the 4 + 4 row gathers of `lp` are its own
+    ops = next(iter(xplane.device_ops(KERNEL_TRACE).values()))
+    row_gathers = collections.Counter(
+        xplane.scope_of(op["tf_op"]) for op in ops
+        if op["name"].startswith("fusion")
+        and op["tf_op"].rstrip(":").endswith("/gather")
+        and "/rows/" in op["tf_op"])
+    assert row_gathers == {"photon.table_gather/rows": 2 * (4 + 4),
+                           "photon.csc/boundary_combine/lp/rows": 2 * 4}
     assert sum(r["share"] for r in scopes.values()) == pytest.approx(1.0)
     assert sum(r["device_s"] for r in scopes.values()) == pytest.approx(
         table["busy_s"])
     # every event's program is named after the fit
-    ops = next(iter(xplane.device_ops(KERNEL_TRACE).values()))
     programs = {op["tf_op"].split("/")[0] for op in ops if op["tf_op"]}
     assert "jit(photon_fit_lbfgs_margin)" in programs
     assert "jit(run)" not in programs
@@ -611,6 +719,32 @@ def test_scope_of_a_name_stack():
                     "branch_1_fun/select_n:") == "photon.lbfgs/line_search"
     assert scope_of("jit(f)/while/body/add:") is None
     assert scope_of("") is None
+
+
+def test_scope_of_the_boundary_gather_in_its_vector_form():
+    """`lp` calls ``table_gather``'s body with scopes of its own, so its
+    rows and its lane select stay the combine's in the kernels table."""
+    from photon_ml_tpu.obs.xplane import scope_of
+
+    lp = ("jit(photon_fit_lbfgs_margin)/while/body/photon.lbfgs/update/"
+          "photon.csc/boundary_combine/lp/")
+    assert scope_of(lp + "while/body/closed_call/rows/jit(_take)/gather:") == (
+        "photon.csc/boundary_combine/lp/rows")
+    assert scope_of(lp + "while/body/closed_call/select/reduce_sum:") == (
+        "photon.csc/boundary_combine/lp/select")
+    assert scope_of(lp + "rows/jit(_take)/gather:") == (
+        "photon.csc/boundary_combine/lp/rows")  # one chunk: no lax.map
+    assert scope_of(lp + "while:") == "photon.csc/boundary_combine/lp"
+    assert scope_of(lp + "jit(_where)/select_n:") == (
+        "photon.csc/boundary_combine/lp")
+    # the product gather's own stack, for contrast
+    assert scope_of("jit(photon_fit_lbfgs_margin)/while/body/"
+                    "photon.table_gather/while/body/closed_call/"
+                    "photon.table_gather/rows/jit(_take)/gather:") == (
+        "photon.table_gather/rows")
+    for stack in (lp + "while/body/closed_call/rows/jit(_take)/gather:",
+                  lp + "while/body/closed_call/select/reduce_sum:"):
+        assert not scope_of(stack).startswith("photon.table_gather")
 
 
 def test_wire_reader_reads_the_trace_without_names():

@@ -17,6 +17,7 @@ explicitly.
 
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -194,3 +195,46 @@ def test_serving_fused_score_compiles(one_chip):
         s((B, k), i32), s((B, k)), s((slots, d_re)),
         s((B,), i32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+# X^T d at the sizes of the benchmark's cells: (rows, columns, features a
+# row) -> row gathers in the program (d[rows], then `lp` run by run)
+XTD_CELLS = {
+    "criteo-lr": ((1 << 19, 1 << 24, 39), 2),  # 81.8 MB of prefixes: whole
+    "criteo-lr-tron": ((1 << 20, 1 << 24, 39), 3),  # 163.6 MB: in two runs
+    "glmix-ml20m": ((1 << 22, 1 << 20, 12), 4),  # 201.3 MB: in three runs
+}
+
+
+@pytest.mark.parametrize("cell", list(XTD_CELLS))
+def test_row_gathers_read_tables_in_fast_memory(one_chip, cell):
+    """A row gather costs 1.8 ns a row out of a table the TPU compiler
+    keeps in its fast memory space (``S(1)`` in a compiled layout) and
+    9-15 ns out of one it leaves in HBM (PERF.md section 7.7, measured):
+    ``table_gather`` reads a table too large for that space in runs that
+    fit. Held here on the compiled ``X^T d`` of every cell's size: each
+    row gather's table carries ``S(1)``."""
+    from photon_ml_tpu.ops.pallas_kernels import csc_transpose_apply_pallas
+    from photon_ml_tpu.types import CSCTranspose
+
+    (rows, dim, k), gathers = XTD_CELLS[cell]
+
+    def xtd(csc_rows, col_starts, d):
+        return csc_transpose_apply_pallas(
+            CSCTranspose(values=None, rows=csc_rows, col_starts=col_starts),
+            d)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(xtd).lower(s((rows * k,), i32), s((dim + 1,), i32),
+                              s((rows,), f32)).compile().as_text()
+    layouts = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", text,
+                              re.M))
+    tables = [layouts[table] for table in re.findall(
+        r"= f32\[\d+,128\]\S* fusion\((%[\w.\-]+), [^\n]*kind=kCustom"
+        r"[^\n]*/rows/jit\(_take\)/gather", text)]
+    assert len(tables) == gathers, tables
+    for layout in tables:
+        assert re.fullmatch(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}",
+                            layout), tables

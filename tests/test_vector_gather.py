@@ -49,6 +49,33 @@ def test_chunked_path_bit_identical(vector_mode, monkeypatch):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(table)[idx])
 
 
+@pytest.mark.parametrize("rows_cap", [5, 8, 16])  # 4, 2 runs; one, whole
+def test_table_read_in_runs_bit_identical(vector_mode, monkeypatch, rows_cap):
+    """A table of more than ``_GATHER_TABLE_BYTES`` is gathered run by run
+    (ISSUE 29: out of a table too large for the TPU's fast memory space a
+    row costs 9-15 ns, out of a run that fits 1.8): the last run is
+    ragged, indices sit on every run's edges, and the chunk loop runs
+    inside each run."""
+    monkeypatch.setattr(T, "_GATHER_TABLE_BYTES", rows_cap * 128 * 4)
+    monkeypatch.setattr(T, "_GATHER_CHUNK", 1 << 12)
+    rng = np.random.default_rng(4)
+    d = 15 * 128 + 77  # 16 rows of 128 once padded
+    table, idx = _rand_table_idx(rng, d, ((1 << 14) + 5,))
+    edges = jnp.asarray([0, d - 1] + [r * 128 + o for r in range(1, 16)
+                                      for o in (-1, 0, 1) if r * 128 + o < d],
+                        jnp.int32)
+    idx = idx.at[:edges.shape[0]].set(edges)
+    lowered = jax.jit(T.table_gather).lower(table, idx).as_text()
+    runs = -(-16 // rows_cap)
+    assert lowered.count("stablehlo.while") == runs  # a chunk loop a run
+    out = jax.jit(T.table_gather)(table, idx)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(table)[idx])
+    sorted_idx = jnp.sort(idx)  # what the boundary combine hands in
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(T.table_gather)(table, sorted_idx)),
+        np.asarray(table)[sorted_idx])
+
+
 def test_small_and_scalar_modes_fall_through(vector_mode):
     rng = np.random.default_rng(2)
     table, idx = _rand_table_idx(rng, 512, (64,))  # below _GATHER_MIN_SIZE
