@@ -1,24 +1,13 @@
-"""Headline benchmark: Criteo-shaped sparse logistic regression throughput.
+"""CPU integration gates, one a sub-command: ``python bench.py <mode>``.
 
-Mirrors the north star in BASELINE.json ("Criteo-1TB logistic-reg wall-clock
-vs 256-exec Spark") at single-run scale: a Criteo-like batch (39 nonzeros per
-row, hashed feature space) trained with the distributed jitted L-BFGS path —
-the exact hot loop SURVEY.md §4.2 identifies (the reference pays one cluster
-treeAggregate round-trip per optimizer iteration; here an iteration is an
-on-device fused pass + psum).
+Each mode drives one subsystem end to end at a CPU size, checks its own
+invariants (compile counts, bit parity, shed and recovery behaviour), writes
+``BENCH_<mode>.json`` and exits non-zero when one fails
+(``scripts/ci_bench_smoke.sh`` runs them). The seconds they print are a
+CPU's: none is a speed of the system. Speeds are measured on the chip by
+``benchmark/run.py`` (``BENCHMARK.json``, ``PERF.md``).
 
-Metric: example-passes/second = rows x optimizer-iterations / wall-clock of
-the jitted fit (compile time excluded; one warm-up fit on identical shapes
-precedes the timed run).
-
-Also reported (stderr + unit string): a model-FLOPs throughput and an
-effective-HBM-bandwidth estimate. The workload is memory-bound, so the
-bandwidth fraction is the honest utilization number; the FLOP model is
-4*nnz per pass (margin gather-multiply-add + transposed contraction).
-
-Needs an accelerator: with no chip and no explicit ``JAX_PLATFORMS=cpu``
-it exits non-zero rather than print a CPU number under the device metric's
-name. Prints ONE JSON line: {"metric", "value", "unit", "environment"}.
+With no mode, lists the modes and exits 2.
 """
 
 from __future__ import annotations
@@ -86,217 +75,6 @@ def _environment() -> dict:
         "photon_check": analysis.repo_report(
             os.path.dirname(os.path.abspath(__file__))),
     }
-
-
-# Published peaks of one chip, keyed by ``jax.devices()[0].device_kind``.
-# A device that is not in the table is an error, not a default.
-_DEVICE_PEAKS = {
-    "TPU v5 lite": {
-        # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and
-        # 819 GB/s of HBM bandwidth per chip
-        "flops": 1.97e14,
-        "bytes_per_s": 8.19e11,
-        # core clock: not in the published table; the figure the
-        # 2026-07-31 builder session used for its cycles/element view
-        "clock_hz": 9.4e8,
-    },
-}
-
-
-def main() -> None:
-    import jax
-
-    from photon_ml_tpu.utils import configure_compile_cache
-
-    configure_compile_cache()
-    import jax.numpy as jnp
-
-    from photon_ml_tpu.ops.objective import make_objective
-    from photon_ml_tpu.optimize import OptimizerConfig
-    from photon_ml_tpu.parallel.data_parallel import build_csc, fit_distributed
-    from photon_ml_tpu.parallel.mesh import make_mesh
-    from photon_ml_tpu.types import LabeledBatch, SparseFeatures
-
-    platform = jax.devices()[0].platform
-    if platform == "cpu" and os.environ.get("JAX_PLATFORMS") != "cpu":
-        # no fallback: a CPU figure is never printed under the device
-        # metric's name unless the caller pinned the CPU (CI smoke)
-        print("no accelerator found and JAX_PLATFORMS=cpu not set — "
-              "aborting", file=sys.stderr)
-        sys.exit(3)
-    # Criteo shape: 39 features/row. Sized to finish the timed fit in
-    # seconds; the CPU-pinned CI smoke runs a small shape.
-    if platform == "cpu":
-        n_rows, dim, iters = 1 << 15, 1 << 14, 10
-    else:
-        n_rows, dim, iters = 1 << 21, 1 << 18, 20
-    k = 39
-
-    # Synthesize the dataset ON DEVICE: a host->device transfer would time
-    # the pipe, not the hot loop. jit'd jax.random keeps everything in HBM.
-    @jax.jit
-    def make_data(key):
-        k_idx, k_w, k_lab = jax.random.split(key, 3)
-        indices = jax.random.randint(k_idx, (n_rows, k), 0, dim, jnp.int32)
-        w_true = jax.random.normal(k_w, (dim,), jnp.float32) * 0.5
-        logits = jnp.sum(w_true[indices], axis=1)
-        labels = (jax.random.uniform(k_lab, (n_rows,))
-                  < jax.nn.sigmoid(logits)).astype(jnp.float32)
-        return indices, labels
-
-    indices, labels = jax.block_until_ready(make_data(jax.random.key(0)))
-
-    mesh = make_mesh()
-    obj = make_objective("logistic")
-    # Criteo rows are one-hot categorical: the implicit-ones layout
-    # (values=None) skips the values array entirely — half the bytes per
-    # sparse pass on the HBM-bound hot loop (types.SparseFeatures).
-    batch = LabeledBatch(
-        SparseFeatures(indices, None, dim=dim),
-        labels,
-        jnp.zeros((n_rows,), jnp.float32),
-        jnp.ones((n_rows,), jnp.float32),
-    )
-    w0 = jnp.zeros((dim,), jnp.float32)
-
-    # The column-sorted view is a once-per-DATASET artifact (like ingestion):
-    # build it outside the timed fit and share it across calibration + the
-    # headline run (VERDICT r2: the 82M-nnz sort was re-paid per fit and
-    # poisoned the csc calibration).
-    csc = None
-    try:
-        csc = jax.block_until_ready(build_csc(obj, batch, mesh))
-    except Exception as e:
-        print(f"csc precompute failed ({e}); csc modes will sort in-fit",
-              file=sys.stderr)
-
-    def run(sparse_grad, n_iters, salt=0):
-        # tolerance=0 disables convergence tests -> the iteration count is
-        # exact (optimize/common.py honors an explicit 0 since round 3).
-        # ``salt`` perturbs w0 so a timed run is a genuinely different
-        # computation from its warm-up, so a backend that memoizes
-        # identical executions cannot answer the timed run from the
-        # warm-up's result.
-        res = fit_distributed(
-            obj, batch, mesh, w0 + jnp.float32(salt) * 1e-8, l2=1.0,
-            optimizer="lbfgs",
-            config=OptimizerConfig(max_iters=n_iters, tolerance=0.0),
-            sparse_grad=sparse_grad,
-            precomputed_csc=(csc if sparse_grad.startswith("csc") else None),
-        )
-        # sync by SCALAR FETCH, not block_until_ready: a device->host read
-        # of the result cannot complete before the computation has actually
-        # run, whatever the transfer/queue semantics of the backend.
-        res = res._replace(iterations=int(res.iterations),
-                           value=float(res.value))
-        return res
-
-    # Sparse-gradient strategy space (scatter-add vs scatter-free CSC prefix
-    # sums vs the fused Pallas kernel — types.CSCTranspose); which wins is
-    # hardware-dependent, so calibrate unless pinned via BENCH_SPARSE_GRAD.
-    #
-    # Every calibration fit runs at the FULL headline iteration count: a
-    # different max_iters is a different compiled program — a short
-    # calibration + separate accuracy fits + a separate headline would pay
-    # ~2x the compiles for no extra information. Each mode's single timed,
-    # salted, fetch-synced run serves as its timing, its accuracy evidence
-    # (final w vs the scatter reference), and — for the winner — the
-    # headline measurement itself.
-    mode = os.environ.get("BENCH_SPARSE_GRAD", "auto")
-    if mode == "auto":
-        times, results = {}, {}
-        # csc_precise is NOT a candidate: without jax_enable_x64 (never set
-        # here; TPUs have no native f64) its f64 prefix silently degrades to
-        # exactly the global-f32 scheme the blocked default replaces
-        for i, m in enumerate(("scatter", "csc", "csc_segment", "csc_pallas")):
-            try:
-                run(m, iters, salt=1)  # compile + warm-up
-                t0 = time.perf_counter()
-                r = run(m, iters, salt=2 + i)
-                times[m] = time.perf_counter() - t0
-                results[m] = r
-            except Exception as e:  # a mode that fails to lower is skipped
-                print(f"calibration: {m} failed: {e}", file=sys.stderr)
-        print(f"calibration ({iters} iters): {times}", file=sys.stderr)
-        if not times:
-            print("calibration: every mode failed — no measurement",
-                  file=sys.stderr)
-            sys.exit(4)
-        # speed is not enough: cross-check each candidate's solution against
-        # the scatter reference (an inaccurate fast mode must be visible).
-        # The f32 cumsum-difference transpose loses ~sqrt(nnz)*eps ≈ 1e-3
-        # relative at 82M nnz, so the fastest mode can legitimately fail the
-        # gate — walk the modes fastest-first and take the first accurate
-        # one instead of falling straight back to scatter.
-        w_ref = (np.asarray(results["scatter"].w)
-                 if "scatter" in results else None)
-        mode = "scatter"
-        for m in sorted(times, key=times.get):
-            if m == "scatter" or w_ref is None:
-                mode = m  # scatter is its own reference; or none available
-                break
-            w_got = np.asarray(results[m].w)
-            dev_rel = float(np.linalg.norm(w_got - w_ref)
-                            / max(np.linalg.norm(w_ref), 1e-30))
-            print(f"calibration accuracy: |w_{m} - w_scatter| rel = "
-                  f"{dev_rel:.2e}", file=sys.stderr)
-            if dev_rel <= 1e-3:
-                mode = m
-                break
-            print(f"calibration: {m} rejected (> 1e-3)", file=sys.stderr)
-        print(f"calibration -> {mode}", file=sys.stderr)
-        res, elapsed = results[mode], times[mode]
-    else:
-        run(mode, iters, salt=101)  # compile + warm-up
-        t0 = time.perf_counter()
-        res = run(mode, iters, salt=102)  # scalar-fetch-synced inside run()
-        elapsed = time.perf_counter() - t0
-
-    done = int(res.iterations)
-    value = n_rows * max(done, 1) / elapsed
-
-    # -- utilization model (documented, order-of-magnitude honest) ----------
-    # FLOPs/pass: margin gather-add (nnz) + transposed contraction (nnz);
-    # pointwise loss math is O(n) and ignored. Bytes/pass: int32 indices
-    # (4B) read twice (forward gather + backward transpose view); the
-    # implicit-ones layout has no values array and the d-vector traffic is
-    # negligible at these shapes.
-    nnz = n_rows * k
-    passes = max(done, 1)
-    flops = 2.0 * nnz * passes / elapsed
-    bytes_touched = 8.0 * nnz * passes / elapsed
-    # The sparse hot loop is VPU/HBM work, so the bandwidth fraction is
-    # the real utilization; MFU vs the MXU peak is reported for
-    # completeness. The pass is bounded by the chip's random-gather ISSUE
-    # RATE, not bandwidth (docs/PERF.md), so cycles per gathered element
-    # are reported too: 2 gather passes over nnz per optimizer iteration.
-    if platform == "cpu":
-        util = (f"model {flops/1e9:.3g} GFLOP/s; utilization: not measured "
-                "(CPU run)")
-    else:
-        kind = jax.devices()[0].device_kind
-        if kind not in _DEVICE_PEAKS:
-            print(f"no published peaks for device kind {kind!r}: add it to "
-                  "_DEVICE_PEAKS with its source", file=sys.stderr)
-            sys.exit(5)
-        peaks = _DEVICE_PEAKS[kind]
-        mfu = flops / peaks["flops"]
-        bw_frac = bytes_touched / peaks["bytes_per_s"]
-        cyc_per_gather = peaks["clock_hz"] * elapsed / (2.0 * nnz * passes)
-        util = (f"model {flops/1e9:.3g} GFLOP/s (mfu {mfu:.3g}), "
-                f"~{bytes_touched/1e9:.3g} GB/s HBM ({bw_frac:.3g} of "
-                f"peak), {cyc_per_gather:.2g} cycles/gathered-elem "
-                "(issue-rate view)")
-    print(f"utilization: {util}", file=sys.stderr)
-
-    print(json.dumps({
-        "metric": "criteo_shaped_logreg_lbfgs_example_passes_per_sec",
-        "value": round(value, 1),
-        "unit": f"example-passes/sec ({platform}, {len(jax.devices())} dev, "
-                f"n={n_rows}, d={dim}, k={k}, iters={done}, "
-                f"sparse_grad={mode}; {util})",
-        "environment": _environment(),
-    }))
 
 
 def serving_main() -> None:
@@ -2816,26 +2594,16 @@ def trace_main() -> None:
         sys.exit(9)
 
 
+_MODES = {
+    "serving": serving_main, "degrade": degrade_main,
+    "affinity": affinity_main, "swap": swap_main, "stream": stream_main,
+    "cd": cd_main, "path": path_main, "shard": shard_main,
+    "recovery": recovery_main, "trace": trace_main,
+}
+
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "degrade":
-        degrade_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "serving":
-        serving_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "affinity":
-        affinity_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "swap":
-        swap_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "stream":
-        stream_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "cd":
-        cd_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "path":
-        path_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "shard":
-        shard_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "recovery":
-        recovery_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "trace":
-        trace_main()
-    else:
-        main()
+    if len(sys.argv) < 2 or sys.argv[1] not in _MODES:
+        print("usage: python bench.py <mode>; modes: " + " ".join(_MODES),
+              file=sys.stderr)
+        sys.exit(2)
+    _MODES[sys.argv[1]]()

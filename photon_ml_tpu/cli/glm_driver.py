@@ -571,11 +571,10 @@ def _run(args) -> int:
     grid_csc = None
     if not streaming:
         from photon_ml_tpu.parallel.data_parallel import (
-            build_csc, resolve_sparse_grad,
+            build_csc, resolve_sparse_grad, uses_csc,
         )
 
-        if resolve_sparse_grad("auto",
-                               batch.features).startswith("csc"):
+        if uses_csc(resolve_sparse_grad("auto", batch.features)):
             grid_csc = build_csc(objective, batch, mesh)
 
     path_solver = None

@@ -71,12 +71,19 @@ from photon_ml_tpu.obs import metrics as obs_metrics
 from photon_ml_tpu.obs import trace as obs_trace
 from photon_ml_tpu.ops.objective import make_objective
 from photon_ml_tpu.ops.regularization import RegularizationContext, RegularizationType
-from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
+from photon_ml_tpu.optimize import (
+    OptimizerConfig,
+    get_optimizer,
+    run_optimizer,
+)
 from photon_ml_tpu.parallel import fault_injection
 from photon_ml_tpu.parallel.data_parallel import (
     cached_jit,
     distributed_hvp,
     distributed_value_and_grad,
+    make_csc_path,
+    resolve_sparse_grad,
+    uses_csc,
 )
 from photon_ml_tpu.parallel.entity_shard import (
     EntityShardSpec,
@@ -418,7 +425,7 @@ class _FixedState:
             optimizer = "owlqn"  # the reference routes L1 to OWLQN
         self.obj = make_objective(task, normalization=cfg.normalization,
                                   intercept_index=cfg.intercept_index)
-        opt = get_optimizer(optimizer)
+        get_optimizer(optimizer)  # an unknown name raises here, not at a fit
         cfg_opt = cfg.opt_config()
         d = sp.dim
 
@@ -525,13 +532,13 @@ class _FixedState:
         if cfg.intercept_index >= 0:
             l1_mask = jnp.ones((d,), dtype).at[cfg.intercept_index].set(0.0)
 
-        from photon_ml_tpu.parallel.data_parallel import resolve_sparse_grad
-
         sparse_grad = resolve_sparse_grad(cfg.sparse_grad, feats)
-        use_csc = sparse_grad in ("csc", "csc_pallas")
+        use_csc = uses_csc(sparse_grad)
         if use_csc and not isinstance(feats, SparseFeatures):
             raise ValueError(f"sparse_grad='{sparse_grad}' needs sparse "
                              "features")
+        # each branch leaves ``bind(batch, l2, *view) -> (fg, hvp)``, the
+        # objective at this step's offsets, and the resident ``fit_data``
         if use_mesh or use_csc:
             work_mesh = mesh if use_mesh else make_mesh({"data": 1})
             if use_mesh:
@@ -543,8 +550,6 @@ class _FixedState:
             else:
                 self._offset_sharding = None
             if use_csc:
-                from photon_ml_tpu.parallel.data_parallel import make_csc_path
-
                 build, fg_csc, hvp_csc = make_csc_path(
                     self.obj, work_mesh,
                     use_pallas=(sparse_grad == "csc_pallas"),
@@ -557,47 +562,31 @@ class _FixedState:
                 )
                 fit_data = (feats, labels, weights, csc)
 
-                def _make_fit(run_cfg):
-                    def _fit(w0, offs, l2, l1, data):
-                        feats, labels, weights, csc = data
-                        batch = LabeledBatch(feats, labels, offs, weights)
-                        fg = lambda w: fg_csc(w, batch, csc, l2)
-                        if optimizer == "owlqn":
-                            return opt(fg, w0, l1, run_cfg, l1_mask=l1_mask)
-                        if optimizer == "tron":
-                            return opt(fg, w0, run_cfg,
-                                       hvp=lambda w, v: hvp_csc(w, v, batch, csc, l2))
-                        return opt(fg, w0, run_cfg)
-                    return _fit
+                def bind(batch, l2, csc):
+                    return (lambda w: fg_csc(w, batch, csc, l2),
+                            lambda w, v: hvp_csc(w, v, batch, csc, l2))
             else:
                 fg_dist = distributed_value_and_grad(self.obj, mesh)
-                hvp_dist = distributed_hvp(self.obj, mesh) if optimizer == "tron" else None
-
+                hvp_dist = distributed_hvp(self.obj, mesh)
                 fit_data = (feats, labels, weights)
 
-                def _make_fit(run_cfg):
-                    def _fit(w0, offs, l2, l1, data):
-                        batch = LabeledBatch(data[0], data[1], offs, data[2])
-                        fg = lambda w: fg_dist(w, batch, l2)
-                        if optimizer == "owlqn":
-                            return opt(fg, w0, l1, run_cfg, l1_mask=l1_mask)
-                        if optimizer == "tron":
-                            return opt(fg, w0, run_cfg,
-                                       hvp=lambda w, v: hvp_dist(w, v, batch, l2))
-                        return opt(fg, w0, run_cfg)
-                    return _fit
+                def bind(batch, l2):
+                    return (lambda w: fg_dist(w, batch, l2),
+                            lambda w, v: hvp_dist(w, v, batch, l2))
         else:
             self._offset_sharding = None
             fit_data = (feats, labels, weights)
 
-            def _make_fit(run_cfg):
-                def _fit(w0, offs, l2, l1, data):
-                    batch = LabeledBatch(data[0], data[1], offs, data[2])
-                    fg = lambda w: self.obj.value_and_grad(w, batch, l2)
-                    if optimizer == "owlqn":
-                        return opt(fg, w0, l1, run_cfg, l1_mask=l1_mask)
-                    return opt(fg, w0, run_cfg)
-                return _fit
+            def bind(batch, l2):
+                return lambda w: self.obj.value_and_grad(w, batch, l2), None
+
+        def _make_fit(run_cfg):
+            def _fit(w0, offs, l2, l1, data):
+                batch = LabeledBatch(data[0], data[1], offs, data[2])
+                fg, hvp = bind(batch, l2, *data[3:])
+                return run_optimizer(optimizer, fg, w0, run_cfg, l1=l1,
+                                     l1_mask=l1_mask, hvp=hvp)
+            return _fit
 
         # scoring features: when training uses every row un-padded, the
         # training copy IS the scoring copy — aliasing avoids the 2x

@@ -261,7 +261,8 @@ class PathSolver:
             if mesh is None:
                 raise ValueError("batch= mode needs mesh=")
             from photon_ml_tpu.parallel.data_parallel import (
-                cached_jit, distributed_value_and_grad, resolve_sparse_grad)
+                cached_jit, distributed_value_and_grad, resolve_sparse_grad,
+                uses_csc)
             from photon_ml_tpu.parallel.mesh import shard_batch
             from photon_ml_tpu.types import SparseFeatures
 
@@ -296,8 +297,7 @@ class PathSolver:
             # only (restricted geometry differs); it is an error to hold
             # one when the resolved sparse-grad path would not read it
             resolved = resolve_sparse_grad(sparse_grad, feats)
-            self._pcsc = precomputed_csc if resolved.startswith("csc") \
-                else None
+            self._pcsc = precomputed_csc if uses_csc(resolved) else None
             # certification kernel: the batch is sharded ONCE and the fg
             # runner cached on the full objective, so every lambda's full-
             # gradient pass reuses one executable

@@ -15,7 +15,7 @@ from __future__ import annotations
 import functools
 import time
 from collections import OrderedDict
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +26,7 @@ from photon_ml_tpu.obs import metrics as obs_metrics
 from photon_ml_tpu.obs import trace as obs_trace
 from photon_ml_tpu.ops.losses import apply_weights, mask_margins
 from photon_ml_tpu.ops.objective import GLMObjective
-from photon_ml_tpu.optimize import OptimizerConfig, get_optimizer
+from photon_ml_tpu.optimize import OptimizerConfig, run_optimizer
 from photon_ml_tpu.optimize.common import OptimizationResult
 from photon_ml_tpu.parallel.mesh import shard_batch
 from photon_ml_tpu.types import (
@@ -236,9 +236,28 @@ def _norm_chain_t(norm, gx, d_sum):
     return gx
 
 
+def _weighted_loss(loss, weights, labels):
+    """-> ``m -> Σ wᵢ l(mᵢ)``, the data term as a function of the margins
+    (weight-0 rows masked out of the loss and out of its derivative)."""
+    return lambda m: jnp.sum(apply_weights(
+        weights, loss.loss(mask_margins(weights, m), labels)))
+
+
+def _csc_apply(sparse_grad: str):
+    """-> ``(apply_t, check_vma)`` of a CSC ``sparse_grad``: the function
+    ``apply_t(csc, d) = Xᵀd`` and what ``shard_map`` is told around it.
+    check_vma is off around the Pallas scan: the interpret-mode kernel body
+    can't thread varying-axis types through pallas_call (the reductions
+    are explicit psums, so nothing relies on vma-driven transposes)."""
+    if sparse_grad == "csc_pallas":
+        from photon_ml_tpu.ops.pallas_kernels import csc_transpose_apply_pallas
+
+        return csc_transpose_apply_pallas, False
+    return csc_transpose_apply, True
+
+
 def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
-                  use_pallas: bool = False, precise: bool = False,
-                  segment: bool = False, with_cols: Optional[bool] = None):
+                  use_pallas: bool = False):
     """Scatter-free sparse gradient path (see ``types.CSCTranspose``).
 
     Returns (build, fg, hvp): ``build(batch)`` sorts each shard's nonzeros by
@@ -254,8 +273,7 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     ``g = f̃ ⊙ (Xᵀd) − f̃ s̃ Σd`` (f̃/s̃ have the intercept slot pinned to
     1/0) — both linear, so they commute with the per-shard psum."""
     norm = objective.normalization
-    if with_cols is None:
-        with_cols = segment
+    apply_t, check_vma = _csc_apply("csc_pallas" if use_pallas else "csc")
 
     def _eff(w):
         return _eff_coeffs(norm, w)
@@ -263,26 +281,6 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     def _chain_t(gx, d_sum):
         return _norm_chain_t(norm, gx, d_sum)
 
-    if use_pallas:
-        from photon_ml_tpu.ops.pallas_kernels import csc_transpose_apply_pallas
-
-        if precise:
-            raise ValueError("precise (f64 prefix) accumulation is not "
-                             "available in the Pallas kernel; use "
-                             "sparse_grad='csc_precise'")
-        apply_t = csc_transpose_apply_pallas
-    elif segment:
-        from photon_ml_tpu.types import csc_segment_apply
-
-        apply_t = csc_segment_apply
-    elif precise:
-        # full-f64 global prefix: meaningful only under jax_enable_x64
-        # (x64-off runs, i.e. all TPU runs, silently degrade it to the
-        # global-f32 scheme that cancels at scale) — the blocked default
-        # is the accurate choice there (types.csc_transpose_apply)
-        apply_t = functools.partial(csc_transpose_apply, precise=True)
-    else:
-        apply_t = csc_transpose_apply
     def build(batch: LabeledBatch):
         feats = batch.features
         if not isinstance(feats, SparseFeatures):
@@ -294,39 +292,26 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
             out_specs=P(axis),
         )
         def _build(indices, values):
-            # cols only when the segment apply will read them (the rest of
-            # a precomputed view shouldn't carry +4 B/nnz of dead weight;
-            # build_csc passes with_cols=True so one artifact serves every
-            # calibration mode)
-            csc = build_csc_transpose(indices, values, dim,
-                                      with_cols=with_cols)
             # lead with a shard axis so P(axis) concatenation keeps each
-            # shard's arrays intact ([n_shards, ...] leaves overall); the
-            # whole CSCTranspose travels as one pytree so new fields (cols)
-            # flow through every consumer
-            return jax.tree.map(lambda a: a[None], csc)
+            # shard's arrays intact ([n_shards, ...] leaves overall)
+            return jax.tree.map(
+                lambda a: a[None], build_csc_transpose(indices, values, dim))
 
         return _build(feats.indices, feats.values)
 
     def _margin_value_and_d(w, batch):
         w_eff, adjust = _eff(w)
         m = ell_margins(batch.features, w_eff) + batch.offsets + adjust
-        per_ex = lambda m: jnp.sum(apply_weights(
-            batch.weights,
-            objective.loss.loss(mask_margins(batch.weights, m),
-                                batch.labels)))
+        per_ex = _weighted_loss(objective.loss, batch.weights, batch.labels)
         with jax.named_scope("photon.glm/loss"):
             f, d = jax.value_and_grad(per_ex)(m)
         return f, d
 
-    # check_vma is disabled on the pallas variant: the interpret-mode kernel
-    # body can't thread varying-axis types through pallas_call (reductions
-    # here are explicit psums, so nothing relies on vma-driven transposes)
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P()),
-        check_vma=not use_pallas,
+        check_vma=check_vma,
     )
     def shard_fg(w, batch, csc_sh):
         f, d = _margin_value_and_d(w, batch)
@@ -338,7 +323,7 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         shard_map, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
         out_specs=P(),
-        check_vma=not use_pallas,
+        check_vma=check_vma,
     )
     def shard_hvp(w, v, batch, csc_sh):
         w_eff, adjust = _eff(w)
@@ -370,24 +355,31 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     return build, fg, hvp
 
 
-# Measured per-platform sparse-gradient defaults for "auto" (both
-# platforms calibrated — docs/PERF.md): the v5e calibration at the
-# bench shape ran {scatter 17.9s, csc 12.6s, csc_segment 27.2s,
-# csc_pallas 12.5s}/20 iters (builder-measured on a v5e, 2026-07-31, not
-# re-measured since) — the fused Mosaic kernel wins on TPU,
-# while on CPU the XLA scatter-add is ~10x faster than the csc paths.
+# What "auto" resolves to where it was measured (PERF.md sections 5 and 7):
+# on the v5e the CSC view with the Pallas per-tile scan, on a CPU XLA's
+# scatter-add.
 _SPARSE_GRAD_DEFAULT = {"cpu": "scatter", "tpu": "csc_pallas"}
-_CSC_GRADS = ("csc", "csc_pallas", "csc_precise", "csc_segment")
+_CSC_GRADS = ("csc", "csc_pallas")
+_SPARSE_GRADS = ("auto", "scatter") + _CSC_GRADS
 _sparse_grad_warned: set = set()
 
 
+def uses_csc(sparse_grad: str) -> bool:
+    """Whether a RESOLVED ``sparse_grad`` computes ``Xᵀd`` from the CSC view."""
+    return sparse_grad in _CSC_GRADS
+
+
 def resolve_sparse_grad(sparse_grad: str, features=None) -> str:
-    """Resolve ``"auto"`` to the measured per-platform default. Dense
-    features always resolve to "scatter" (the csc paths are sparse-only;
-    dense X^T d is a plain MXU matmul). Unmeasured platforms fall back
-    to "scatter" with a one-line log, mirroring
-    ``game.random_effect.resolve_re_optimizer`` — no silent
+    """The one gate of ``sparse_grad``: rejects a name that is not one of
+    "auto" | "scatter" | "csc" | "csc_pallas", and resolves "auto" to the
+    measured per-platform default. Dense features always resolve "auto" to
+    "scatter" (the csc paths are sparse-only; dense X^T d is a plain MXU
+    matmul). Unmeasured platforms fall back to "scatter" with a one-line
+    log, mirroring ``game.random_effect.resolve_re_optimizer`` — no silent
     cross-platform fallback."""
+    if sparse_grad not in _SPARSE_GRADS:
+        raise ValueError(f"unknown sparse_grad {sparse_grad!r}; one of "
+                         f"{', '.join(map(repr, _SPARSE_GRADS))}")
     if sparse_grad != "auto":
         return sparse_grad
     if features is not None and not isinstance(features, SparseFeatures):
@@ -399,32 +391,30 @@ def resolve_sparse_grad(sparse_grad: str, features=None) -> str:
         import logging
 
         logging.getLogger("photon_ml_tpu").info(
-            "sparse_grad='auto' on platform %r -> %r (unmeasured default; "
-            "run python bench.py on this platform to calibrate)",
-            platform, choice)
+            "sparse_grad='auto' on platform %r -> %r (no benchmark cell has "
+            "run there; pass sparse_grad='csc' or 'csc_pallas' to train "
+            "through the CSC view)", platform, choice)
     return choice
 
 
 def build_csc(objective: GLMObjective, batch: LabeledBatch, mesh: Mesh,
-              axis: str = "data", with_cols: bool = True):
+              axis: str = "data"):
     """Precompute the column-sorted (CSC) view of a sharded batch ONCE for
     reuse across fits (``fit_distributed(..., precomputed_csc=...)``) —
-    regularization grids, hyperparameter calibration, and repeated bench
-    fits all share one dataset, so the O(nnz log nnz) device sort should be
-    paid per dataset, not per fit. The batch is padded/sharded exactly as
+    regularization grids and repeated fits share one dataset, so the
+    O(nnz log nnz) device sort should be paid per dataset, not per fit. The batch is padded/sharded exactly as
     ``fit_distributed`` will pad it, so the views line up."""
     with obs_trace.span("fit.build_csc", cat="train", rows=batch.num_examples,
               dim=batch.dim, chips=mesh.shape[axis]):
         batch = shard_batch(batch, mesh, axis)
         build = cached_jit(
-            objective, ("build_csc", mesh, axis, with_cols),
-            lambda: make_csc_path(objective, mesh, axis,
-                                  with_cols=with_cols)[0])
+            objective, ("build_csc", mesh, axis),
+            lambda: make_csc_path(objective, mesh, axis)[0])
         return build(batch)
 
 
 def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
-                     transpose: str = "scatter", precise: bool = False):
+                     transpose: str = "scatter"):
     """Margin-space primitives for :func:`optimize.lbfgs_margin.lbfgs_margin`.
 
     Returns ``(init_margin, dir_margin, loss_and_dir, make_data_grad)``:
@@ -447,19 +437,7 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     norm = objective.normalization
     loss = objective.loss
 
-    if transpose == "csc_pallas":
-        from photon_ml_tpu.ops.pallas_kernels import csc_transpose_apply_pallas
-
-        apply_t = csc_transpose_apply_pallas
-    elif transpose == "csc_segment":
-        from photon_ml_tpu.types import csc_segment_apply
-
-        apply_t = csc_segment_apply
-    elif precise:
-        apply_t = functools.partial(csc_transpose_apply, precise=True)
-    else:
-        apply_t = csc_transpose_apply
-    check_vma = transpose != "csc_pallas"
+    apply_t, check_vma = _csc_apply(transpose)  # read where a csc is given
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
@@ -484,8 +462,7 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         out_specs=(P(), P()),
     )
     def s_loss_and_dir(m, mp, labels, weights):
-        per_ex = lambda mm: jnp.sum(apply_weights(
-            weights, loss.loss(mask_margins(weights, mm), labels)))
+        per_ex = _weighted_loss(loss, weights, labels)
         with jax.named_scope("photon.glm/loss"):
             f, d1 = jax.value_and_grad(per_ex)(m)
             df = jnp.sum(d1 * mp)
@@ -507,8 +484,7 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         improvements near convergence, so Wolfe tests on totals become
         coin flips and the fit stalls (observed: hard stop at 16/20 on
         TPU). The derivative is evaluated at the trial point as usual."""
-        per_ex = lambda mm: jnp.sum(apply_weights(
-            weights, loss.loss(mask_margins(weights, mm), labels)))
+        per_ex = _weighted_loss(loss, weights, labels)
         with jax.named_scope("photon.glm/loss"):
             mm0 = mask_margins(weights, m)
             m1 = m + alpha * mp
@@ -530,8 +506,7 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         out_specs=P(),
     )
     def s_grad_scatter(m, feats, labels, weights):
-        per_ex = lambda mm: jnp.sum(apply_weights(
-            weights, loss.loss(mask_margins(weights, mm), labels)))
+        per_ex = _weighted_loss(loss, weights, labels)
         with jax.named_scope("photon.glm/loss"):
             d1 = jax.grad(per_ex)(m)
         g = _norm_chain_t(norm, transpose_apply(feats, d1), jnp.sum(d1))
@@ -544,8 +519,7 @@ def make_margin_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         check_vma=check_vma,
     )
     def s_grad_csc(m, labels, weights, csc_sh):
-        per_ex = lambda mm: jnp.sum(apply_weights(
-            weights, loss.loss(mask_margins(weights, mm), labels)))
+        per_ex = _weighted_loss(loss, weights, labels)
         with jax.named_scope("photon.glm/loss"):
             d1 = jax.grad(per_ex)(m)
         csc = jax.tree.map(lambda a: a[0], csc_sh)
@@ -567,11 +541,10 @@ def _margin_fit(objective, mesh, axis, config, transpose, precomputed):
     """-> (cache key, maker of the program ``run(w0, b, l2v, csc)``): the
     L-BFGS fit with the margin-space line search, 2 data passes per
     iteration (one gather, one transpose) regardless of line-search
-    effort. ``transpose`` in {"scatter", "csc", "csc_pallas",
-    "csc_precise", "csc_segment"}; the csc variants sort the nonzeros once
-    (inside the jit but OUTSIDE the optimizer loop), or take a
-    precomputed view."""
-    use_csc = transpose in _CSC_GRADS
+    effort. ``transpose`` is a resolved ``sparse_grad``; the csc ones sort
+    the nonzeros once (inside the jit but OUTSIDE the optimizer loop), or
+    take a precomputed view."""
+    use_csc = uses_csc(transpose)
     key = ("fit_lbfgs_margin", mesh, axis, transpose, config, precomputed)
 
     def make():
@@ -579,17 +552,11 @@ def _margin_fit(objective, mesh, axis, config, transpose, precomputed):
 
         (init_margin, dir_margin, loss_and_dir, make_data_grad,
          delta_and_dir) = \
-            make_margin_path(objective, mesh, axis, transpose=transpose,
-                             precise=(transpose == "csc_precise"))
+            make_margin_path(objective, mesh, axis, transpose=transpose)
         reg_mask = objective._reg_mask
         build = None
         if use_csc and not precomputed:
-            build = make_csc_path(
-                objective, mesh, axis,
-                use_pallas=(transpose == "csc_pallas"),
-                precise=(transpose == "csc_precise"),
-                segment=(transpose == "csc_segment"),
-            )[0]
+            build = make_csc_path(objective, mesh, axis)[0]
 
         def run(w0, b, l2v, csc):
             if use_csc and csc is None:
@@ -606,98 +573,50 @@ def _margin_fit(objective, mesh, axis, config, transpose, precomputed):
     return key, make
 
 
-def _full_fit(objective, mesh, axis, optimizer, config):
-    """-> (key, maker) of the black-box fit on the autodiff (scatter)
-    gradient: ``run(w0, b, l2v)``, OWL-QN ``run(w0, b, l2v, l1v)``."""
-    key = (f"fit_{optimizer}", "full", mesh, axis, config)
+def _black_box_fit(objective, mesh, axis, optimizer, config, sparse_grad,
+                   precomputed):
+    """-> (key, maker) of the fit that hands the optimizer a black-box
+    objective: the program ``run(w0, b, l2v, l1v, csc)``. Its one fork is
+    where ``fg`` and ``hvp`` come from: autodiff over the sharded
+    objective (XLA's scatter-add), or :func:`make_csc_path` — then ONE
+    program sorts the shard's nonzeros by column (or takes the view
+    :func:`build_csc` made) and runs the whole optimizer loop against the
+    sorted view, so the sort amortizes over every iteration (and over
+    every fit when precomputed). ``l1v`` is None for every optimizer but
+    OWL-QN, ``csc`` is None unless precomputed: neither is an operand
+    then."""
+    use_csc = uses_csc(sparse_grad)
+    key = (f"fit_{optimizer}", mesh, axis, config, sparse_grad, precomputed)
 
     def make():
-        fg = distributed_value_and_grad(objective, mesh, axis)
-        opt = get_optimizer(optimizer)
-        if optimizer == "owlqn":
+        if use_csc:
+            build, fg, hvp = make_csc_path(
+                objective, mesh, axis,
+                use_pallas=(sparse_grad == "csc_pallas"))
+        else:
+            fg = distributed_value_and_grad(objective, mesh, axis)
+            hvp = distributed_hvp(objective, mesh, axis)
+        diag = distributed_diagonal_hessian(objective, mesh, axis)
+        mask_int = (objective.intercept_index
+                    if (objective.intercept_index >= 0
+                        and not objective.regularize_intercept) else -1)
+
+        def run(w0, b, l2v, l1v, csc):
+            if use_csc and csc is None:
+                csc = build(b)
+            data = (b, csc) if use_csc else (b,)
             # L1 intercept mask (consistent with the L2 mask) is
             # shape-dependent: derive from the traced w0 so the cached
             # runner serves any dimension
-            mask_int = (objective.intercept_index
-                        if (objective.intercept_index >= 0
-                            and not objective.regularize_intercept) else -1)
-
-            def run(w0, b, l2v, l1v):
-                l1_mask = (None if mask_int < 0
-                           else jnp.ones_like(w0).at[mask_int].set(0.0))
-                return opt(lambda w: fg(w, b, l2v), w0, l1v, config,
-                           l1_mask=l1_mask)
-
-        elif optimizer == "tron":
-            hvp = distributed_hvp(objective, mesh, axis)
-            diag = distributed_diagonal_hessian(objective, mesh, axis)
-
-            # Jacobi preconditioner: one extra data pass per OUTER
+            l1_mask = (None if l1v is None or mask_int < 0
+                       else jnp.ones_like(w0).at[mask_int].set(0.0))
+            # Jacobi preconditioner (TRON): one extra data pass per OUTER
             # iteration buys fewer CG passes (each CG step is a full pass)
-            def run(w0, b, l2v):
-                return opt(lambda w: fg(w, b, l2v), w0, config,
-                           hvp=lambda w, v: hvp(w, v, b, l2v),
-                           precond=lambda w: diag(w, b, l2v))
-
-        else:
-
-            def run(w0, b, l2v):
-                return opt(lambda w: fg(w, b, l2v), w0, config)
-
-        return run
-
-    return key, make
-
-
-def _csc_fit(objective, mesh, axis, optimizer, config, sparse_grad,
-             precomputed):
-    """-> (key, maker) of the CSC-path fit: ONE program that sorts the
-    shard nonzeros by column (or takes the view :func:`build_csc` made),
-    then runs the whole optimizer loop against the sorted view — sort cost
-    amortizes over every iteration (and over every fit when precomputed).
-    ``run(w0, b, l2v, csc)``, OWL-QN ``run(w0, b, l2v, l1v, csc)``."""
-    use_pallas = sparse_grad == "csc_pallas"
-    precise = sparse_grad == "csc_precise"
-    segment = sparse_grad == "csc_segment"
-    key = (f"fit_{optimizer}", "csc", mesh, axis, config, use_pallas,
-           precise, segment, precomputed)
-
-    def make():
-        build, fg, hvp = make_csc_path(objective, mesh, axis,
-                                       use_pallas=use_pallas,
-                                       precise=precise, segment=segment)
-        opt = get_optimizer(optimizer)
-        if optimizer == "owlqn":
-            # the mask is shape-dependent: derive it from the traced w0 so
-            # the cached runner serves any dimension
-            mask_int = (objective.intercept_index
-                        if (objective.intercept_index >= 0
-                            and not objective.regularize_intercept) else -1)
-
-            def run(w0, b, l2v, l1v, csc):
-                if csc is None:
-                    csc = build(b)
-                l1_mask = (None if mask_int < 0
-                           else jnp.ones_like(w0).at[mask_int].set(0.0))
-                return opt(lambda w: fg(w, b, csc, l2v), w0, l1v, config,
-                           l1_mask=l1_mask)
-
-        elif optimizer == "tron":
-            diag = distributed_diagonal_hessian(objective, mesh, axis)
-
-            def run(w0, b, l2v, csc):
-                if csc is None:
-                    csc = build(b)
-                return opt(lambda w: fg(w, b, csc, l2v), w0, config,
-                           hvp=lambda w, v: hvp(w, v, b, csc, l2v),
-                           precond=lambda w: diag(w, b, l2v))
-
-        else:
-
-            def run(w0, b, l2v, csc):
-                if csc is None:
-                    csc = build(b)
-                return opt(lambda w: fg(w, b, csc, l2v), w0, config)
+            return run_optimizer(
+                optimizer, lambda w: fg(w, *data, l2v), w0, config,
+                l1=l1v, l1_mask=l1_mask,
+                hvp=lambda w, v: hvp(w, v, *data, l2v),
+                precond=lambda w: diag(w, b, l2v))
 
         return run
 
@@ -721,14 +640,13 @@ def fit_distributed(
     """Shard the batch over the mesh and run a full jitted fit — the
     ``DistributedOptimizationProblem.run`` equivalent (SURVEY.md §3.2).
 
-    ``sparse_grad``: "auto" (default: the measured per-platform choice —
-    ``resolve_sparse_grad``), "scatter" (XLA scatter-add via autodiff transpose),
-    "csc" (scatter-free column-sorted gradients — see ``make_csc_path``;
-    sorts once per fit on device, best for many-iteration sparse fits on
-    TPU), "csc_pallas" (fused Pallas kernel), "csc_precise" (CSC with
-    f64 global prefix — only meaningful under jax_enable_x64), or "csc_segment" (sorted
-    segment-sum: a scatter with indices_are_sorted=True, which XLA can
-    lower without collision ordering).
+    ``sparse_grad``, how ``X^T d`` is computed — anything else raises
+    (``resolve_sparse_grad``): "auto" (default: by platform, the CSC view
+    with the Pallas scan on a TPU, XLA's scatter-add on a CPU), "scatter"
+    (XLA scatter-add via the autodiff transpose), "csc" (scatter-free
+    column-sorted gradients — see ``make_csc_path``; sorts once per fit on
+    device, or takes ``precomputed_csc``), "csc_pallas" (the same view, its
+    multiply and per-tile prefix sums in one Pallas kernel).
 
     ``line_search``: "margin" (default, L-BFGS only) runs the strong-Wolfe
     search on cached margin vectors — O(n) per trial, two O(nnz) passes per
@@ -737,8 +655,8 @@ def fit_distributed(
     parity testing and as the TRON/OWL-QN path).
 
     ``precomputed_csc``: reuse a ``build_csc(batch, mesh)`` result across
-    fits on the same dataset (regularization grids, calibration) so the
-    per-dataset column sort is paid once, not per fit.
+    fits on the same dataset (regularization grids) so the per-dataset
+    column sort is paid once, not per fit.
 
     The call returns once the program is dispatched. It leaves a ``fit``
     span (with ``fit.shard_batch`` and ``fit.dispatch`` under it) and one
@@ -747,21 +665,21 @@ def fit_distributed(
     record is read."""
     t_entry = time.perf_counter()
     sparse_grad = resolve_sparse_grad(sparse_grad, batch.features)
-    use_csc = sparse_grad in _CSC_GRADS
     margin = optimizer == "lbfgs" and line_search == "margin"
-    if precomputed_csc is not None and not use_csc:
+    if precomputed_csc is not None and not uses_csc(sparse_grad):
         raise ValueError(
             f"precomputed_csc given but sparse_grad={sparse_grad!r} does "
-            "not use it; pass sparse_grad='csc' (or a csc variant)")
+            "not use it; pass sparse_grad='csc' (or 'csc_pallas')")
     precomputed = precomputed_csc is not None
     if margin:
         key, make = _margin_fit(objective, mesh, axis, config, sparse_grad,
                                 precomputed)
-    elif use_csc:
-        key, make = _csc_fit(objective, mesh, axis, optimizer, config,
-                             sparse_grad, precomputed)
+        args = (precomputed_csc,)
     else:
-        key, make = _full_fit(objective, mesh, axis, optimizer, config)
+        key, make = _black_box_fit(objective, mesh, axis, optimizer, config,
+                                   sparse_grad, precomputed)
+        # only OWL-QN's program reads l1: no other has the operand
+        args = (l1 if optimizer == "owlqn" else None, precomputed_csc)
     compiled = key not in _runner_cache_for(objective)
     with obs_trace.span("fit", cat="train", optimizer=optimizer,
               sparse_grad=sparse_grad, rows=batch.num_examples,
@@ -769,11 +687,8 @@ def fit_distributed(
         with obs_trace.span("fit.shard_batch", cat="train"):
             batch = shard_batch(batch, mesh, axis)
         run = cached_jit(objective, key, make)
-        args = (w0, batch, l2) + ((l1,) if optimizer == "owlqn" else ())
-        if margin or use_csc:
-            args += (precomputed_csc,)
         with obs_trace.span("fit.dispatch", cat="train"):
-            res = run(*args)
+            res = run(w0, batch, l2, *args)
     obs_metrics.training_metrics().record_fit(
         optimizer=optimizer, sparse_grad=sparse_grad, compiled=compiled,
         dispatch_s=time.perf_counter() - t_entry, result=res)

@@ -15,7 +15,6 @@ the hot ops — margin gather and gradient scatter-add — vectorized.
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Union
 
 import jax
@@ -50,17 +49,17 @@ _GATHER_MIN_SIZE = 1 << 14  # below this, the serial gather costs < ~20 us
 # the largest table seen kept there has 159,744 rows of 128 f32 (PERF.md
 # section 7.7; tests/test_tpu_compile.py holds the placement)
 _GATHER_TABLE_BYTES = 80 << 20
-_gather_mode = os.environ.get("PHOTON_GATHER", "auto")
+_gather_mode = "auto"
 
 
 def set_gather_mode(mode: str) -> None:
     """'auto' (vector on TPU, scalar elsewhere), 'scalar', or 'vector'.
 
-    The mode is read at TRACE time, so a change must invalidate every
-    cached executable that baked the old mode in — otherwise an A/B
-    (bench calibration, parity tests) would silently re-time the cached
-    path and measure nothing. Flipping the mode is a rare, human-driven
-    event; the recompile cost is accepted."""
+    The seam the tests use to run the vector form on a CPU, where 'auto'
+    never picks it; no driver and no benchmark cell sets it. The mode is
+    read at TRACE time, so a change must invalidate every cached executable
+    that baked the old mode in — otherwise a parity test would compare the
+    cached path with itself."""
     global _gather_mode
     if mode not in ("auto", "scalar", "vector"):
         raise ValueError(f"unknown gather mode {mode!r}")
@@ -231,21 +230,15 @@ class CSCTranspose:
     values: Optional[jax.Array]  # None under the implicit-ones layout
     rows: jax.Array
     col_starts: jax.Array
-    # sorted column id per nonzero (== the sort key). Optional: only the
-    # segment-sum apply needs it; cumsum-difference works from col_starts.
-    cols: Optional[jax.Array] = None
 
 
 @jax.named_scope("photon.csc/build")
 def build_csc_transpose(indices: jax.Array, values: Optional[jax.Array],
-                        dim: int, with_cols: bool = True) -> CSCTranspose:
+                        dim: int) -> CSCTranspose:
     """Sort the padded ELL nonzeros by column (pure jax; jit/shard_map safe).
     Padding slots (value 0) are kept — they land in their index's run and
     contribute 0 to every product. ``values=None`` (implicit ones) keeps
-    the sorted view value-free too. ``with_cols=False`` drops the sorted
-    column-id array (+4 B/nnz) when the segment-sum apply won't be used —
-    in-fit builds are dead-code-eliminated by XLA either way, but a
-    precomputed view materializes every stored leaf."""
+    the sorted view value-free too."""
     n, k = indices.shape
     flat_idx = indices.reshape(-1)
     order = jnp.argsort(flat_idx)
@@ -256,12 +249,10 @@ def build_csc_transpose(indices: jax.Array, values: Optional[jax.Array],
         col_starts=jnp.searchsorted(
             sorted_cols, jnp.arange(dim + 1, dtype=jnp.int32), side="left"
         ).astype(jnp.int32),
-        cols=sorted_cols.astype(jnp.int32) if with_cols else None,
     )
 
 
 def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
-                        precise: bool = False,
                         block: int = 1 << 16) -> jax.Array:
     """``X^T d`` from the column-sorted view, with no scatter.
 
@@ -273,7 +264,7 @@ def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
     all-positive ``d2`` contributions of the HVP path, where the prefix
     grows linearly.
 
-    The default is therefore a BLOCKED two-level scheme whose error does
+    This is therefore a BLOCKED two-level scheme whose error does
     not grow with nnz: contributions reshape to [B, block]; each block
     gets a local f32 cumsum (magnitudes bounded by one block); a column
     contained in one block differences only local prefixes; a column
@@ -285,21 +276,9 @@ def csc_transpose_apply(csc: CSCTranspose, d: jax.Array,
     same one pass of cumsum traffic, plus one gather over the column
     boundaries (``dim`` of them: the first is always 0; in
     ``table_gather``'s form, so 128-lane rows on a TPU) and B-long ones
-    for the <= B spanning columns.
-
-    ``precise=True`` keeps the old full-f64 global prefix (meaningful
-    only under jax_enable_x64; without it, f64 silently degrades to f32,
-    which is exactly what the blocked default repairs)."""
+    for the <= B spanning columns."""
     dg = table_gather(d, csc.rows)
     contrib = dg if csc.values is None else csc.values * dg
-    if precise:
-        prefix = jnp.concatenate([
-            jnp.zeros((1,), jnp.float64),
-            jnp.cumsum(contrib.astype(jnp.float64)),
-        ])
-        out = prefix[csc.col_starts[1:]] - prefix[csc.col_starts[:-1]]
-        return out.astype(d.dtype)
-
     nnz = contrib.shape[0]
     if nnz == 0:
         return jnp.zeros((csc.col_starts.shape[0] - 1,), d.dtype)
@@ -333,8 +312,7 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
     ``dim >= 2^14`` on, ``local_flat`` is read as 128-lane rows with a
     one-hot lane select (2.8 ns an index for each 80 MiB of prefixes,
     where the scalar gather took 7.5-18.5: PERF.md section 5), under
-    ``lp/rows`` and ``lp/select``; elsewhere, or under
-    ``PHOTON_GATHER=scalar``, as ``local_flat[...]``.
+    ``lp/rows`` and ``lp/select``; elsewhere as ``local_flat[...]``.
     Column 0 starts at nonzero 0 (``col_starts[0] == 0``: nothing sorts
     below column 0), so ``lp[0]`` is the constant 0 and the gather runs
     over the ``dim`` other boundaries, a whole number of chunks at a
@@ -371,22 +349,6 @@ def blocked_boundary_combine(local_flat: jax.Array, bt: jax.Array,
         # a column over several boundaries is written the same value again
         return out.at[jnp.where(spans, j, dim)].set(
             suffix0 + mid + lp[j + 1], mode="drop")
-
-
-def csc_segment_apply(csc: CSCTranspose, d: jax.Array) -> jax.Array:
-    """``X^T d`` from the column-sorted view as a SORTED segment sum: the
-    scatter carries ``indices_are_sorted=True``, which XLA can lower far
-    better than the unordered ELL scatter (no collision ordering to
-    respect). A third strategy for the per-hardware calibration next to
-    the unordered scatter and the cumsum-difference."""
-    if csc.cols is None:
-        raise ValueError("csc.cols missing: rebuild the CSC view "
-                         "(build_csc_transpose now stores sorted cols)")
-    dg = table_gather(d, csc.rows)
-    contrib = dg if csc.values is None else csc.values * dg
-    dim = csc.col_starts.shape[0] - 1
-    return jax.ops.segment_sum(contrib, csc.cols, num_segments=dim,
-                               indices_are_sorted=True)
 
 
 def margins(features: Features, w: jax.Array) -> jax.Array:

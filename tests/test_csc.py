@@ -23,6 +23,7 @@ from photon_ml_tpu.types import (
     build_csc_transpose,
     csc_transpose_apply,
     make_batch,
+    SparseFeatures,
     sparse_from_scipy,
     transpose_apply,
 )
@@ -45,6 +46,19 @@ def sparse_batch(rng):
     )
 
 
+def _dense_xt_d(indices, values, d, dim):
+    """``X^T d`` in float64 by ``np.add.at`` over the ELL slots: the truth
+    the applies are held to, independent of the code under test."""
+    indices = np.asarray(indices)
+    contrib = np.broadcast_to(np.asarray(d, np.float64)[:, None],
+                              indices.shape)
+    if values is not None:
+        contrib = contrib * np.asarray(values, np.float64)
+    out = np.zeros(dim)
+    np.add.at(out, indices.reshape(-1), contrib.reshape(-1))
+    return out
+
+
 def test_csc_transpose_apply_matches_scatter(sparse_batch, rng):
     feats = sparse_batch.features
     d_vec = jnp.asarray(rng.normal(size=feats.num_rows))
@@ -53,9 +67,10 @@ def test_csc_transpose_apply_matches_scatter(sparse_batch, rng):
     want = transpose_apply(feats, d_vec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-12, atol=1e-12)
-    got_precise = csc_transpose_apply(csc, d_vec, precise=True)
-    np.testing.assert_allclose(np.asarray(got_precise), np.asarray(want),
-                               rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(
+        np.asarray(got),
+        _dense_xt_d(feats.indices, feats.values, d_vec, feats.dim),
+        rtol=1e-12, atol=1e-12)
 
 
 def test_csc_fg_and_hvp_match_autodiff(sparse_batch, rng):
@@ -188,60 +203,33 @@ def test_game_fixed_coordinate_csc_matches_scatter():
     np.testing.assert_allclose(s_csc, s_scatter, rtol=1e-6, atol=1e-8)
 
 
-def test_csc_precise_fit_matches_scatter(sparse_batch):
-    """sparse_grad='csc_precise' (f64 prefix accumulation) is plumbed end to
-    end through fit_distributed and matches the scatter fit."""
-    obj = make_objective("logistic")
-    mesh = make_mesh()
-    w0 = jnp.zeros(sparse_batch.features.dim, jnp.float64)
-    kw = dict(l2=0.5, config=OptimizerConfig(max_iters=40, tolerance=1e-12))
-    res_sc = fit_distributed(obj, sparse_batch, mesh, w0, **kw)
-    res_pr = fit_distributed(obj, sparse_batch, mesh, w0,
-                             sparse_grad="csc_precise", **kw)
-    np.testing.assert_allclose(float(res_pr.value), float(res_sc.value),
-                               rtol=1e-10)
-    np.testing.assert_allclose(np.asarray(res_pr.w), np.asarray(res_sc.w),
-                               rtol=1e-5, atol=1e-8)
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit_ones"])
+def test_build_csc_view_has_three_leaves(rng, implicit):
+    """The view ``build_csc`` leaves in HBM is ``values`` / ``rows`` /
+    ``col_starts`` and nothing else: 4 B a nonzero and 4 B a column
+    boundary a shard under the implicit-ones layout, the values' bytes on
+    top under the explicit one."""
+    import dataclasses
 
+    from photon_ml_tpu.parallel.data_parallel import build_csc
+    from photon_ml_tpu.types import CSCTranspose
 
-def test_csc_pallas_rejects_precise():
-    obj = make_objective("logistic")
-    with pytest.raises(ValueError, match="precise"):
-        make_csc_path(obj, make_mesh(), use_pallas=True, precise=True)
-
-
-def test_csc_segment_apply_and_fit(rng):
-    """Sorted segment-sum apply == cumsum-difference apply == dense X^T d,
-    and the csc_segment fit matches scatter (the third hardware strategy:
-    scatter with indices_are_sorted=True)."""
-    from photon_ml_tpu.optimize import OptimizerConfig
-    from photon_ml_tpu.parallel import fit_distributed, make_mesh
-    from photon_ml_tpu.types import (
-        csc_segment_apply, csc_transpose_apply, make_batch, sparse_from_scipy,
-    )
-    import scipy.sparse as sp_mod
-
-    n, d = 120, 25
-    X = rng.normal(size=(n, d)) * (rng.random((n, d)) < 0.4)
-    feats = sparse_from_scipy(sp_mod.csr_matrix(X), dtype=jnp.float64)
-    csc = build_csc_transpose(feats.indices, feats.values, feats.dim)
-    dvec = jnp.asarray(rng.normal(size=n))
-    seg = csc_segment_apply(csc, dvec)
-    cum = csc_transpose_apply(csc, dvec)
-    np.testing.assert_allclose(seg, cum, rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(seg, X.T @ np.asarray(dvec), rtol=1e-9,
-                               atol=1e-9)
-
-    y = (rng.random(n) < 0.5).astype(float)
-    batch = make_batch(feats, y, dtype=jnp.float64)
-    mesh = make_mesh()
-    cfg = OptimizerConfig(max_iters=50, tolerance=1e-10)
-    obj = make_objective("logistic")
-    r_seg = fit_distributed(obj, batch, mesh, jnp.zeros(d), l2=0.5,
-                            config=cfg, sparse_grad="csc_segment")
-    r_sca = fit_distributed(obj, batch, mesh, jnp.zeros(d), l2=0.5,
-                            config=cfg, sparse_grad="scatter")
-    np.testing.assert_allclose(r_seg.w, r_sca.w, rtol=1e-6, atol=1e-9)
+    n, k, dim, shards = 256, 6, 40, 8
+    indices = jnp.asarray(rng.integers(0, dim, (n, k)), jnp.int32)
+    values = None if implicit else jnp.asarray(rng.normal(size=(n, k)),
+                                               jnp.float32)
+    batch = make_batch(SparseFeatures(indices, values, dim=dim),
+                       np.zeros(n), dtype=jnp.float32)
+    csc = build_csc(make_objective("logistic"), batch, make_mesh())
+    assert [f.name for f in dataclasses.fields(CSCTranspose)] == [
+        "values", "rows", "col_starts"]
+    nnz = n * k // shards
+    assert csc.rows.shape == (shards, nnz) and csc.rows.dtype == jnp.int32
+    assert csc.col_starts.shape == (shards, dim + 1)
+    assert (csc.values is None) == implicit
+    per_shard = sum(a.nbytes for a in jax.tree.leaves(csc)) // shards
+    assert per_shard == 4 * nnz + 4 * (dim + 1) + (0 if implicit else 4 * nnz)
 
 
 def test_blocked_prefix_accuracy_at_scale(rng):
@@ -262,9 +250,7 @@ def test_blocked_prefix_accuracy_at_scale(rng):
     d32 = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
 
     got = csc_transpose_apply(csc, d32)  # blocked f32 path
-    # f64 ground truth via the precise path (x64 is enabled in conftest)
-    ref = np.asarray(csc_transpose_apply(csc, jnp.asarray(d32, jnp.float64),
-                                         precise=True))
+    ref = _dense_xt_d(indices, None, d32, dim)  # f64 ground truth
     rel = np.abs(np.asarray(got, np.float64) - ref) / np.maximum(ref, 1e-30)
     assert float(rel.max()) < 1e-4, float(rel.max())
 
@@ -279,11 +265,8 @@ def test_blocked_prefix_accuracy_at_scale(rng):
     # sign-mixed small case stays exact vs dense in f64
     d64 = jnp.asarray(rng.normal(size=n), jnp.float64)
     got64 = csc_transpose_apply(csc, d64)
-    dense = np.zeros(dim)
-    np.add.at(dense, np.asarray(indices).reshape(-1),
-              np.broadcast_to(np.asarray(d64)[:, None],
-                              indices.shape).reshape(-1))
-    np.testing.assert_allclose(got64, dense, rtol=1e-9, atol=1e-9)
+    np.testing.assert_allclose(got64, _dense_xt_d(indices, None, d64, dim),
+                               rtol=1e-9, atol=1e-9)
 
 
 def test_pallas_blocked_accuracy_all_positive(rng):
@@ -291,15 +274,13 @@ def test_pallas_blocked_accuracy_all_positive(rng):
     reference on all-positive contributions at a scale where a global f32
     scan would already be degraded (several hundred tiles of growth)."""
     from photon_ml_tpu.ops.pallas_kernels import csc_transpose_apply_pallas
-    from photon_ml_tpu.types import csc_transpose_apply
 
     n, k, dim = 1 << 14, 32, 1 << 10
     indices = jnp.asarray(rng.integers(0, dim, (n, k)), jnp.int32)
     csc32 = build_csc_transpose(indices, None, dim)
     d32 = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
     got = np.asarray(csc_transpose_apply_pallas(csc32, d32), np.float64)
-    ref = np.asarray(csc_transpose_apply(csc32, jnp.asarray(d32, jnp.float64),
-                                         precise=True))
+    ref = _dense_xt_d(indices, None, d32, dim)
     rel = np.abs(got - ref) / np.maximum(ref, 1e-30)
     assert float(rel.max()) < 1e-4, float(rel.max())
 

@@ -207,10 +207,12 @@ def _lower_fit(fit, n, d):
     if line_search == "margin":
         key, make = dp._margin_fit(obj, mesh, "data", cfg, sparse_grad, False)
     else:
-        key, make = dp._csc_fit(obj, mesh, "data", optimizer, cfg,
-                                sparse_grad, False)
+        key, make = dp._black_box_fit(obj, mesh, "data", optimizer, cfg,
+                                      sparse_grad, False)
     args = (jnp.zeros(d, jnp.float32), shard_batch(batch, mesh), 1.0)
-    args += (0.1, None) if optimizer == "owlqn" else (None,)
+    if line_search != "margin":
+        args += (0.1 if optimizer == "owlqn" else None,)
+    args += (None,)
     return dp.cached_jit(obj, key, make).lower(*args), batch
 
 
@@ -324,8 +326,8 @@ def test_lowered_fit_reads_the_column_boundaries_as_rows(vector_gather):
 
 
 def test_scalar_mode_leaves_no_row_gather_in_the_fit():
-    """``PHOTON_GATHER=scalar`` is ``set_gather_mode("scalar")`` at import:
-    the same fit then reads words everywhere, `lp` among them."""
+    """Under ``set_gather_mode("scalar")`` the same fit reads words
+    everywhere, `lp` among them."""
     before = types.gather_mode()
     types.set_gather_mode("scalar")
     try:

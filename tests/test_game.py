@@ -441,6 +441,25 @@ def test_newton_rejected_for_fixed_coordinates():
                          optimizer="newton")
 
 
+def test_fixed_effect_rejects_unknown_sparse_grad(rng):
+    """A fixed-effect coordinate asks ``resolve_sparse_grad``: a name legal
+    nowhere ("csc_segment" was, one layer down) raises where it used to
+    train through the scatter transpose without a word."""
+    from photon_ml_tpu.game.data import HostSparse
+
+    n, d, k = 64, 20, 3
+    idx = rng.integers(0, d, (n, k)).astype(np.int32)
+    ds = make_game_dataset({"global": HostSparse(idx, None, d)},
+                           (rng.random(n) < 0.5).astype(float))
+    cd = CoordinateDescent(
+        [CoordinateConfig(name="fe", feature_shard="global", reg_type="l2",
+                          reg_weight=1.0, max_iters=3,
+                          sparse_grad="csc_segment")],
+        task="logistic", n_iterations=1)
+    with pytest.raises(ValueError, match="csc_segment"):
+        cd.run(ds)
+
+
 def test_re_optimizer_auto_resolves_per_platform(rng):
     """optimizer="auto" picks the measured per-platform default (CPU:
     vmapped L-BFGS) and produces the same fit as naming it explicitly

@@ -182,3 +182,27 @@ def test_tron_jacobi_preconditioner(rng):
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(prec.w), np.asarray(plain.w),
                                rtol=1e-2, atol=1e-4)
+
+
+@pytest.mark.parametrize("name", ["lbfgs", "owlqn", "tron"])
+def test_run_optimizer_is_the_direct_call(rng, name):
+    """``run_optimizer`` hands each optimizer the extras it takes and no
+    others: the result is the direct call's, bit for bit."""
+    from photon_ml_tpu.optimize import run_optimizer
+
+    fg, obj, batch, X, *_ = _logreg_problem(rng)
+    w0 = jnp.zeros(X.shape[1], jnp.float64)
+    cfg = OptimizerConfig(max_iters=30, tolerance=1e-9)
+    mask = jnp.ones_like(w0).at[0].set(0.0)
+    hvp = lambda w, v: obj.hvp(w, v, batch, 1.0)
+    diag = lambda w: obj.diagonal_hessian(w, batch, 1.0)
+    want = {
+        "lbfgs": lambda: lbfgs(fg, w0, cfg),
+        "owlqn": lambda: owlqn(fg, w0, 0.7, cfg, l1_mask=mask),
+        "tron": lambda: tron(fg, w0, cfg, hvp=hvp, precond=diag),
+    }[name]()
+    got = run_optimizer(name, fg, w0, cfg, l1=0.7, l1_mask=mask, hvp=hvp,
+                        precond=diag)
+    assert int(got.iterations) == int(want.iterations) > 1
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -215,7 +215,23 @@ def test_fit_distributed_implicit_ones(rng, mesh):
                                    err_msg=mode)
 
 
-@pytest.mark.parametrize("mode", ["csc", "csc_segment", "csc_pallas"])
+@pytest.mark.parametrize("name", ["csc_segment", "csc_precise", "csc_typo"])
+def test_unknown_sparse_grad_is_rejected(rng, mesh, name):
+    """``sparse_grad`` has one gate: a name that is not one of its four
+    values raises — the two modes that lost the calibration, and a typo,
+    which used to train through the scatter transpose without a word."""
+    from photon_ml_tpu.parallel.data_parallel import resolve_sparse_grad
+
+    batch, X, y = _problem(rng, sparse=True)
+    with pytest.raises(ValueError, match="csc_pallas"):
+        resolve_sparse_grad(name)
+    with pytest.raises(ValueError, match=name):
+        fit_distributed(make_objective("logistic"), batch, mesh,
+                        jnp.zeros(X.shape[1]), l2=0.5, sparse_grad=name,
+                        config=OptimizerConfig(max_iters=2))
+
+
+@pytest.mark.parametrize("mode", ["csc", "csc_pallas"])
 def test_csc_modes_single_vs_eight_device_equivalence(rng, mesh, mode):
     """Every dryrun sparse-gradient variant asserted allclose between a
     1-device and the 8-device mesh — not merely finite (VERDICT r4 #6).

@@ -47,7 +47,6 @@ def _fit_exporter(mesh_axes={"data": 8}, **kw):
 @pytest.mark.parametrize("kw", [
     dict(optimizer="lbfgs"),                             # margin + scatter
     dict(optimizer="lbfgs", sparse_grad="csc"),
-    dict(optimizer="lbfgs", sparse_grad="csc_segment"),
     dict(optimizer="tron", line_search="full"),
     dict(optimizer="owlqn", line_search="full"),
 ], ids=lambda kw: "-".join(str(v) for v in kw.values()))

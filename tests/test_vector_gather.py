@@ -116,8 +116,7 @@ def test_margins_parity_vector_vs_scalar(implicit):
 
 @pytest.mark.parametrize("implicit", [False, True])
 @pytest.mark.parametrize("apply_name",
-                         ["csc_transpose_apply", "csc_segment_apply",
-                          "pallas"])
+                         ["csc_transpose_apply", "pallas"])
 def test_csc_applies_parity_vector_vs_scalar(implicit, apply_name):
     rng = np.random.default_rng(4)
     feats = _sparse_batch(rng, n=8192, k=4, implicit=implicit)
