@@ -186,13 +186,20 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     after = meter.snapshot()
     device["memory_peak_bytes"] = memory_peak(jax, cell.chips)
 
-    summary = None
+    summary, trace_cost = None, {}
     if traced:
         try:
             if trace_path is not None and not rehearse:
                 from benchmark import trace_reduce
 
-                summary = trace_reduce.reduce_file(trace_path)
+                t0 = time.perf_counter()
+                events = trace_reduce.load_events(trace_path)
+                t1 = time.perf_counter()
+                summary = trace_reduce.reduce_events(events)
+                # the harness's own cost, for the next writer: no metric
+                trace_cost = {"trace_load_s": t1 - t0,
+                              "trace_reduce_s": time.perf_counter() - t1,
+                              **trace_reduce.sizes(events)}
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         if summary is not None:
@@ -236,6 +243,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         "setup_cache_hits": setup_compile[2], "cache_dir": cache_dir,
         "check_s": check_s,
         "setup_phases": {"chip_acquire_s": chip_acquire_s, **runner.phases},
+        **trace_cost,
     }
     result["compared"] = compared
     for name, (value, limit) in compared.items():

@@ -25,6 +25,7 @@ the window are the events of the program that took most time there.
 from __future__ import annotations
 
 import re
+from bisect import bisect_right
 from collections import defaultdict
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -81,9 +82,10 @@ def total(merged) -> float:
     return sum(e - s for s, e in merged)
 
 
-def subtract(a, b):
-    """Parts of merged intervals ``a`` not covered by merged ``b``."""
-    out, j = [], 0
+def subtract(a, b, j=0):
+    """Parts of merged intervals ``a`` not covered by merged ``b``; the
+    first ``j`` of ``b`` are known to end at or before ``a`` starts."""
+    out = []
     for s, e in a:
         cur = s
         while j < len(b) and b[j][1] <= cur:
@@ -146,9 +148,23 @@ def _module_at(modules, t):
     return None, "no program"
 
 
+def sizes(events: dict) -> dict:
+    """What a reduction has to read, on the first chip: the harness prints
+    it beside the seconds the reduction took."""
+    chips = [d for d, v in events.items() if d != "host" and v["ops"]]
+    v = events[min(chips)] if chips else {"ops": [], "modules": []}
+    return {"trace_device_ops": len(leaf_ops([o for o in v["ops"]
+                                              if o[2] > o[1]])),
+            "trace_host_spans": len(events.get("host", [])),
+            "trace_programs": len(v["modules"])}
+
+
 def reduce_events(events: dict) -> dict | None:
     """The summary the metric readers use; ``None`` where no op ran on a
-    device. Seconds throughout."""
+    device. Seconds throughout. Every step is one pass over the window's
+    ops, program runs or host spans, or a sort of them: a step that scans
+    them once for each gap or each piece takes minutes at the 10^5 ops of a
+    GLMix window, and grows with the square of any gain (PERF.md, PR 32)."""
     host = events.get("host", [])
     devices = {d: v for d, v in events.items() if d != "host" and v["ops"]}
     if not devices:
@@ -181,8 +197,10 @@ def reduce_events(events: dict) -> dict | None:
         if by_module:
             main = max(by_module, key=by_module.get)
             pieces = sorted([s, e] for n, s, e in v["modules"] if n == main)
+            ends = [e for _, e in merged]
             for (s0, e0), (s1, e1) in zip(pieces, pieces[1:]):
-                idle = total(subtract([[e0, s1]], merged))
+                idle = total(subtract([[e0, s1]], merged,
+                                      bisect_right(ends, e0)))
                 piece_gaps.append(idle / 1e9)
         if coll and pieces:
             has_collective = True
@@ -191,7 +209,14 @@ def reduce_events(events: dict) -> dict | None:
                 [[start, end]], pieces))) / 1e9)
             shown.append(total(pieces) / 1e9)
         if dev == min(devices):  # the longest idle gaps, on the first chip
-            for (_, e0), (s1, _) in zip(merged, merged[1:]):
+            # ranked before they are labelled: a label scans every program
+            # run and every host span, and ten are printed. Ranked by the
+            # seconds that are printed, so that gaps which round to the same
+            # seconds tie, and ties keep their order in time (a stable sort)
+            between = sorted(((e0, s1) for (_, e0), (s1, _)
+                              in zip(merged, merged[1:])),
+                             key=lambda g: -((g[1] - g[0]) / 1e9))
+            for e0, s1 in between[:10]:
                 (i0, before), (i1, after) = (
                     _module_at(v["modules"], e0 - 1),
                     _module_at(v["modules"], s1 + 1))
@@ -203,14 +228,13 @@ def reduce_events(events: dict) -> dict | None:
     if not busy:
         return None
     n = len(busy)
-    gaps.sort(key=lambda g: -g[1])
     return {
         "busy_s": sum(busy) / n,
         "window_s": sum(windows) / n,
         "devices": n,
         "top_ops": [[k, v] for k, v in sorted(op_time.items(),
                                               key=lambda kv: -kv[1])[:10]],
-        "top_gaps": gaps[:10],
+        "top_gaps": gaps,
         "piece_gaps_s": piece_gaps,
         # over the runs that came with their text, mean over the chips
         "collective_exposed_s": (sum(exposed) / len(exposed)
@@ -218,7 +242,3 @@ def reduce_events(events: dict) -> dict | None:
         "collective_window_s": (sum(shown) / len(shown)
                                 if has_collective else None),
     }
-
-
-def reduce_file(path: str) -> dict | None:
-    return reduce_events(load_events(path))
