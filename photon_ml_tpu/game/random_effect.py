@@ -39,6 +39,67 @@ from photon_ml_tpu.types import LabeledBatch, SparseFeatures
 _EXACT = jax.lax.Precision.HIGHEST
 
 
+# The TPU's vector width. An entity axis on the lanes is padded to it in
+# device memory whatever its length.
+_LANES = 128
+
+
+def _spd_solve(H, rhs):
+    """``H^-1 rhs`` for a batch of small symmetric positive definite
+    matrices: ``H [E, D, D]``, ``rhs [E, D]`` or ``[E, D, R]``.
+
+    Elimination without pivoting (SPD needs none) with the entities on the
+    minor axis, ``[D, D + R, E]``: every step of the two ``D``-step loops
+    is element-wise over 128 entities a vector instruction, where a batched
+    LU works on one ``D``-wide matrix at a time and pads each to the tile.
+    The forward loop carries the right-hand sides as ``R`` more columns;
+    the back substitution carries them alone. Ahead of the LU at both ends
+    of what ``"auto"`` hands it (v5e, PERF.md section 6, PR 33: 8,858 x 21
+    in 1.7 ms against 52, 4,096 x 128 in 121 against 253), so one path.
+
+    Nothing is summed across entities or along ``D``, and the entity axis
+    is filled up to whole vectors (with copies of the last entity), so
+    every entity goes through the same instructions and its result does
+    not depend on how many share the call. Unfilled, XLA's CPU code for 3
+    or 7 entities contracts other multiply-adds than for 300.
+
+    A pivot that is not positive (``H`` singular or indefinite to working
+    precision) makes that entity's result NaN and no other's: the caller
+    masks a non-finite step.
+    """
+    E, D = H.shape[:2]
+    vector = rhs.ndim == 2
+    aug = jnp.concatenate([H, rhs[:, :, None] if vector else rhs], axis=2)
+    aug = jnp.pad(aug, ((0, -E % _LANES), (0, 0), (0, 0)), mode="edge")
+    aug = jnp.transpose(aug, (1, 2, 0))
+    row_id = jnp.arange(D)[:, None, None]
+
+    def column(A, k):
+        """Column ``k`` as ``[D, 1, E]`` and its pivot, NaN unless positive."""
+        c = jax.lax.dynamic_slice_in_dim(A, k, 1, axis=1)
+        p = jax.lax.dynamic_index_in_dim(c, k, axis=0)
+        return c, jnp.where(p > 0, p, jnp.nan)
+
+    def eliminate(k, A):
+        c, p = column(A, k)
+        l = jnp.where(row_id > k, c / p, 0.0)
+        return A - l * jax.lax.dynamic_index_in_dim(A, k, axis=0)
+
+    aug = jax.lax.fori_loop(0, D, eliminate, aug)
+    U = aug[:, :D]  # upper triangle; what is left below it is never read
+
+    def substitute(j, Y):
+        k = D - 1 - j
+        c, p = column(U, k)
+        x_k = jax.lax.dynamic_index_in_dim(Y, k, axis=0) / p
+        return jnp.where(row_id < k, Y - c * x_k,
+                         jnp.where(row_id == k, x_k, Y))
+
+    X = jnp.transpose(jax.lax.fori_loop(0, D, substitute, aug[:, D:]),
+                      (2, 0, 1))[:E]
+    return X[:, :, 0] if vector else X
+
+
 def _newton_dense_solver(local_dim: int, task: str,
                          config: OptimizerConfig,
                          compute_variance: bool | str, norm_mode: int = 0):
@@ -49,7 +110,7 @@ def _newton_dense_solver(local_dim: int, task: str,
     ``vmap`` of sparse L-BFGS loops: rows densify once to ``X [E, D, N]``
     (a k-step scan, no scatter), every Newton iteration is two einsums
     (gradient ``X^T d1``, Hessian ``X^T diag(d2) X`` — MXU contractions)
-    plus one batched SPD solve, and a 4-level per-entity step-halving
+    plus one batched SPD solve (``_spd_solve``), and a 4-level step-halving
     safeguard keeps descent monotone. The returned ``solve`` carries its
     two halves, ``solve.densify`` and ``solve.solve_dense``, so a caller
     that keeps ``X`` between sweeps runs the second alone. A vmapped
@@ -128,7 +189,7 @@ def _newton_dense_solver(local_dim: int, task: str,
 
         @jax.named_scope("photon.re/newton/solve")
         def newton_step(H, g):
-            return jnp.linalg.solve(H, g[..., None])[..., 0]  # SPD batched
+            return _spd_solve(H, g)
 
         @jax.named_scope("photon.re/newton/halving")
         def halving(W, f, step):
@@ -210,7 +271,7 @@ def _newton_dense_solver(local_dim: int, task: str,
         if compute_variance:
             if compute_variance == "full":
                 _, H_fin = grad_hess(W)
-                Hinv = jnp.linalg.solve(
+                Hinv = _spd_solve(
                     H_fin, jnp.broadcast_to(jnp.eye(D, dtype=dt),
                                             (E, D, D)))
                 var = jnp.diagonal(Hinv, axis1=1, axis2=2)
@@ -422,11 +483,11 @@ def _re_to_model_space(W_opt, f_loc, s_loc, pos):
 # (VERDICT r3 #7). Measured by scripts/bench_game.py: on CPU the vmapped
 # sparse L-BFGS wins (28.4k entities/s vs 16.6k for the batched dense
 # Newton at E=2000, rows/entity=32, d_local=16). On the TPU the batched
-# dense-Newton IRLS wins: per entity it is [E, d, d] einsum Hessians +
-# batched Cholesky solves, systolic-array work, where the vmapped L-BFGS
-# path is gather/VPU-bound. An unmeasured platform logs one line when its
-# default is used, so no silent cross-platform fallback remains
-# (VERDICT r4 missing #3).
+# dense-Newton IRLS wins: per entity it is [E, d, d] einsum Hessians
+# (systolic-array work) + an elimination with the entities on the lanes
+# (``_spd_solve``), where the vmapped L-BFGS path is gather/VPU-bound. An
+# unmeasured platform logs one line when its default is used, so no silent
+# cross-platform fallback remains (VERDICT r4 missing #3).
 _RE_SOLVER_DEFAULT = {"cpu": "lbfgs", "tpu": "newton"}
 # tpu: newton 7919 entities/s vs lbfgs 2315 at E=100k, rows=64,
 # d_local=32 (builder-measured on a v5e, 2026-07-31, not re-measured
@@ -458,9 +519,10 @@ def entity_bytes(N: int, k: int, D: int, itemsize: int, optimizer: str,
     takes inside a solver execution, tiles counted: its sparse rows
     (slot-major, ``[k, N]``) and six row vectors and, for the dense
     Newton, ``X [D, N]`` with one temporary of its size, the four trial
-    points' margins, the ``[D, D]`` Hessian with the solve's workspace;
-    for a vmapped optimizer, a row-major copy of the rows and its
-    history."""
+    points' margins, and four ``[D, D]`` tiles: the Hessian, the form it
+    is joined to the gradient in, and the solve's two buffers with the
+    entities on the lanes (``D^2`` elements each, at most a tile); for a
+    vmapped optimizer, a row-major copy of the rows and its history."""
     rows = _tiled(N, k) * (4 + itemsize) + 6 * _tiled(N, 1) * itemsize // 8
     if optimizer == "newton":
         return rows + itemsize * (2 * _tiled(N, D) + 4 * _tiled(N, 1) // 8
@@ -619,10 +681,10 @@ def _active_width(n_active: int, block: int, n_dev: int) -> int:
 
 # "auto" only picks the dense-Newton solver up to this per-entity dim:
 # its [block, d, d] Hessians are d^2 x 4 B an entity (8 GB at the d=351 CD
-# bucket that crashed the Mosaic batched-Cholesky compile — builder-
-# measured on a v5e, 2026-07-31, not re-measured since);
-# the vmapped L-BFGS memory is O(d) per entity and handles wide
-# subspaces fine.
+# bucket that crashed the compile of the batched LU this path called then
+# — builder-measured on a v5e, 2026-07-31, not re-measured since) and the
+# solve's work grows as d^3; the vmapped L-BFGS memory is O(d) per entity
+# and handles wide subspaces fine.
 _RE_NEWTON_MAX_DIM = 128
 
 

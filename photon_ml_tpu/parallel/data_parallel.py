@@ -689,7 +689,11 @@ def fit_distributed(
         run = cached_jit(objective, key, make)
         with obs_trace.span("fit.dispatch", cat="train"):
             res = run(w0, batch, l2, *args)
-    obs_metrics.training_metrics().record_fit(
-        optimizer=optimizer, sparse_grad=sparse_grad, compiled=compiled,
-        dispatch_s=time.perf_counter() - t_entry, result=res)
+    # a call traced inside a caller's jit (an export, a compile from shapes)
+    # ran no fit: its counters are tracers, which the ring would hold past
+    # their trace and fail on 64 fits later
+    if not isinstance(res.iterations, jax.core.Tracer):
+        obs_metrics.training_metrics().record_fit(
+            optimizer=optimizer, sparse_grad=sparse_grad, compiled=compiled,
+            dispatch_s=time.perf_counter() - t_entry, result=res)
     return res

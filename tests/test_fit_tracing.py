@@ -426,6 +426,25 @@ def test_fit_distributed_leaves_a_record_without_a_device_fetch():
     assert 0 < rec["dispatch_s"] < 60
 
 
+def test_a_fit_traced_inside_a_jit_leaves_no_record():
+    # tests/test_tpu_lowering.py and test_tpu_compile.py trace whole fits
+    # from shapes; their counters are tracers, and a record of them failed
+    # the fit that pushed it out of the ring, FIT_RECORDS fits later in
+    # the same process
+    tm = obs_metrics.training_metrics()
+    case = ("lbfgs", "margin", "scatter", 1)
+    parity_fit(case)
+    before = [r["iterations"] for r in tm.fit_records()]
+    jax.jit(lambda: parity_fit(case).w).lower()
+    assert [r["iterations"] for r in tm.fit_records()] == before
+    for _ in range(tm.FIT_RECORDS + 1):
+        tm.record_fit(optimizer="lbfgs", sparse_grad="scatter",
+                      compiled=False, dispatch_s=0.0,
+                      result=pytypes.SimpleNamespace(
+                          iterations=1, gather_products=None,
+                          transpose_products=None))
+
+
 def test_streamed_results_count_nothing():
     tm = obs_metrics.TrainingMetrics()
     tm.record_fit(optimizer="lbfgs", sparse_grad="scatter", compiled=False,

@@ -143,7 +143,8 @@ def test_glm_fit_compiles_on_four_chips(topo, one_chip_fit):
 
 def test_newton_re_solver_compiles_at_one_block(topo):
     """One real block of the batched dense-Newton solver: the widest
-    program the GAME phase can hand the batched-Cholesky compile."""
+    program the GAME phase can hand the compiler, inside the bytes
+    ``entity_bytes`` budgets for it."""
     from photon_ml_tpu.game.random_effect import (
         _jitted_sharded_solver,
         block_entities,
@@ -172,6 +173,35 @@ def test_newton_re_solver_compiles_at_one_block(topo):
     mem = compiled.memory_analysis()
     print(mem)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("variance", [False, "full"])
+@pytest.mark.parametrize("E,D_loc,rows,k", [(8858, 21, 128, 11),
+                                            (445, 36, 1024, 5)])
+def test_newton_re_solver_holds_no_linalg_custom_call(one_chip, E, D_loc,
+                                                      rows, k, variance):
+    """The Newton step's solve (and the inverse ``compute_variance="full"``
+    reads) is plain element-wise XLA at the GLMix cell's bucket shapes: a
+    batched ``jnp.linalg.solve`` compiles to ``LuDecompositionBlock`` and
+    ``InvertDiagBlocks*Triangular`` custom calls, one small matrix at a
+    time."""
+    from photon_ml_tpu.game.random_effect import _newton_dense_solver
+
+    solver = _newton_dense_solver(
+        D_loc, "logistic", OptimizerConfig(max_iters=4, tolerance=0.0),
+        variance)
+
+    def s(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(solver).lower(
+        s((E, rows, k), i32), s((E, rows, k)), s((E, rows)), s((E, rows)),
+        s((E, rows)), s((E, D_loc)), s((E, 1)), s((E, 1)), s(()),
+        s(())).compile().as_text()
+    targets = set(re.findall(r'custom_call_target="([^"]+)"', text))
+    assert not [t for t in targets
+                if re.search("Lu|Triangular|Cholesky|Qr|Eigh", t)], targets
+    assert "photon.re/newton/solve" in text
 
 
 def test_serving_fused_score_compiles(one_chip):
