@@ -250,7 +250,9 @@ class TrainingMetrics:
         host's time inside each before the device has the program;
       fit_passes_total / fit_products_total{kind=gather|transpose} — the
         optimizer iterations and the ``X v`` / ``X^T d`` products those
-        fits ran, as their programs counted them. A fit's counters stay
+        fits ran, as their programs counted them (an OWL-QN fit's record
+        also carries ``line_search_trials`` and ``nonzeros``, in
+        :meth:`fit_records` alone: no series). A fit's counters stay
         device scalars in its record (:meth:`record_fit`) until a read
         (:meth:`fit_records`, :meth:`snapshot`, :meth:`render`) or until
         the record leaves the ring of ``FIT_RECORDS``;
@@ -442,7 +444,8 @@ class TrainingMetrics:
     def record_fit(self, *, optimizer: str, sparse_grad: str,
                    compiled: bool, dispatch_s: float, result) -> None:
         """One ``fit_distributed`` call, on its return. ``result``'s
-        ``iterations`` / ``gather_products`` / ``transpose_products`` are
+        ``iterations`` / ``gather_products`` / ``transpose_products`` /
+        ``line_search_trials`` / ``nonzeros`` (the last two OWL-QN's) are
         kept as they are — device scalars of a fit that may still be
         running — and fetched only when the record is read, never on the
         fit's path; only a record pushed out of the ring (a fit
@@ -452,6 +455,8 @@ class TrainingMetrics:
                "iterations": result.iterations,
                "gather_products": result.gather_products,
                "transpose_products": result.transpose_products,
+               "line_search_trials": result.line_search_trials,
+               "nonzeros": result.nonzeros,
                "counted": False}
         evicted = None
         with self._fit_lock:
@@ -469,7 +474,8 @@ class TrainingMetrics:
         made outside the lock ``record_fit`` takes."""
         if rec["counted"]:
             return
-        fields = ("iterations", "gather_products", "transpose_products")
+        fields = ("iterations", "gather_products", "transpose_products",
+                  "line_search_trials", "nonzeros")
         fetched = {f: None if rec[f] is None else int(rec[f]) for f in fields}
         with self._fit_lock:
             if rec["counted"]:

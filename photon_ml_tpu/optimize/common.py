@@ -159,6 +159,12 @@ class OptimizationResult(NamedTuple):
     # their passes).
     gather_products: "jax.Array | None" = None
     transpose_products: "jax.Array | None" = None
+    # OWL-QN only, counted on the device like the products (i32 scalars):
+    # the trial points its backtracking searches evaluated over the whole
+    # fit, and the coefficients of the final ``w`` that are not exactly
+    # zero. None from the other optimizers.
+    line_search_trials: "jax.Array | None" = None
+    nonzeros: "jax.Array | None" = None
 
 
 def converged_check(f_prev, f, g_norm, g0_norm, tol, f_scale=None):

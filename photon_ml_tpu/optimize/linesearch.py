@@ -164,7 +164,7 @@ def strong_wolfe(
     return LineSearchResult(alpha, f, g, s.i, (finished | took_step) & ~bad_direction)
 
 
-@jax.named_scope("photon.lbfgs/line_search")
+@jax.named_scope("photon.owlqn/line_search")
 def backtracking(
     fun: Callable,
     w: jax.Array,

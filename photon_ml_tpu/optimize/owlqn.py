@@ -79,36 +79,41 @@ def owlqn(
     pg0_norm = l2_norm(pseudo_gradient(w0, g0, lam))
     loss_hist, gnorm_hist = init_history(config.max_iters, F0.dtype)
 
-    @jax.named_scope("photon.lbfgs/update")
     def body(s: _State) -> _State:
-        pg = pseudo_gradient(s.w, s.g, lam)
+        with jax.named_scope("photon.owlqn/pseudo_gradient"):
+            pg = pseudo_gradient(s.w, s.g, lam)
         p = two_loop_direction(pg, s.s_hist, s.y_hist, s.rho, s.k, m)
-        # align the direction with -pg (orthant-wise projection of direction)
-        p = jnp.where(p * (-pg) > 0, p, 0.0)
-        dg = jnp.sum(p * pg)
-        p = jnp.where(dg < 0, p, -pg)
-        # orthant choice: sign(w), or sign(-pg) where w == 0
-        xi = jnp.where(s.w != 0, jnp.sign(s.w), jnp.sign(-pg))
+        with jax.named_scope("photon.owlqn/direction"):
+            # align the direction with -pg (orthant-wise projection of
+            # the direction)
+            p = jnp.where(p * (-pg) > 0, p, 0.0)
+            dg = jnp.sum(p * pg)
+            p = jnp.where(dg < 0, p, -pg)
+            # orthant choice: sign(w), or sign(-pg) where w == 0
+            xi = jnp.where(s.w != 0, jnp.sign(s.w), jnp.sign(-pg))
+            alpha0 = jnp.where(s.k > 0, 1.0, 1.0 / jnp.maximum(l2_norm(pg), 1.0))
 
         def project(w_trial):
             return jnp.where(w_trial * xi > 0, w_trial, 0.0)
 
-        alpha0 = jnp.where(s.k > 0, 1.0, 1.0 / jnp.maximum(l2_norm(pg), 1.0))
+        # under photon.owlqn/line_search: projection, the trial's value
         w_new, F_new, n_trials, ok = backtracking(
             full_value, s.w, p, s.F, pg, alpha0=alpha0,
             max_evals=config.max_line_search_steps, project=project,
         )
         _, g_new = fun_and_grad(w_new)
-        step = w_new - s.w
-        y = g_new - s.g
-        sy = jnp.sum(step * y)
-        store = ok & (sy > 1e-10 * jnp.maximum(l2_norm(step) * l2_norm(y), jnp.finfo(dtype).tiny))
-        slot = jnp.mod(s.k, m)
-        s_hist = jnp.where(store, s.s_hist.at[slot].set(step), s.s_hist)
-        y_hist = jnp.where(store, s.y_hist.at[slot].set(y), s.y_hist)
-        rho = jnp.where(store, s.rho.at[slot].set(1.0 / jnp.where(sy == 0, 1.0, sy)), s.rho)
-        k_new = jnp.where(store, s.k + 1, s.k)
-        pg_new_norm = l2_norm(pseudo_gradient(w_new, g_new, lam))
+        with jax.named_scope("photon.owlqn/update"):
+            step = w_new - s.w
+            y = g_new - s.g
+            sy = jnp.sum(step * y)
+            store = ok & (sy > 1e-10 * jnp.maximum(l2_norm(step) * l2_norm(y), jnp.finfo(dtype).tiny))
+            slot = jnp.mod(s.k, m)
+            s_hist = jnp.where(store, s.s_hist.at[slot].set(step), s.s_hist)
+            y_hist = jnp.where(store, s.y_hist.at[slot].set(y), s.y_hist)
+            rho = jnp.where(store, s.rho.at[slot].set(1.0 / jnp.where(sy == 0, 1.0, sy)), s.rho)
+            k_new = jnp.where(store, s.k + 1, s.k)
+        with jax.named_scope("photon.owlqn/pseudo_gradient"):
+            pg_new_norm = l2_norm(pseudo_gradient(w_new, g_new, lam))
         conv = converged_check(s.F, F_new, pg_new_norm, pg0_norm, config.tolerance)
         return _State(
             s.it + 1, k_new, w_new, F_new, g_new,
@@ -134,9 +139,13 @@ def owlqn(
         n_transpose=jnp.asarray(1, jnp.int32),
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
-    final_pg = pseudo_gradient(s.w, s.g, lam)
+    with jax.named_scope("photon.owlqn/pseudo_gradient"):
+        final_pg = pseudo_gradient(s.w, s.g, lam)
     return OptimizationResult(
         w=s.w, value=s.F, grad_norm=l2_norm(final_pg), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
         gather_products=s.n_gather, transpose_products=s.n_transpose,
+        # every gather but (f0, g0)'s and an accepted point's a pass is a trial
+        line_search_trials=s.n_gather - 1 - s.it.astype(jnp.int32),
+        nonzeros=jnp.count_nonzero(s.w).astype(jnp.int32),
     )

@@ -180,6 +180,11 @@ _KERNEL_SCOPES = [
     "photon.allreduce/grad", "photon.allreduce/value"]
 _LBFGS_SCOPES = ["photon.lbfgs/two_loop", "photon.lbfgs/line_search",
                  "photon.lbfgs/update"]
+# OWL-QN's own (ISSUE 34); its two-loop is L-BFGS's, its body no longer
+# borrows `photon.lbfgs/update`
+_OWLQN_SCOPES = ["photon.lbfgs/two_loop", "photon.owlqn/pseudo_gradient",
+                 "photon.owlqn/direction", "photon.owlqn/line_search",
+                 "photon.owlqn/update"]
 _TRON_SCOPES = ["photon.tron/cg", "photon.tron/hvp", "photon.tron/precond"]
 # (optimizer, line_search, sparse_grad) -> (program, scopes beside the
 # kernels', call sites of X^T d: distinct name stacks of the `lp` gather)
@@ -191,7 +196,7 @@ LOWERED = {
     ("lbfgs", "full", "csc_pallas"):
         ("photon_fit_lbfgs", _LBFGS_SCOPES, 2),  # g0, a search's trial
     # g0, a backtracking trial (dead code: compiled away), the accepted point
-    ("owlqn", "full", "csc_pallas"): ("photon_fit_owlqn", _LBFGS_SCOPES, 3),
+    ("owlqn", "full", "csc_pallas"): ("photon_fit_owlqn", _OWLQN_SCOPES, 3),
     ("tron", "full", "csc_pallas"):
         ("photon_fit_tron", _TRON_SCOPES, 3),  # g0, an HVP, the trial point
 }
@@ -266,6 +271,12 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
     if sparse_grad == "csc_pallas":
         assert any("photon_multiply_prefix_sum" in s for s in stacks)
     if optimizer == "owlqn":
+        assert not any("photon.lbfgs/update" in s
+                       or "photon.lbfgs/line_search" in s for s in stacks)
+        # the trial's product gather keeps the kernel's scope, under the
+        # search's
+        assert any("photon.owlqn/line_search/" in s
+                   and "photon.table_gather/rows" in s for s in stacks)
         # what OWL-QN's counters say: a trial's X^T d does not run
         compiled = set(re.findall(
             r'op_name="([^"]*boundary_combine/lp/gather)"',
@@ -371,10 +382,14 @@ class _OnDevice:
         return self.value
 
 
-def _result(passes, gathers, transposes, cls=_OnDevice):
+def _result(passes, gathers, transposes, trials=None, nonzeros=None,
+            cls=_OnDevice):
+    """``trials`` and ``nonzeros`` are OWL-QN's: None from the others."""
     return pytypes.SimpleNamespace(
         iterations=cls(passes), gather_products=cls(gathers),
-        transpose_products=cls(transposes))
+        transpose_products=cls(transposes),
+        line_search_trials=None if trials is None else cls(trials),
+        nonzeros=None if nonzeros is None else cls(nonzeros))
 
 
 def test_record_fit_fetches_nothing_until_read_and_keeps_64():
@@ -402,9 +417,20 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
     assert records[-1] == {
         "optimizer": "tron", "sparse_grad": "csc", "compiled": False,
         "dispatch_s": 0.5, "iterations": 1, "gather_products": 6,
-        "transpose_products": 6}
+        "transpose_products": 6, "line_search_trials": None,
+        "nonzeros": None}
     assert records[0]["compiled"] is False  # the first record has gone
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 641}
+    # an OWL-QN fit's record carries its two counters, fetched with the
+    # other three when the record is read and not before
+    fetched = _OnDevice.fetched
+    tm.record_fit(optimizer="owlqn", sparse_grad="csc", compiled=False,
+                  dispatch_s=0.1, result=_result(10, 23, 11, 12, 580063))
+    assert _OnDevice.fetched == fetched
+    last = tm.fit_records()[-1]
+    assert _OnDevice.fetched == fetched + 5
+    assert (last["line_search_trials"], last["nonzeros"]) == (12, 580063)
+    assert (last["iterations"], last["gather_products"]) == (10, 23)
 
 
 def test_fit_distributed_leaves_a_record_without_a_device_fetch():
@@ -442,7 +468,8 @@ def test_a_fit_traced_inside_a_jit_leaves_no_record():
                       compiled=False, dispatch_s=0.0,
                       result=pytypes.SimpleNamespace(
                           iterations=1, gather_products=None,
-                          transpose_products=None))
+                          transpose_products=None,
+                          line_search_trials=None, nonzeros=None))
 
 
 def test_streamed_results_count_nothing():
@@ -450,7 +477,8 @@ def test_streamed_results_count_nothing():
     tm.record_fit(optimizer="lbfgs", sparse_grad="scatter", compiled=False,
                   dispatch_s=0.0, result=pytypes.SimpleNamespace(
                       iterations=3, gather_products=None,
-                      transpose_products=None))
+                      transpose_products=None,
+                      line_search_trials=None, nonzeros=None))
     assert tm.fit_records()[0]["gather_products"] is None
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 3}
 
@@ -623,7 +651,8 @@ def test_readers_on_hand_made_records(monkeypatch):
     tm.record_fit(optimizer="lbfgs", sparse_grad="scatter", compiled=False,
                   dispatch_s=0.001, result=pytypes.SimpleNamespace(
                       iterations=10, gather_products=None,
-                      transpose_products=None))
+                      transpose_products=None,
+                      line_search_trials=None, nonzeros=None))
     assert readers[0].read(run) is None and readers[1].read(run) is None
     assert readers[2].read(run) == pytest.approx(1.3)
 
@@ -640,10 +669,12 @@ def test_readers_find_nothing_in_a_program_without_records(monkeypatch):
 def test_new_metrics_are_declared_for_every_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # every cell of whole fixed-effect fits (the GAME cells keep sweep
-    # records, not fit records, and have readers of their own)
+    # every cell of whole fixed-effect fits that PR 26 found (the GAME
+    # cells keep sweep records, not fit records, and have readers of their
+    # own; so has the elastic-net cell, below: appending it to these lists
+    # would be an edit of what the benchmark has)
     cells = [w["name"] for w in bench["workloads"]
-             if w["traffic"].startswith("fit")]
+             if w["traffic"] in ("fit", "fit-x4")]
     declared = {m["name"]: m for m in bench["per_layer"]}
     for name, source, layer in (
             ("fit_products_per_pass", "program_counter", "optimize"),
@@ -652,6 +683,16 @@ def test_new_metrics_are_declared_for_every_cell():
         m = declared[name]
         assert (m["source"], m["layer"], m["moves"], m["workloads"]) == (
             source, layer, "train_rows_per_s", cells)
+    for name, source, layer in (
+            ("enet_mfu_pct", "host_clock", "whole step"),
+            ("enet_roofline_pct", "host_clock", "whole step"),
+            ("enet_device_idle_pct", "device_trace", "device"),
+            ("enet_pass_ms", "host_clock", "optimize"),
+            ("enet_trials_per_pass", "program_counter", "optimize"),
+            ("enet_gathers_per_pass", "program_counter", "optimize")):
+        m = declared[name]
+        assert (m["source"], m["layer"], m["moves"], m["workloads"]) == (
+            source, layer, "train_rows_per_s", ["criteo-enet.fit"])
 
 
 def test_traced_rehearsal_still_ends():
