@@ -16,6 +16,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,6 +212,51 @@ def init_history(max_iters: int, dtype) -> tuple[jax.Array, jax.Array]:
 
 def l2_norm(a):
     return jnp.sqrt(jnp.sum(a * a))
+
+
+# The (s, y) pair history of L-BFGS / OWL-QN: ``m`` slots of ``[d]``
+# vectors, each read whole by a dynamic index. It is ONE flat array of
+# ``m * stride`` elements, slot ``j`` at ``[j * stride, j * stride + d)``,
+# so a slot is contiguous in memory. As ``[m, d]`` the TPU tiles the two
+# minor dimensions (8, 128): the slot index lands on the sublanes, a slot
+# is one row in eight of every tile, and reading or writing one moves
+# eight (PERF.md section 6, PR 35: 0.85 ms a slot read at d = 2^24 where
+# a contiguous one rides inside its consumer). The three functions below
+# are the only code that knows the layout.
+
+# a 1-D f32 array lies in HBM in tiles of 1024 elements (``T(1024)``): a
+# slot that starts on a tile boundary is sliced without a shifting copy
+_HISTORY_TILE = 1024
+
+
+def _history_stride(d: int) -> int:
+    """Elements from one slot's start to the next: ``d`` filled up to whole
+    tiles where that costs under an eighth of a slot, ``d`` itself below
+    (the random effects' vmapped solves hold a history of ``d`` in the
+    tens per entity: a tile a slot would be most of their memory)."""
+    if d < 8 * _HISTORY_TILE:
+        return d
+    return -(-d // _HISTORY_TILE) * _HISTORY_TILE
+
+
+def history_zeros(m: int, d: int, dtype) -> jax.Array:
+    """An empty history of ``m`` slots of ``[d]`` vectors."""
+    return jnp.zeros((m * _history_stride(d),), dtype)
+
+
+def history_slot(hist: jax.Array, j, d: int) -> jax.Array:
+    """Slot ``j`` of ``hist`` as a ``[d]`` vector."""
+    return lax.dynamic_slice(hist, (j * _history_stride(d),), (d,))
+
+
+def history_store(hist: jax.Array, j, v: jax.Array, store=None) -> jax.Array:
+    """``hist`` with ``v`` in slot ``j`` — where ``store`` (a traced bool;
+    None: always) says so, else as it was. Only the slot is read and
+    written, so inside a loop carry the update is in place."""
+    (d,) = v.shape
+    if store is not None:
+        v = jnp.where(store, v, history_slot(hist, j, d))
+    return lax.dynamic_update_slice(hist, v, (j * _history_stride(d),))
 
 
 def match_vma(x, ref):

@@ -22,6 +22,9 @@ from photon_ml_tpu.optimize.common import (
     OptimizationResult,
     OptimizerConfig,
     converged_check,
+    history_slot,
+    history_store,
+    history_zeros,
     init_history,
     l2_norm,
     match_vma_tree,
@@ -35,8 +38,8 @@ class _State(NamedTuple):
     w: jax.Array
     f: jax.Array
     g: jax.Array
-    s_hist: jax.Array  # [m, d]
-    y_hist: jax.Array  # [m, d]
+    s_hist: jax.Array  # m slots of [d] (optimize.common.history_zeros)
+    y_hist: jax.Array  # the same
     rho: jax.Array  # [m]
     converged: jax.Array
     stalled: jax.Array
@@ -50,14 +53,16 @@ def two_loop_direction(g, s_hist, y_hist, rho, k, m):
     """Two-loop recursion over a circular buffer; slot (k-1-i) mod m is the
     i-th most recent pair, masked out when i >= min(k, m)."""
     dtype = g.dtype
+    (d,) = g.shape
     n_valid = jnp.minimum(k, m)
 
     def newest_to_oldest(i, carry):
         q, alphas = carry
         j = jnp.mod(k - 1 - i, m)
         valid = i < n_valid
-        a = jnp.where(valid, rho[j] * jnp.sum(s_hist[j] * q), 0.0)
-        q = q - a * y_hist[j]
+        a = jnp.where(
+            valid, rho[j] * jnp.sum(history_slot(s_hist, j, d) * q), 0.0)
+        q = q - a * history_slot(y_hist, j, d)
         return q, alphas.at[j].set(a)
 
     q, alphas = lax.fori_loop(
@@ -65,8 +70,9 @@ def two_loop_direction(g, s_hist, y_hist, rho, k, m):
     )
 
     newest = jnp.mod(k - 1, m)
-    sy = jnp.sum(s_hist[newest] * y_hist[newest])
-    yy = jnp.sum(y_hist[newest] * y_hist[newest])
+    y_newest = history_slot(y_hist, newest, d)
+    sy = jnp.sum(history_slot(s_hist, newest, d) * y_newest)
+    yy = jnp.sum(y_newest * y_newest)
     gamma = jnp.where((k > 0) & (yy > 0), sy / jnp.maximum(yy, jnp.finfo(dtype).tiny), 1.0)
     r = gamma * q
 
@@ -74,8 +80,8 @@ def two_loop_direction(g, s_hist, y_hist, rho, k, m):
         rank = n_valid - 1 - i  # recency rank, oldest first
         j = jnp.mod(k - 1 - rank, m)
         valid = rank >= 0
-        beta = rho[j] * jnp.sum(y_hist[j] * r)
-        upd = s_hist[j] * (alphas[j] - beta)
+        beta = rho[j] * jnp.sum(history_slot(y_hist, j, d) * r)
+        upd = history_slot(s_hist, j, d) * (alphas[j] - beta)
         return r + jnp.where(valid, upd, 0.0)
 
     r = lax.fori_loop(0, m, oldest_to_newest, r)
@@ -114,8 +120,8 @@ def lbfgs(
             sy > 1e-10 * jnp.maximum(l2_norm(step) * l2_norm(y), jnp.finfo(dtype).tiny)
         )
         slot = jnp.mod(s.k, m)
-        s_hist = jnp.where(store, s.s_hist.at[slot].set(step), s.s_hist)
-        y_hist = jnp.where(store, s.y_hist.at[slot].set(y), s.y_hist)
+        s_hist = history_store(s.s_hist, slot, step, store)
+        y_hist = history_store(s.y_hist, slot, y, store)
         rho = jnp.where(store, s.rho.at[slot].set(1.0 / jnp.where(sy == 0, 1.0, sy)), s.rho)
         # on line-search failure: reset the history and retry from
         # steepest descent; stall only if -g itself failed (k == 0).
@@ -161,7 +167,7 @@ def lbfgs(
 
     init = _State(
         it=jnp.asarray(0), k=jnp.asarray(0), w=w0, f=f0, g=g0,
-        s_hist=jnp.zeros((m, d), dtype), y_hist=jnp.zeros((m, d), dtype),
+        s_hist=history_zeros(m, d, dtype), y_hist=history_zeros(m, d, dtype),
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,

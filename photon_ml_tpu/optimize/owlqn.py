@@ -22,6 +22,8 @@ from photon_ml_tpu.optimize.common import (
     OptimizationResult,
     OptimizerConfig,
     converged_check,
+    history_store,
+    history_zeros,
     init_history,
     l2_norm,
     match_vma_tree,
@@ -108,8 +110,8 @@ def owlqn(
             sy = jnp.sum(step * y)
             store = ok & (sy > 1e-10 * jnp.maximum(l2_norm(step) * l2_norm(y), jnp.finfo(dtype).tiny))
             slot = jnp.mod(s.k, m)
-            s_hist = jnp.where(store, s.s_hist.at[slot].set(step), s.s_hist)
-            y_hist = jnp.where(store, s.y_hist.at[slot].set(y), s.y_hist)
+            s_hist = history_store(s.s_hist, slot, step, store)
+            y_hist = history_store(s.y_hist, slot, y, store)
             rho = jnp.where(store, s.rho.at[slot].set(1.0 / jnp.where(sy == 0, 1.0, sy)), s.rho)
             k_new = jnp.where(store, s.k + 1, s.k)
         with jax.named_scope("photon.owlqn/pseudo_gradient"):
@@ -131,7 +133,7 @@ def owlqn(
 
     init = _State(
         it=jnp.asarray(0), k=jnp.asarray(0), w=w0, F=F0, g=g0,
-        s_hist=jnp.zeros((m, d), dtype), y_hist=jnp.zeros((m, d), dtype),
+        s_hist=history_zeros(m, d, dtype), y_hist=history_zeros(m, d, dtype),
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,

@@ -51,7 +51,12 @@ from photon_ml_tpu.parallel.resilience import (
     use_transport,
 )
 from photon_ml_tpu.parallel.data_parallel import cached_jit
-from photon_ml_tpu.optimize.common import OptimizationResult, OptimizerConfig
+from photon_ml_tpu.optimize.common import (
+    OptimizationResult,
+    OptimizerConfig,
+    history_store,
+    history_zeros,
+)
 from photon_ml_tpu.optimize.lbfgs import two_loop_direction
 from photon_ml_tpu.types import LabeledBatch, SparseFeatures
 from photon_ml_tpu.utils import transfer_budget
@@ -754,8 +759,8 @@ def fit_streaming(
 
     f, g = fg(w, l2)
     g0_norm = float(jnp.linalg.norm(g))
-    s_hist = jnp.zeros((m, dim), dtype)
-    y_hist = jnp.zeros((m, dim), dtype)
+    s_hist = history_zeros(m, dim, dtype)
+    y_hist = history_zeros(m, dim, dtype)
     rho = jnp.zeros((m,), dtype)
     k = 0
     eps = float(jnp.finfo(dtype).eps)
@@ -798,8 +803,8 @@ def fit_streaming(
                 it += 1
                 break
             if k > 0:
-                s_hist = jnp.zeros((m, dim), dtype)
-                y_hist = jnp.zeros((m, dim), dtype)
+                s_hist = history_zeros(m, dim, dtype)
+                y_hist = history_zeros(m, dim, dtype)
                 rho = jnp.zeros((m,), dtype)
                 k = 0
                 continue
@@ -866,7 +871,8 @@ def _lbfgs_stream_kernels(objective, mesh, axis, m):
         def store_pair(s_hist, y_hist, rho, k, step, y):
             sy = jnp.sum(step * y)
             slot = jnp.mod(k, m)
-            return (s_hist.at[slot].set(step), y_hist.at[slot].set(y),
+            return (history_store(s_hist, slot, step),
+                    history_store(y_hist, slot, y),
                     rho.at[slot].set(1.0 / sy))
         return store_pair
 
@@ -1050,8 +1056,8 @@ def _fit_streaming_lbfgs_margin(objective, chunks, dim, w0, l2, config,
     g0_norm = float(jnp.linalg.norm(g))
     mw_h = margins_of(w, [None] * len(chunks))
     mp_h = [None] * len(chunks)
-    s_hist = jnp.zeros((m, dim), dtype)
-    y_hist = jnp.zeros((m, dim), dtype)
+    s_hist = history_zeros(m, dim, dtype)
+    y_hist = history_zeros(m, dim, dtype)
     rho = jnp.zeros((m,), dtype)
     k = 0
     eps = float(jnp.finfo(dtype).eps)
@@ -1114,8 +1120,8 @@ def _fit_streaming_lbfgs_margin(objective, chunks, dim, w0, l2, config,
                 it += 1
                 break
             if k > 0:
-                s_hist = jnp.zeros((m, dim), dtype)
-                y_hist = jnp.zeros((m, dim), dtype)
+                s_hist = history_zeros(m, dim, dtype)
+                y_hist = history_zeros(m, dim, dtype)
                 rho = jnp.zeros((m,), dtype)
                 k = 0
                 continue
@@ -1335,8 +1341,8 @@ def _fit_streaming_owlqn(objective, chunks, dim, w0, l2, l1, config, dtype,
     pg0_norm = float(jnp.linalg.norm(pg))
     eps = float(jnp.finfo(dtype).eps)
     tol = _host_tol(config.tolerance, dtype)
-    s_hist = jnp.zeros((m, dim), dtype)
-    y_hist = jnp.zeros((m, dim), dtype)
+    s_hist = history_zeros(m, dim, dtype)
+    y_hist = history_zeros(m, dim, dtype)
     rho = jnp.zeros((m,), dtype)
     k = 0
     loss_hist = np.full((config.max_iters,), np.nan)
@@ -1368,8 +1374,8 @@ def _fit_streaming_owlqn(objective, chunks, dim, w0, l2, l1, config, dtype,
             float(jnp.linalg.norm(step)) * float(jnp.linalg.norm(yv)), eps
         ):
             slot = k % m
-            s_hist = s_hist.at[slot].set(step)
-            y_hist = y_hist.at[slot].set(yv)
+            s_hist = history_store(s_hist, slot, step)
+            y_hist = history_store(y_hist, slot, yv)
             rho = rho.at[slot].set(1.0 / sy)
             k += 1
         F_prev = F

@@ -1,6 +1,6 @@
 """The fixed-effect fit's tracing (ISSUE 26): kernel scopes and program
 names in the lowered text, product counters against executed evaluations
-and against the parent's recorded fits (bit-equal), fit records that stay
+and against the parent's recorded fits (to rounding), fit records that stay
 on the device until read, and spans on the profiler's clock."""
 
 import collections
@@ -33,7 +33,7 @@ from photon_ml_tpu.types import (LabeledBatch, SparseFeatures, make_batch,
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(ROOT, "tests", "data")
 
-# -- counters and bit parity against the parent -----------------------------
+# -- counters and parity against the parent ---------------------------------
 # (optimizer, line_search, sparse_grad, chips) -> (gather, transpose)
 # products of the fit below; tests/data/fit_parity_pr25.npz holds what the
 # parent commit (PR 25, no counters, no scopes) returned for the same calls
@@ -82,14 +82,19 @@ def parity_fit(case, objective=None):
 
 @pytest.mark.parametrize("case", list(PARITY_CASES),
                          ids=["-".join(map(str, c)) for c in PARITY_CASES])
-def test_fit_counts_products_and_stays_bit_equal_to_parent(case):
+def test_fit_counts_products_and_stays_equal_to_parent(case):
     res = parity_fit(case)
     with np.load(os.path.join(DATA, "fit_parity_pr25.npz")) as parent:
         for field in PARITY_FIELDS:
-            np.testing.assert_array_equal(
+            # to rounding, not to the bit, since PR 35: at d = 24 the CPU
+            # compiler unrolls the two-loop's kernels whole, and with a
+            # slot sliced out of a flat history it contracts other
+            # multiply-adds of them (9 of 14 cases moved: |w| by 4.4e-16
+            # at most, the gradient norm by 2.1e-15; TRON's none)
+            np.testing.assert_allclose(
                 np.asarray(getattr(res, field)),
                 parent["-".join(map(str, case)) + "/" + field],
-                err_msg=field)
+                rtol=1e-12, atol=1e-14, err_msg=field)
     assert res.gather_products.dtype == jnp.int32
     assert res.transpose_products.dtype == jnp.int32
     got = (int(res.gather_products), int(res.transpose_products))

@@ -1,6 +1,8 @@
 """Optimizer tests vs scipy/sklearn ground truth on convex problems
 (the reference's optimizer unit tier: known convex problems, SURVEY.md §8)."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,3 +208,171 @@ def test_run_optimizer_is_the_direct_call(rng, name):
     assert int(got.iterations) == int(want.iterations) > 1
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the (s, y) history: one owner of its layout (optimize/common.py) --------
+HISTORY_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "data", "history_parity_pr35.npz")
+M = 4  # history slots in the two-loop tests
+
+
+def _pairs(rng, n, d):
+    """``n`` curvature pairs with ``s . y > 0``: ``y = A s`` for one SPD A
+    (diagonal plus rank one, so d = 8229 costs nothing)."""
+    diag, u = rng.random(d) + 0.5, rng.normal(size=d) / np.sqrt(d)
+    S = rng.normal(size=(n, d))
+    return S, S * diag + np.outer(S @ u, u)
+
+
+def _bfgs_direction(g, S, Y):
+    """``-H g`` by the BFGS recursion itself, pairs oldest first:
+    ``H_i = V_i^T H_{i-1} V_i + rho_i s_i s_i^T``, ``V_i = I - rho_i y_i
+    s_i^T``, ``H_0 = (s.y / y.y of the newest pair) I``; no pair: ``-g``.
+    Applied to the vector, never formed: d = 8229 costs m vectors."""
+    if not len(S):
+        return -g
+    gamma = (S[-1] @ Y[-1]) / (Y[-1] @ Y[-1])
+
+    def apply(i, v):
+        if i == 0:
+            return gamma * v
+        s, y = S[i - 1], Y[i - 1]
+        rho = 1.0 / (y @ s)
+        u = apply(i - 1, v - rho * y * (s @ v))
+        return u - rho * s * (y @ u) + rho * s * (s @ v)
+
+    return -apply(len(S), g)
+
+
+def test_bfgs_direction_oracle_is_the_dense_recursion(rng):
+    d = 10
+    S, Y = _pairs(rng, 3, d)
+    g = rng.normal(size=d)
+    H = (S[-1] @ Y[-1]) / (Y[-1] @ Y[-1]) * np.eye(d)
+    for s, y in zip(S, Y):
+        rho = 1.0 / (y @ s)
+        V = np.eye(d) - rho * np.outer(y, s)
+        H = V.T @ H @ V + rho * np.outer(s, s)
+    np.testing.assert_allclose(_bfgs_direction(g, S, Y), -H @ g, rtol=1e-12)
+
+
+def _filled_history(S, Y, d):
+    """The history after ``len(S)`` stores into ``M`` slots (circular):
+    ``s`` under a true ``store``, ``y`` with none (always), and a refused
+    pair (``store=False``) aimed at the next slot after each one."""
+    from photon_ml_tpu.optimize.common import history_store, history_zeros
+
+    s_hist = history_zeros(M, d, jnp.float64)
+    y_hist = history_zeros(M, d, jnp.float64)
+    rho = jnp.zeros((M,))
+    for k, (s, y) in enumerate(zip(S, Y)):
+        s_hist = history_store(s_hist, k % M, jnp.asarray(s), jnp.asarray(True))
+        y_hist = history_store(y_hist, k % M, jnp.asarray(y))
+        junk = jnp.full((d,), jnp.nan)
+        s_hist = history_store(s_hist, (k + 1) % M, junk, jnp.asarray(False))
+        rho = rho.at[k % M].set(1.0 / (s @ y))
+    return s_hist, y_hist, rho
+
+
+# d under a lane row, off the 128 lanes, off the 1,024-element tile with
+# the stride not filled (d < 8 tiles) and filled (8,229 -> 9,216)
+@pytest.mark.parametrize("d", [10, 200, 1300, 8229])
+@pytest.mark.parametrize("k", [0, 1, 3, 4, 6, 9])  # empty, partial, wrapped
+def test_two_loop_direction_is_the_bfgs_recursion(rng, d, k):
+    from photon_ml_tpu.optimize.common import _history_stride
+    from photon_ml_tpu.optimize.lbfgs import two_loop_direction
+
+    S, Y = _pairs(rng, k, d)
+    g = rng.normal(size=d)
+    s_hist, y_hist, rho = _filled_history(S, Y, d)
+    assert s_hist.shape == (M * _history_stride(d),)
+    assert _history_stride(d) == (d if d < 8192 else 9216)
+    p = jax.jit(two_loop_direction, static_argnums=5)(
+        jnp.asarray(g), s_hist, y_hist, rho, jnp.asarray(k), M)
+    want = _bfgs_direction(g, S[-M:], Y[-M:])
+    np.testing.assert_allclose(np.asarray(p), want, rtol=1e-9, atol=1e-12)
+
+
+def test_two_loop_direction_under_vmap(rng):
+    """The random effects' use: entities on the batch axis, each with its
+    own ``k``; a slot of ``d`` in the tens takes ``d`` elements, not a tile."""
+    from photon_ml_tpu.optimize.lbfgs import two_loop_direction
+
+    d, ks = 7, [0, 2, 4, 7]
+    problems = [_pairs(rng, k, d) for k in ks]
+    G = rng.normal(size=(len(ks), d))
+    hists = [_filled_history(S, Y, d) for S, Y in problems]
+    s_hist, y_hist, rho = (jnp.stack(x) for x in zip(*hists))
+    assert s_hist.shape == (len(ks), M * d)
+    P = jax.vmap(lambda g, s, y, r, k: two_loop_direction(g, s, y, r, k, M))(
+        jnp.asarray(G), s_hist, y_hist, rho, jnp.asarray(ks))
+    for e, (S, Y) in enumerate(problems):
+        np.testing.assert_allclose(
+            np.asarray(P[e]), _bfgs_direction(G[e], S[-M:], Y[-M:]),
+            rtol=1e-9, atol=1e-12)
+
+
+def _margin_fit(X, y, l2, cfg):
+    """``lbfgs_margin`` on a dense logistic problem, the callables written
+    out (``parallel/data_parallel.py`` builds them from an objective)."""
+    from photon_ml_tpu.optimize.lbfgs_margin import lbfgs_margin
+
+    X, y = jnp.asarray(X), jnp.asarray(y)
+
+    def loss_and_dir(m, mp):
+        return (jnp.sum(jnp.logaddexp(0.0, m) - y * m),
+                jnp.sum((jax.nn.sigmoid(m) - y) * mp))
+
+    d = X.shape[1]
+    return lbfgs_margin(
+        lambda p: X @ p, loss_and_dir,
+        lambda m: X.T @ (jax.nn.sigmoid(m) - y), lambda w: w,
+        jnp.zeros(d), jnp.zeros(X.shape[0]), l2, cfg)
+
+
+def history_parity_fit(name, d):
+    """The fits whose results ``tests/data/history_parity_pr35.npz`` holds
+    as the parent commit (PR 34: the history an ``[m, d]`` array) returned
+    them: the file's logistic fixture at the widths of the cases above, and
+    the vmapped fit of the random effects."""
+    fg, obj, batch, X, y, _, l2 = _logreg_problem(
+        np.random.default_rng(35), n=60, d=d)
+    cfg = OptimizerConfig(max_iters=25, tolerance=1e-9, history=5)
+    w0 = jnp.zeros(d)
+    if name == "lbfgs":
+        return lbfgs(fg, w0, cfg)
+    if name == "owlqn":
+        return owlqn(fg, w0, 0.3, cfg)
+    if name == "lbfgs_margin":
+        return _margin_fit(X, y, l2, cfg)
+    assert name == "lbfgs_vmap"
+    rng = np.random.default_rng(36)
+    Xe = jnp.asarray(rng.normal(size=(6, 40, d)))
+    ye = jnp.asarray((rng.random((6, 40)) < 0.5).astype(float))
+
+    def one(Xi, yi):
+        def fg_i(w):
+            m = Xi @ w
+            return (jnp.sum(jnp.logaddexp(0.0, m) - yi * m) + 0.5 * w @ w,
+                    Xi.T @ (jax.nn.sigmoid(m) - yi) + w)
+        return lbfgs(fg_i, w0, cfg)
+
+    return jax.vmap(one)(Xe, ye)
+
+
+HISTORY_PARITY_CASES = [(name, d) for name in ("lbfgs", "lbfgs_margin",
+                                               "owlqn")
+                        for d in (10, 200, 8229)] + [("lbfgs_vmap", 10)]
+HISTORY_PARITY_FIELDS = ("w", "value", "grad_norm", "iterations",
+                         "converged", "loss_history", "grad_norm_history")
+
+
+@pytest.mark.parametrize("name,d", HISTORY_PARITY_CASES)
+def test_history_layout_keeps_results_bit_equal_to_parent(name, d):
+    res = history_parity_fit(name, d)
+    assert np.all(np.asarray(res.iterations) > 5)
+    with np.load(HISTORY_DATA) as parent:
+        for field in HISTORY_PARITY_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(res, field)),
+                parent[f"{name}-{d}/{field}"], err_msg=field)

@@ -16,6 +16,7 @@ explicitly.
 """
 
 import contextlib
+import math
 import os
 import re
 
@@ -268,3 +269,73 @@ def test_row_gathers_read_tables_in_fast_memory(one_chip, cell):
     for layout in tables:
         assert re.fullmatch(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}",
                             layout), tables
+
+
+# the Criteo cells' width, history and passes (benchmark/configs/
+# criteo-lr.json, criteo-enet.json). Rows are cut from 2^19: the history
+# holds m slots of d whatever the rows are, and the compile's time is
+# linear in them
+HIST_DIM, HIST_M, HIST_ROWS = 1 << 24, 10, 1 << 12
+HISTORY_FITS = {
+    "lbfgs_margin": dict(optimizer="lbfgs", line_search="margin"),
+    "owlqn": dict(optimizer="owlqn", l1=1.0, line_search="full"),
+}
+
+
+@pytest.mark.parametrize("fit", list(HISTORY_FITS))
+def test_history_slot_is_contiguous_in_hbm(topo, fit):
+    """The TPU tiles an array's two minor dimensions (8, 128). With the
+    (s, y) history as ``[m, d]`` the slot index is the sublane axis: a slot
+    is one row in eight of every tile, ``two_loop_direction`` copies each
+    slot it reads out as ``f32[1, d]{T(1,128)}`` at eight times its bytes
+    (0.85 ms a slot at d = 2^24 on the v5e; PERF.md section 6, PR 35), and
+    the pair's store is a read-modify-write of eight rows. Held here on the
+    compiled L-BFGS-margin and OWL-QN fits at the cells' width: nothing
+    under the two-loop or the store produces a ``[1, d]`` slice, and no
+    loop carries a history whose slot index is a tiled dimension."""
+    from photon_ml_tpu.parallel.data_parallel import fit_distributed
+
+    mesh = make_mesh({"data": 1}, devices=topo.devices[:1])
+    obj = make_objective("logistic")
+    cfg = OptimizerConfig(max_iters=10, tolerance=0.0, history=HIST_M)
+
+    def run(w0, indices, labels, offsets, weights):
+        batch = LabeledBatch(SparseFeatures(indices, None, dim=HIST_DIM),
+                             labels, offsets, weights)
+        r = fit_distributed(obj, batch, mesh, w0, l2=1.0, config=cfg,
+                            sparse_grad="csc_pallas", **HISTORY_FITS[fit])
+        return r.w, r.value
+
+    rows = NamedSharding(mesh, P("data"))
+    s = jax.ShapeDtypeStruct
+    row = s((HIST_ROWS,), f32, sharding=rows)
+    text = jax.jit(run).lower(
+        s((HIST_DIM,), f32, sharding=NamedSharding(mesh, P())),
+        s((HIST_ROWS, K), i32, sharding=rows), row, row, row,
+    ).compile().as_text()
+
+    scopes = ("photon.lbfgs/two_loop", "photon.lbfgs/update",
+              "photon.owlqn/update")
+    scoped = [line for line in text.splitlines()
+              if re.search(r'op_name="[^"]*(?:%s)' % "|".join(scopes), line)]
+    assert any("photon.lbfgs/two_loop" in line for line in scoped)
+    slices = [m.group(1, 2) for m in (
+        re.match(r"\s*(?:ROOT )?(%%[\w.\-]+) = (f32\[1,%d\]\S*) " % HIST_DIM,
+                 line) for line in scoped) if m]
+    assert not slices, slices
+
+    # every array a while loop carries that is large enough to be a history
+    carried = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(.*\)) while\(", line)
+        if m:
+            carried.update(re.findall(r"f32\[([\d,]+)\]\{([\d,]+):", m[1]))
+    histories = []
+    for dims, minor_to_major in carried:
+        dims = [int(x) for x in dims.split(",")]
+        if math.prod(dims) >= HIST_M * HIST_DIM:
+            tiled = [dims[int(i)] for i in minor_to_major.split(",")[:2]]
+            histories.append((dims, tiled))
+    assert histories, "no loop carries a history"
+    on_tile = [h for h in histories if len(h[0]) > 1 and HIST_M in h[1]]
+    assert not on_tile, on_tile
