@@ -251,7 +251,8 @@ class TrainingMetrics:
       fit_passes_total / fit_products_total{kind=gather|transpose} — the
         optimizer iterations and the ``X v`` / ``X^T d`` products those
         fits ran, as their programs counted them (an OWL-QN fit's record
-        also carries ``line_search_trials`` and ``nonzeros``, in
+        also carries ``line_search_trials`` and ``nonzeros``, a TRON
+        fit's ``cg_steps``, ``rejected_steps`` and ``precond_passes``, in
         :meth:`fit_records` alone: no series). A fit's counters stay
         device scalars in its record (:meth:`record_fit`) until a read
         (:meth:`fit_records`, :meth:`snapshot`, :meth:`render`) or until
@@ -270,6 +271,10 @@ class TrainingMetrics:
 
     FIT_RECORDS = 64
     SWEEP_RECORDS = 64
+    # the device scalars of an ``OptimizationResult`` a fit record keeps
+    _FIT_COUNTERS = ("iterations", "gather_products", "transpose_products",
+                     "line_search_trials", "nonzeros", "cg_steps",
+                     "rejected_steps", "precond_passes")
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -445,18 +450,14 @@ class TrainingMetrics:
                    compiled: bool, dispatch_s: float, result) -> None:
         """One ``fit_distributed`` call, on its return. ``result``'s
         ``iterations`` / ``gather_products`` / ``transpose_products`` /
-        ``line_search_trials`` / ``nonzeros`` (the last two OWL-QN's) are
-        kept as they are — device scalars of a fit that may still be
+        ``line_search_trials`` / ``nonzeros`` (OWL-QN's) / ``cg_steps`` /
+        ``rejected_steps`` / ``precond_passes`` (TRON's) are kept as they are — device scalars of a fit that may still be
         running — and fetched only when the record is read, never on the
         fit's path; only a record pushed out of the ring (a fit
         ``FIT_RECORDS`` calls back) is fetched here, to be counted."""
         rec = {"optimizer": optimizer, "sparse_grad": sparse_grad,
                "compiled": bool(compiled), "dispatch_s": float(dispatch_s),
-               "iterations": result.iterations,
-               "gather_products": result.gather_products,
-               "transpose_products": result.transpose_products,
-               "line_search_trials": result.line_search_trials,
-               "nonzeros": result.nonzeros,
+               **{f: getattr(result, f, None) for f in self._FIT_COUNTERS},
                "counted": False}
         evicted = None
         with self._fit_lock:
@@ -474,9 +475,8 @@ class TrainingMetrics:
         made outside the lock ``record_fit`` takes."""
         if rec["counted"]:
             return
-        fields = ("iterations", "gather_products", "transpose_products",
-                  "line_search_trials", "nonzeros")
-        fetched = {f: None if rec[f] is None else int(rec[f]) for f in fields}
+        fetched = {f: None if rec[f] is None else int(rec[f])
+                   for f in self._FIT_COUNTERS}
         with self._fit_lock:
             if rec["counted"]:
                 return

@@ -166,6 +166,14 @@ class OptimizationResult(NamedTuple):
     # zero. None from the other optimizers.
     line_search_trials: "jax.Array | None" = None
     nonzeros: "jax.Array | None" = None
+    # TRON only, counted on the device (i32 scalars): the CG steps of all
+    # its outer iterations (one HVP each), the iterations whose trial
+    # point was refused (``w`` kept, the radius shrunk) and the Jacobi
+    # diagonals computed (the starting point's and one an accepted step; 0
+    # without a preconditioner). None from the other optimizers.
+    cg_steps: "jax.Array | None" = None
+    rejected_steps: "jax.Array | None" = None
+    precond_passes: "jax.Array | None" = None
 
 
 def converged_check(f_prev, f, g_norm, g0_norm, tol, f_scale=None):

@@ -113,6 +113,9 @@ class _State(NamedTuple):
     loss_hist: jax.Array
     gnorm_hist: jax.Array
     n_products: jax.Array  # i32: evaluations + HVPs (one X v + X^T d each)
+    cg_steps: jax.Array  # i32: CG steps (one HVP each) over all iterations
+    rejected_steps: jax.Array  # i32: iterations whose trial point was refused
+    precond_passes: jax.Array  # i32: Jacobi diagonals computed, m0 included
 
 
 def tron(
@@ -136,6 +139,7 @@ def tron(
             return jax.jvp(grad_only, (w,), (v,))[1]
 
     hvp = jax.named_scope("photon.tron/hvp")(hvp)
+    trial = jax.named_scope("photon.tron/trial")(fun_and_grad)
     if precond is not None:
         precond = jax.named_scope("photon.tron/precond")(precond)
     max_cg = max_cg_iters if max_cg_iters is not None else max(w0.shape[0], 20)
@@ -155,7 +159,7 @@ def tron(
         step, r, n_cg = _steihaug_cg(lambda v: hvp(s.w, v), s.g, s.delta,
                                   cg_tol, max_cg, m_diag=m_diag)
         w_try = s.w + step
-        f_try, g_try = fun_and_grad(w_try)
+        f_try, g_try = trial(w_try)
         gs = jnp.sum(s.g * step)
         # r == -(g + H step) from CG, so s.H.s = -g.s - r.s and
         # prered = -(g.s + s.H.s/2) = 0.5*(r.s - g.s) — no extra HVP needed
@@ -207,6 +211,10 @@ def tron(
             # one HVP a CG step and the trial point's (f, g); the Jacobi
             # diagonal is a scatter-add of its own, not a product
             s.n_products + n_cg.astype(jnp.int32) + 1,
+            s.cg_steps + n_cg.astype(jnp.int32),
+            s.rejected_steps + (~accept).astype(jnp.int32),
+            s.precond_passes + (accept.astype(jnp.int32)
+                                if precond is not None else 0),
         )
 
     def cond(s: _State):
@@ -220,10 +228,15 @@ def tron(
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
         n_products=jnp.asarray(1, jnp.int32),  # (f0, g0)
+        cg_steps=jnp.asarray(0, jnp.int32),
+        rejected_steps=jnp.asarray(0, jnp.int32),
+        precond_passes=jnp.asarray(int(precond is not None), jnp.int32),
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     return OptimizationResult(
         w=s.w, value=s.f, grad_norm=l2_norm(s.g), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
         gather_products=s.n_products, transpose_products=s.n_products,
+        cg_steps=s.cg_steps, rejected_steps=s.rejected_steps,
+        precond_passes=s.precond_passes,
     )

@@ -190,7 +190,8 @@ _LBFGS_SCOPES = ["photon.lbfgs/two_loop", "photon.lbfgs/line_search",
 _OWLQN_SCOPES = ["photon.lbfgs/two_loop", "photon.owlqn/pseudo_gradient",
                  "photon.owlqn/direction", "photon.owlqn/line_search",
                  "photon.owlqn/update"]
-_TRON_SCOPES = ["photon.tron/cg", "photon.tron/hvp", "photon.tron/precond"]
+_TRON_SCOPES = ["photon.tron/cg", "photon.tron/hvp", "photon.tron/precond",
+                "photon.tron/trial"]  # the trial point's (f, g): ISSUE 36
 # (optimizer, line_search, sparse_grad) -> (program, scopes beside the
 # kernels', call sites of X^T d: distinct name stacks of the `lp` gather)
 LOWERED = {
@@ -275,6 +276,14 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
         assert short and short[-1][0] <= B, (site, gathers)
     if sparse_grad == "csc_pallas":
         assert any("photon_multiply_prefix_sum" in s for s in stacks)
+    if optimizer == "tron":
+        # the trial point's products keep the kernels' scopes under the
+        # trial's, apart from the CG's HVPs and from (f0, g0)
+        trial = {s for s in stacks if "photon.tron/trial/" in s}
+        assert any("photon.table_gather/rows" in s for s in trial)
+        assert any("photon.csc/boundary_combine/lp" in s for s in trial)
+        assert not any("photon.tron/hvp" in s or "photon.tron/cg" in s
+                       for s in trial)
     if optimizer == "owlqn":
         assert not any("photon.lbfgs/update" in s
                        or "photon.lbfgs/line_search" in s for s in stacks)
@@ -388,13 +397,18 @@ class _OnDevice:
 
 
 def _result(passes, gathers, transposes, trials=None, nonzeros=None,
-            cls=_OnDevice):
-    """``trials`` and ``nonzeros`` are OWL-QN's: None from the others."""
+            cls=_OnDevice, tron=None):
+    """``trials`` and ``nonzeros`` are OWL-QN's, ``tron`` = (CG steps,
+    refused steps, diagonals) TRON's: None from the others."""
+    cg, refused, diagonals = tron or (None, None, None)
     return pytypes.SimpleNamespace(
         iterations=cls(passes), gather_products=cls(gathers),
         transpose_products=cls(transposes),
         line_search_trials=None if trials is None else cls(trials),
-        nonzeros=None if nonzeros is None else cls(nonzeros))
+        nonzeros=None if nonzeros is None else cls(nonzeros),
+        cg_steps=None if cg is None else cls(cg),
+        rejected_steps=None if refused is None else cls(refused),
+        precond_passes=None if diagonals is None else cls(diagonals))
 
 
 def test_record_fit_fetches_nothing_until_read_and_keeps_64():
@@ -423,7 +437,8 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
         "optimizer": "tron", "sparse_grad": "csc", "compiled": False,
         "dispatch_s": 0.5, "iterations": 1, "gather_products": 6,
         "transpose_products": 6, "line_search_trials": None,
-        "nonzeros": None}
+        "nonzeros": None, "cg_steps": None, "rejected_steps": None,
+        "precond_passes": None}
     assert records[0]["compiled"] is False  # the first record has gone
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 641}
     # an OWL-QN fit's record carries its two counters, fetched with the
@@ -436,6 +451,19 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
     assert _OnDevice.fetched == fetched + 5
     assert (last["line_search_trials"], last["nonzeros"]) == (12, 580063)
     assert (last["iterations"], last["gather_products"]) == (10, 23)
+    assert (last["cg_steps"], last["rejected_steps"],
+            last["precond_passes"]) == (None, None, None)
+    # and a TRON fit's its three (ISSUE 36), fetched on read and not before
+    fetched = _OnDevice.fetched
+    tm.record_fit(optimizer="tron", sparse_grad="csc_pallas", compiled=False,
+                  dispatch_s=0.1,
+                  result=_result(6, 19, 19, tron=(12, 1, 6)))
+    assert _OnDevice.fetched == fetched
+    last = tm.fit_records()[-1]
+    assert _OnDevice.fetched == fetched + 6
+    assert (last["cg_steps"], last["rejected_steps"],
+            last["precond_passes"]) == (12, 1, 6)
+    assert (last["line_search_trials"], last["nonzeros"]) == (None, None)
 
 
 def test_fit_distributed_leaves_a_record_without_a_device_fetch():
@@ -455,6 +483,19 @@ def test_fit_distributed_leaves_a_record_without_a_device_fetch():
     assert (rec["iterations"], rec["gather_products"],
             rec["transpose_products"]) == (int(res.iterations), 29, 29)
     assert 0 < rec["dispatch_s"] < 60
+    # TRON's own three: products = (f0, g0) + an HVP a CG step + a trial
+    # a pass; a diagonal at w0 and one an accepted step
+    assert rec["cg_steps"] == 29 - 1 - rec["iterations"]
+    assert rec["rejected_steps"] == int(res.rejected_steps)
+    assert rec["precond_passes"] == (
+        1 + rec["iterations"] - rec["rejected_steps"])
+    for case in (("lbfgs", "margin", "scatter", 1),
+                 ("owlqn", "full", "scatter", 1)):
+        other = parity_fit(case)
+        rec = tm.fit_records()[-1]
+        assert rec["optimizer"] == case[0]
+        for name in ("cg_steps", "rejected_steps", "precond_passes"):
+            assert getattr(other, name) is None and rec[name] is None
 
 
 def test_a_fit_traced_inside_a_jit_leaves_no_record():
@@ -698,6 +739,30 @@ def test_new_metrics_are_declared_for_every_cell():
         m = declared[name]
         assert (m["source"], m["layer"], m["moves"], m["workloads"]) == (
             source, layer, "train_rows_per_s", ["criteo-enet.fit"])
+    # the Poisson TRON cell's seven, for that cell alone (ISSUE 36)
+    pois = {m["name"]: m for m in bench["per_layer"]
+            if m["name"].startswith("pois_")}
+    assert list(pois) == [
+        "pois_mfu_pct", "pois_roofline_pct", "pois_device_idle_pct",
+        "pois_pass_ms", "pois_products_per_pass", "pois_cg_steps_per_pass",
+        "pois_rejected_steps_per_pass"]
+    for name, source, layer in (
+            ("pois_mfu_pct", "host_clock", "whole step"),
+            ("pois_roofline_pct", "host_clock", "whole step"),
+            ("pois_device_idle_pct", "device_trace", "device"),
+            ("pois_pass_ms", "host_clock", "optimize"),
+            ("pois_products_per_pass", "program_counter", "optimize"),
+            ("pois_cg_steps_per_pass", "program_counter", "optimize"),
+            ("pois_rejected_steps_per_pass", "program_counter", "optimize")):
+        m = pois[name]
+        assert (m["source"], m["layer"], m["moves"], m["workloads"]) == (
+            source, layer, "train_rows_per_s", ["criteo-poisson-tron.fit"])
+        assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
+                                           name + ".py"))
+    # and no list the benchmark had names the new cell
+    for m in bench["per_layer"]:
+        if not m["name"].startswith("pois_"):
+            assert "criteo-poisson-tron.fit" not in m["workloads"], m["name"]
 
 
 def test_traced_rehearsal_still_ends():

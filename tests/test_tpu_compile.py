@@ -339,3 +339,43 @@ def test_history_slot_is_contiguous_in_hbm(topo, fit):
     assert histories, "no loop carries a history"
     on_tile = [h for h in histories if len(h[0]) > 1 and HIST_M in h[1]]
     assert not on_tile, on_tile
+
+
+def test_poisson_tron_fit_compiles_at_the_cells_shapes(topo):
+    """``criteo-poisson-tron.fit``'s program (ISSUE 36) at the cell's own
+    shapes: 2^20 rows of 39 implicit ones over 2^24 columns, float32, six
+    outer iterations under ``tolerance=0``, ``csc_pallas``; the column sort
+    is compiled into the same program here (the cell hands the fit a
+    precomputed view). About 40 s: with implicit ones the flatten that makes
+    the L-BFGS compile above slow has no values to move."""
+    from photon_ml_tpu.parallel.data_parallel import fit_distributed
+
+    rows, dim = 1 << 20, 1 << 24
+    mesh = make_mesh({"data": 1}, devices=topo.devices[:1])
+    obj = make_objective("poisson")
+    cfg = OptimizerConfig(max_iters=6, tolerance=0.0)
+
+    def fit(w0, indices, counts, log_exposures, weights):
+        batch = LabeledBatch(SparseFeatures(indices, None, dim=dim),
+                             counts, log_exposures, weights)
+        r = fit_distributed(obj, batch, mesh, w0, l2=1.0, config=cfg,
+                            optimizer="tron", sparse_grad="csc_pallas")
+        return r.w, r.value, r.cg_steps, r.rejected_steps, r.precond_passes
+
+    on_rows = NamedSharding(mesh, P("data"))
+    s = jax.ShapeDtypeStruct
+    row = s((rows,), f32, sharding=on_rows)
+    compiled = jax.jit(fit).lower(
+        s((dim,), f32, sharding=NamedSharding(mesh, P())),
+        s((rows, K), i32, sharding=on_rows), row, row, row).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the Pallas prefix sum
+    assert "exponential" in text  # the Poisson loss and its d2, unclamped
+    for scope in ("photon.tron/trial", "photon.tron/hvp",
+                  "photon.tron/precond", "photon.tron/cg"):
+        assert scope in text, scope
+    mem = compiled.memory_analysis()
+    print(mem)
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert 0.125 * 16 * 2 ** 30 < peak < 16e9  # over the cell's floor
