@@ -550,21 +550,21 @@ class _FixedState:
             else:
                 self._offset_sharding = None
             if use_csc:
-                build, fg_csc, hvp_csc = make_csc_path(
+                path = make_csc_path(
                     self.obj, work_mesh,
                     use_pallas=(sparse_grad == "csc_pallas"),
                 )
                 # sorted once here; offsets change per CD iteration, the
                 # sparsity pattern never does
                 csc = cached_jit(self.obj, ("cd_build_csc", sparse_grad),
-                                 lambda: build)(
+                                 lambda: path.build)(
                     LabeledBatch(feats, labels, jnp.zeros_like(labels), weights)
                 )
                 fit_data = (feats, labels, weights, csc)
 
                 def bind(batch, l2, csc):
-                    return (lambda w: fg_csc(w, batch, csc, l2),
-                            lambda w, v: hvp_csc(w, v, batch, csc, l2))
+                    return (lambda w: path.fg(w, batch, csc, l2),
+                            lambda w, v: path.hvp(w, v, batch, csc, l2))
             else:
                 fg_dist = distributed_value_and_grad(self.obj, mesh)
                 hvp_dist = distributed_hvp(self.obj, mesh)

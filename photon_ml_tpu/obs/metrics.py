@@ -252,11 +252,12 @@ class TrainingMetrics:
         optimizer iterations and the ``X v`` / ``X^T d`` products those
         fits ran, as their programs counted them (an OWL-QN fit's record
         also carries ``line_search_trials`` and ``nonzeros``, a TRON
-        fit's ``cg_steps``, ``rejected_steps`` and ``precond_passes``, in
-        :meth:`fit_records` alone: no series). A fit's counters stay
-        device scalars in its record (:meth:`record_fit`) until a read
-        (:meth:`fit_records`, :meth:`snapshot`, :meth:`render`) or until
-        the record leaves the ring of ``FIT_RECORDS``;
+        fit's ``cg_steps``, ``rejected_steps``, ``precond_passes`` and
+        ``curvature_passes``, in :meth:`fit_records` alone: no series). A
+        fit's counters stay device scalars in its record
+        (:meth:`record_fit`) until a read (:meth:`fit_records`,
+        :meth:`snapshot`, :meth:`render`) or until the record leaves the
+        ring of ``FIT_RECORDS``;
       sweep_total / sweep_seconds — CD sweeps and their host seconds;
       re_entities_solved_total / re_newton_iterations_total /
         re_row_slots_total{kind=real|padded}{coordinate} — what the
@@ -274,7 +275,7 @@ class TrainingMetrics:
     # the device scalars of an ``OptimizationResult`` a fit record keeps
     _FIT_COUNTERS = ("iterations", "gather_products", "transpose_products",
                      "line_search_trials", "nonzeros", "cg_steps",
-                     "rejected_steps", "precond_passes")
+                     "rejected_steps", "precond_passes", "curvature_passes")
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -451,9 +452,10 @@ class TrainingMetrics:
         """One ``fit_distributed`` call, on its return. ``result``'s
         ``iterations`` / ``gather_products`` / ``transpose_products`` /
         ``line_search_trials`` / ``nonzeros`` (OWL-QN's) / ``cg_steps`` /
-        ``rejected_steps`` / ``precond_passes`` (TRON's) are kept as they are — device scalars of a fit that may still be
-        running — and fetched only when the record is read, never on the
-        fit's path; only a record pushed out of the ring (a fit
+        ``rejected_steps`` / ``precond_passes`` / ``curvature_passes``
+        (TRON's) are kept as they are — device scalars of a fit that may
+        still be running — and fetched only when the record is read, never
+        on the fit's path; only a record pushed out of the ring (a fit
         ``FIT_RECORDS`` calls back) is fetched here, to be counted."""
         rec = {"optimizer": optimizer, "sparse_grad": sparse_grad,
                "compiled": bool(compiled), "dispatch_s": float(dispatch_s),

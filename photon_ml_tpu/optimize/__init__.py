@@ -21,19 +21,21 @@ def get_optimizer(name: str):
 
 
 def run_optimizer(optimizer: str, fg, w0, config, *, l1=None, l1_mask=None,
-                  hvp=None, precond=None):
+                  hvp=None, precond=None, curvature=None):
     """Run ``optimizer`` on ``fg(w) -> (value, grad)`` from ``w0``, handing
     it the extras it takes and no others: OWL-QN the L1 weight ``l1`` and
     the mask of the coefficients it shrinks (``l1_mask``, None = all); TRON
-    the Hessian-vector product ``hvp(w, v)`` (None = autodiff of ``fg``) and
-    the preconditioner's diagonal ``precond(w)`` (None = plain CG); L-BFGS
-    neither."""
+    the Hessian-vector product ``hvp(w, v)`` (None = autodiff of ``fg``),
+    the preconditioner's diagonal ``precond(w)`` (None = plain CG) and the
+    linearization ``curvature(w) -> c`` that both then read in ``w``'s
+    place (None = each recomputes its own); L-BFGS neither."""
     opt = get_optimizer(optimizer)
     optimizer = optimizer.lower()
     if optimizer == "owlqn":
         return opt(fg, w0, l1, config, l1_mask=l1_mask)
     if optimizer == "tron":
-        return opt(fg, w0, config, hvp=hvp, precond=precond)
+        return opt(fg, w0, config, hvp=hvp, precond=precond,
+                   curvature=curvature)
     return opt(fg, w0, config)
 
 

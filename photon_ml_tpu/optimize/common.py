@@ -168,12 +168,16 @@ class OptimizationResult(NamedTuple):
     nonzeros: "jax.Array | None" = None
     # TRON only, counted on the device (i32 scalars): the CG steps of all
     # its outer iterations (one HVP each), the iterations whose trial
-    # point was refused (``w`` kept, the radius shrunk) and the Jacobi
-    # diagonals computed (the starting point's and one an accepted step; 0
-    # without a preconditioner). None from the other optimizers.
+    # point was refused (``w`` kept, the radius shrunk), the Jacobi
+    # diagonals computed (the starting point's and one a step that is
+    # accepted and followed by another iteration; 0 without a
+    # preconditioner) and the evaluations of the caller's ``curvature``
+    # (at the same points; 0 without one: ``cg_steps / curvature_passes``
+    # HVPs shared each). None from the other optimizers.
     cg_steps: "jax.Array | None" = None
     rejected_steps: "jax.Array | None" = None
     precond_passes: "jax.Array | None" = None
+    curvature_passes: "jax.Array | None" = None
 
 
 def converged_check(f_prev, f, g_norm, g0_norm, tol, f_scale=None):

@@ -6,6 +6,15 @@ The decisive TPU difference (SURVEY.md §4.2): the reference pays one full
 cluster ``treeAggregate`` per CG step for each Hessian-vector product; here an
 HVP is forward-over-reverse autodiff inside the same XLA program — roughly two
 fused gradient passes, with any cross-device reduction riding ICI.
+
+The second-order oracle is evaluated at an iterate ONCE. A caller whose
+Hessian is ``X^T diag(d2(w)) X + ...`` hands :func:`tron` the linearization
+``curvature(w) -> c`` (any pytree: the ``[rows]`` vector ``d2`` for a GLM)
+and an ``hvp`` and a ``precond`` that read ``c`` where they would read ``w``:
+``c`` is carried in the loop state beside the Jacobi diagonal, recomputed
+only where a step is accepted and another CG solve will follow, so every
+CG step of a solve and the diagonal share one evaluation of it. Without
+``curvature`` both are handed ``w`` itself and recompute what they need.
 """
 
 from __future__ import annotations
@@ -107,6 +116,7 @@ class _State(NamedTuple):
     f: jax.Array
     g: jax.Array
     delta: jax.Array
+    c: object  # curvature(w) of the kept w (() without ``curvature``)
     m_diag: jax.Array  # cached preconditioner diag ([0] when unused)
     converged: jax.Array
     stalled: jax.Array
@@ -116,6 +126,7 @@ class _State(NamedTuple):
     cg_steps: jax.Array  # i32: CG steps (one HVP each) over all iterations
     rejected_steps: jax.Array  # i32: iterations whose trial point was refused
     precond_passes: jax.Array  # i32: Jacobi diagonals computed, m0 included
+    curvature_passes: jax.Array  # i32: curvature(w) evaluations, w0's included
 
 
 def tron(
@@ -125,13 +136,22 @@ def tron(
     hvp: Callable | None = None,
     max_cg_iters: int | None = None,
     precond: Callable | None = None,
+    curvature: Callable | None = None,
 ) -> OptimizationResult:
     """Minimize fun(w). ``hvp(w, v)`` defaults to forward-over-reverse autodiff
     of the gradient part of ``fun_and_grad``. ``precond(w)`` optionally
     returns the Hessian diagonal at w (one extra data pass per OUTER
     iteration) for Jacobi-preconditioned CG — fewer inner HVP passes on
-    badly-scaled problems."""
+    badly-scaled problems. ``curvature(w) -> c`` optionally linearizes the
+    objective at an iterate: ``hvp(c, v)`` and ``precond(c)`` are then
+    called with it in ``w``'s place (an explicit ``hvp`` is required). The
+    curvature and the diagonal are those of the kept ``w``: computed at
+    ``w0`` and after a step that is accepted AND followed by another
+    iteration — a refused step keeps them, and the last iterate's, which
+    no CG solve would read, are never computed."""
     dtype = w0.dtype
+    if curvature is not None and hvp is None:
+        raise ValueError("curvature needs the hvp(c, v) that reads it")
     if hvp is None:
         grad_only = lambda w: fun_and_grad(w)[1]
 
@@ -142,6 +162,9 @@ def tron(
     trial = jax.named_scope("photon.tron/trial")(fun_and_grad)
     if precond is not None:
         precond = jax.named_scope("photon.tron/precond")(precond)
+    if curvature is not None:
+        curvature = jax.named_scope("photon.tron/curvature")(curvature)
+    second_order = precond is not None or curvature is not None
     max_cg = max_cg_iters if max_cg_iters is not None else max(w0.shape[0], 20)
     f0, g0 = fun_and_grad(w0)
     g0_norm = l2_norm(g0)
@@ -152,11 +175,19 @@ def tron(
         return jnp.maximum(md, jnp.finfo(dtype).eps
                            * jnp.maximum(jnp.max(md), 1.0))
 
+    def _second_order(w):
+        """-> (c, m_diag) at ``w``, each as the state holds it."""
+        c = curvature(w) if curvature is not None else ()
+        at = w if curvature is None else c
+        return c, (_guard(precond(at)) if precond is not None
+                   else jnp.zeros((0,), dtype))
+
     @jax.named_scope("photon.tron/update")
     def body(s: _State) -> _State:
         cg_tol = 0.1 * l2_norm(s.g)
         m_diag = s.m_diag if precond is not None else None
-        step, r, n_cg = _steihaug_cg(lambda v: hvp(s.w, v), s.g, s.delta,
+        at = s.w if curvature is None else s.c
+        step, r, n_cg = _steihaug_cg(lambda v: hvp(at, v), s.g, s.delta,
                                   cg_tol, max_cg, m_diag=m_diag)
         w_try = s.w + step
         f_try, g_try = trial(w_try)
@@ -189,13 +220,6 @@ def tron(
         w_new = jnp.where(accept, w_try, s.w)
         f_new = jnp.where(accept, f_try, s.f)
         g_new = jnp.where(accept, g_try, s.g)
-        if precond is not None:
-            # the diag costs a data pass: recompute only on acceptance
-            # (w unchanged on rejection -> same diagonal)
-            m_new = lax.cond(accept, lambda: _guard(precond(w_new)),
-                             lambda: s.m_diag)
-        else:
-            m_new = s.m_diag
         gnorm = l2_norm(g_new)
         conv = accept & converged_check(s.f, f_new, gnorm, g0_norm, config.tolerance)
         # the quadratic model predicting no significant reduction IS
@@ -204,33 +228,45 @@ def tron(
         conv = conv | (prered <= eps * jnp.maximum(jnp.abs(s.f), 1.0))
         # radius below step resolution at w means further steps can't move w
         stalled = delta < eps * jnp.maximum(l2_norm(w_new), 1.0)
+        # the curvature and the diagonal each cost data passes: recompute
+        # them only where w moved (a refused step keeps w, so both stay
+        # valid) and a CG solve will read them (``cond`` of the next state)
+        renew = accept & ~conv & ~stalled & (s.it + 1 < config.max_iters)
+        if second_order:
+            c_new, m_new = lax.cond(renew, lambda: _second_order(w_new),
+                                    lambda: (s.c, s.m_diag))
+        else:
+            c_new, m_new = s.c, s.m_diag
+        renewed = renew.astype(jnp.int32)
         return _State(
-            s.it + 1, w_new, f_new, g_new, delta, m_new, conv, stalled,
+            s.it + 1, w_new, f_new, g_new, delta, c_new, m_new, conv, stalled,
             s.loss_hist.at[s.it].set(f_new),
             s.gnorm_hist.at[s.it].set(gnorm),
             # one HVP a CG step and the trial point's (f, g); the Jacobi
-            # diagonal is a scatter-add of its own, not a product
+            # diagonal and the curvature it shares with the HVPs are passes
+            # of their own (``precond_passes``, ``curvature_passes``), not
+            # products
             s.n_products + n_cg.astype(jnp.int32) + 1,
             s.cg_steps + n_cg.astype(jnp.int32),
             s.rejected_steps + (~accept).astype(jnp.int32),
-            s.precond_passes + (accept.astype(jnp.int32)
-                                if precond is not None else 0),
+            s.precond_passes + (renewed if precond is not None else 0),
+            s.curvature_passes + (renewed if curvature is not None else 0),
         )
 
     def cond(s: _State):
         return (~s.converged) & (~s.stalled) & (s.it < config.max_iters)
 
-    m0 = (_guard(precond(w0)) if precond is not None
-          else jnp.zeros((0,), dtype))
+    c0, m0 = _second_order(w0)
     init = _State(
         it=jnp.asarray(0), w=w0, f=f0, g=g0,
-        delta=g0_norm, m_diag=m0,
+        delta=g0_norm, c=c0, m_diag=m0,
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
         n_products=jnp.asarray(1, jnp.int32),  # (f0, g0)
         cg_steps=jnp.asarray(0, jnp.int32),
         rejected_steps=jnp.asarray(0, jnp.int32),
         precond_passes=jnp.asarray(int(precond is not None), jnp.int32),
+        curvature_passes=jnp.asarray(int(curvature is not None), jnp.int32),
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     return OptimizationResult(
@@ -239,4 +275,5 @@ def tron(
         gather_products=s.n_products, transpose_products=s.n_products,
         cg_steps=s.cg_steps, rejected_steps=s.rejected_steps,
         precond_passes=s.precond_passes,
+        curvature_passes=s.curvature_passes,
     )

@@ -15,7 +15,7 @@ from __future__ import annotations
 import functools
 import time
 from collections import OrderedDict
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -120,14 +120,19 @@ def distributed_diagonal_hessian(objective: GLMObjective, mesh: Mesh,
             return lax.psum(d, axis)
 
     def diag(w, batch, l2=0.0):
-        l2 = jnp.asarray(l2, w.dtype)
-        d = shard_diag(w, batch)
-        reg = jnp.full_like(d, l2)
-        if not objective.regularize_intercept and objective.intercept_index >= 0:
-            reg = reg.at[objective.intercept_index].set(0.0)
-        return d + reg
+        return _add_l2_diag(objective, shard_diag(w, batch), l2)
 
     return diag
+
+
+@jax.named_scope("photon.glm/reg")
+def _add_l2_diag(objective, d, l2):
+    """The data term's Hessian diagonal plus the L2 term's (0 at an
+    unregularized intercept)."""
+    reg = jnp.full_like(d, jnp.asarray(l2, d.dtype))
+    if not objective.regularize_intercept and objective.intercept_index >= 0:
+        reg = reg.at[objective.intercept_index].set(0.0)
+    return d + reg
 
 
 # Jitted-runner cache: one jit wrapper per (objective, fit configuration),
@@ -256,16 +261,41 @@ def _csc_apply(sparse_grad: str):
     return csc_transpose_apply, True
 
 
+class CSCPath(NamedTuple):
+    """What :func:`make_csc_path` returns (signatures in its docstring)."""
+
+    build: Callable
+    fg: Callable
+    hvp: Callable
+    curvature: Callable
+    hvp_at: Callable
+    diag_at: Callable
+
+
 def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
-                  use_pallas: bool = False):
+                  use_pallas: bool = False) -> CSCPath:
     """Scatter-free sparse gradient path (see ``types.CSCTranspose``).
 
-    Returns (build, fg, hvp): ``build(batch)`` sorts each shard's nonzeros by
-    column under ``shard_map`` (runs on device, once per jitted fit);
-    ``fg(w, batch, csc, l2)`` / ``hvp(w, v, batch, csc, l2)`` evaluate the
-    objective with explicit margin-space derivatives — forward is the ELL
-    gather, backward is the CSC prefix-sum, reductions are explicit psums.
-    Requires SparseFeatures.
+    ``build(batch)`` sorts each shard's nonzeros by column under
+    ``shard_map`` (runs on device, once per jitted fit); ``fg(w, batch, csc,
+    l2)`` / ``hvp(w, v, batch, csc, l2)`` evaluate the objective with
+    explicit margin-space derivatives — forward is the ELL gather, backward
+    is the CSC prefix-sum, reductions are explicit psums. Requires
+    SparseFeatures.
+
+    The second-order oracle comes in three pieces besides, so that a caller
+    who holds an iterate over many products (TRON's CG solve) evaluates it
+    there once: ``curvature(w, batch) -> d2``, the ``[rows]`` vector
+    ``wᵢ l''(mᵢ)`` at ``m = X w_eff + offsets`` (one gather; rows stay
+    sharded over ``axis``); ``hvp_at(d2, v, batch, csc, l2) = Xᵀ(d2 ⊙ X v)
+    + l2 v`` (two gathers, ``X v`` and the transpose's); and ``diag_at(d2,
+    csc, l2)``, the Hessian's exact diagonal ``Σᵢ d2ᵢ x'ᵢⱼ² + l2`` as
+    transposes of ``d2`` through the sorted view — its values squared; with
+    implicit ones the view itself — where
+    ``GLMObjective.diagonal_hessian`` scatter-adds (one gather, one prefix
+    sum: ``csc_transpose_apply``'s f32 cumsum under ``use_pallas`` too,
+    since ``d2`` is all-positive). ``hvp`` is ``hvp_at`` of ``curvature``:
+    one body.
 
     Normalization composes with the coefficient-space trick: margins use
     ``w_eff = f̃·w`` plus the scalar shift adjustment, and the transposed
@@ -299,9 +329,12 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
 
         return _build(feats.indices, feats.values)
 
-    def _margin_value_and_d(w, batch):
+    def _margins_at(w, batch):
         w_eff, adjust = _eff(w)
-        m = ell_margins(batch.features, w_eff) + batch.offsets + adjust
+        return ell_margins(batch.features, w_eff) + batch.offsets + adjust
+
+    def _margin_value_and_d(w, batch):
+        m = _margins_at(w, batch)
         per_ex = _weighted_loss(objective.loss, batch.weights, batch.labels)
         with jax.named_scope("photon.glm/loss"):
             f, d = jax.value_and_grad(per_ex)(m)
@@ -320,39 +353,76 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         return _psum_value(f, axis), _psum_grad(g, axis)
 
     @functools.partial(
+        shard_map, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
+    )
+    def curvature(w, batch):
+        m = _margins_at(w, batch)
+        with jax.named_scope("photon.glm/loss"):
+            return apply_weights(batch.weights,
+                                 objective.loss.d2(
+                                     mask_margins(batch.weights, m),
+                                     batch.labels))
+
+    @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P(), P(), P(axis), P(axis)),
+        in_specs=(P(axis), P(), P(axis), P(axis)),
         out_specs=P(),
         check_vma=check_vma,
     )
-    def shard_hvp(w, v, batch, csc_sh):
-        w_eff, adjust = _eff(w)
-        m = ell_margins(batch.features, w_eff) + batch.offsets + adjust
+    def shard_hvp_at(d2, v, batch, csc_sh):
         # directional margin: the margin is linear in w, so the same
         # effective-coefficient map applies to v (no offset term)
         v_eff, v_adjust = _eff(v)
         mv = ell_margins(batch.features, v_eff) + v_adjust
         with jax.named_scope("photon.glm/loss"):
-            d2 = apply_weights(batch.weights,
-                               objective.loss.d2(
-                                   mask_margins(batch.weights, m),
-                                   batch.labels))
             dv = d2 * mv
         csc = jax.tree.map(lambda a: a[0], csc_sh)
         return _psum_grad(_chain_t(apply_t(csc, dv), jnp.sum(dv)), axis)
+
+    @functools.partial(
+        shard_map, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(),
+    )
+    def shard_diag_at(d2, csc_sh):
+        # Always the blocked f32 cumsum, whatever ``apply_t`` is: d2 is
+        # all-positive, and the Pallas scan's two MXU dots round their
+        # inputs (the contributions, then the 128-lane row totals) to
+        # bfloat16 on the chip. Sign-mixed d forgives that (row totals
+        # stay small); under d2 = exp(m) a column that straddles a lane
+        # row is off by 2^-9 of a row total that dwarfs it, diagonals come
+        # out negative, and a Poisson fit refuses the steps that follow
+        # (PERF.md section 7.10, measured). ~2 ms a call dearer at 40.9M.
+        csc = jax.tree.map(lambda a: a[0], csc_sh)
+        squares = (csc if csc.values is None  # 1^2 == 1
+                   else csc.replace(values=csc.values ** 2))
+        diag = csc_transpose_apply(squares, d2)
+        # GLMObjective.diagonal_hessian's expansion of the (virtually)
+        # normalized square: f² (Σ d2 x² − 2 s Σ d2 x + s² Σ d2)
+        f, s = _norm_fixed_fs(norm, diag.dtype)
+        if s is not None:
+            diag = (diag - 2.0 * s * csc_transpose_apply(csc, d2)
+                    + s * s * jnp.sum(d2))
+        if f is not None:
+            diag = diag * f * f
+        return _psum_grad(diag, axis)
 
     def fg(w, batch, csc, l2=0.0):
         l2 = jnp.asarray(l2, w.dtype)
         f, g = shard_fg(w, batch, csc)
         return _add_l2(objective, w, l2, f, g)
 
-    def hvp(w, v, batch, csc, l2=0.0):
-        l2 = jnp.asarray(l2, w.dtype)
-        hv = shard_hvp(w, v, batch, csc)
+    def hvp_at(d2, v, batch, csc, l2=0.0):
+        l2 = jnp.asarray(l2, v.dtype)
+        hv = shard_hvp_at(d2, v, batch, csc)
         with jax.named_scope("photon.glm/reg"):
             return hv + l2 * objective._reg_mask(v)
 
-    return build, fg, hvp
+    def hvp(w, v, batch, csc, l2=0.0):
+        return hvp_at(curvature(w, batch), v, batch, csc, l2)
+
+    def diag_at(d2, csc, l2=0.0):
+        return _add_l2_diag(objective, shard_diag_at(d2, csc), l2)
+
+    return CSCPath(build, fg, hvp, curvature, hvp_at, diag_at)
 
 
 # What "auto" resolves to where it was measured (PERF.md sections 5 and 7):
@@ -409,7 +479,7 @@ def build_csc(objective: GLMObjective, batch: LabeledBatch, mesh: Mesh,
         batch = shard_batch(batch, mesh, axis)
         build = cached_jit(
             objective, ("build_csc", mesh, axis),
-            lambda: make_csc_path(objective, mesh, axis)[0])
+            lambda: make_csc_path(objective, mesh, axis).build)
         return build(batch)
 
 
@@ -556,7 +626,7 @@ def _margin_fit(objective, mesh, axis, config, transpose, precomputed):
         reg_mask = objective._reg_mask
         build = None
         if use_csc and not precomputed:
-            build = make_csc_path(objective, mesh, axis)[0]
+            build = make_csc_path(objective, mesh, axis).build
 
         def run(w0, b, l2v, csc):
             if use_csc and csc is None:
@@ -577,46 +647,56 @@ def _black_box_fit(objective, mesh, axis, optimizer, config, sparse_grad,
                    precomputed):
     """-> (key, maker) of the fit that hands the optimizer a black-box
     objective: the program ``run(w0, b, l2v, l1v, csc)``. Its one fork is
-    where ``fg`` and ``hvp`` come from: autodiff over the sharded
-    objective (XLA's scatter-add), or :func:`make_csc_path` — then ONE
-    program sorts the shard's nonzeros by column (or takes the view
-    :func:`build_csc` made) and runs the whole optimizer loop against the
-    sorted view, so the sort amortizes over every iteration (and over
-    every fit when precomputed). ``l1v`` is None for every optimizer but
-    OWL-QN, ``csc`` is None unless precomputed: neither is an operand
-    then."""
+    where the oracle comes from: autodiff over the sharded objective
+    (XLA's scatter-add), or :func:`make_csc_path` — then ONE program sorts
+    the shard's nonzeros by column (or takes the view :func:`build_csc`
+    made) and runs the whole optimizer loop against the sorted view, so
+    the sort amortizes over every iteration (and over every fit when
+    precomputed). ``l1v`` is None for every optimizer but OWL-QN, ``csc``
+    is None unless precomputed: neither is an operand then."""
     use_csc = uses_csc(sparse_grad)
     key = (f"fit_{optimizer}", mesh, axis, config, sparse_grad, precomputed)
 
     def make():
         if use_csc:
-            build, fg, hvp = make_csc_path(
-                objective, mesh, axis,
-                use_pallas=(sparse_grad == "csc_pallas"))
+            path = make_csc_path(objective, mesh, axis,
+                                 use_pallas=(sparse_grad == "csc_pallas"))
         else:
             fg = distributed_value_and_grad(objective, mesh, axis)
             hvp = distributed_hvp(objective, mesh, axis)
-        diag = distributed_diagonal_hessian(objective, mesh, axis)
+            diag = distributed_diagonal_hessian(objective, mesh, axis)
         mask_int = (objective.intercept_index
                     if (objective.intercept_index >= 0
                         and not objective.regularize_intercept) else -1)
 
         def run(w0, b, l2v, l1v, csc):
-            if use_csc and csc is None:
-                csc = build(b)
-            data = (b, csc) if use_csc else (b,)
             # L1 intercept mask (consistent with the L2 mask) is
             # shape-dependent: derive from the traced w0 so the cached
             # runner serves any dimension
             l1_mask = (None if l1v is None or mask_int < 0
                        else jnp.ones_like(w0).at[mask_int].set(0.0))
-            # Jacobi preconditioner (TRON): one extra data pass per OUTER
-            # iteration buys fewer CG passes (each CG step is a full pass)
-            return run_optimizer(
-                optimizer, lambda w: fg(w, *data, l2v), w0, config,
-                l1=l1v, l1_mask=l1_mask,
-                hvp=lambda w, v: hvp(w, v, *data, l2v),
-                precond=lambda w: diag(w, b, l2v))
+            # TRON's second-order oracle. The Jacobi preconditioner buys
+            # fewer CG passes (each CG step is a full pass) for a diagonal
+            # an outer iteration. With the sorted view in hand TRON
+            # carries the curvature d2(w) of its iterate: every HVP of a
+            # CG solve and the diagonal (a transpose of d2 through the
+            # view, no scatter-add) read that one vector. Without a view
+            # each recomputes the margins at the w it is handed.
+            if use_csc:
+                if csc is None:
+                    csc = path.build(b)
+                fg_at = lambda w: path.fg(w, b, csc, l2v)
+                second_order = dict(
+                    curvature=lambda w: path.curvature(w, b),
+                    hvp=lambda d2, v: path.hvp_at(d2, v, b, csc, l2v),
+                    precond=lambda d2: path.diag_at(d2, csc, l2v))
+            else:
+                fg_at = lambda w: fg(w, b, l2v)
+                second_order = dict(
+                    hvp=lambda w, v: hvp(w, v, b, l2v),
+                    precond=lambda w: diag(w, b, l2v))
+            return run_optimizer(optimizer, fg_at, w0, config, l1=l1v,
+                                 l1_mask=l1_mask, **second_order)
 
         return run
 

@@ -77,7 +77,7 @@ def test_csc_fg_and_hvp_match_autodiff(sparse_batch, rng):
     obj = make_objective("logistic")
     mesh = make_mesh()
     batch = shard_batch(sparse_batch, mesh, "data")
-    build, fg, hvp = make_csc_path(obj, mesh)
+    build, fg, hvp = make_csc_path(obj, mesh)[:3]
     csc = jax.jit(build)(batch)
 
     fg_ad = distributed_value_and_grad(obj, mesh)
@@ -167,7 +167,7 @@ def test_csc_normalized_fg_hvp_exact(rng):
     sharded = shard_batch(batch, mesh)
     fg_ref = distributed_value_and_grad(obj, mesh)
     hvp_ref = distributed_hvp(obj, mesh)
-    build, fg_csc, hvp_csc = make_csc_path(obj, mesh)
+    build, fg_csc, hvp_csc = make_csc_path(obj, mesh)[:3]
     csc = jax.jit(build)(sharded)
     w = jnp.asarray(rng.normal(size=d + 1))
     v = jnp.asarray(rng.normal(size=d + 1))

@@ -91,10 +91,15 @@ def test_fit_counts_products_and_stays_equal_to_parent(case):
             # slot sliced out of a flat history it contracts other
             # multiply-adds of them (9 of 14 cases moved: |w| by 4.4e-16
             # at most, the gradient norm by 2.1e-15; TRON's none)
+            want = parent["-".join(map(str, case)) + "/" + field]
             np.testing.assert_allclose(
-                np.asarray(getattr(res, field)),
-                parent["-".join(map(str, case)) + "/" + field],
+                np.asarray(getattr(res, field)), want,
                 rtol=1e-12, atol=1e-14, err_msg=field)
+            if case[0] == "tron" and case[2] == "scatter":
+                # without a sorted view TRON is handed no curvature and is
+                # the parent's to the bit (ISSUE 37)
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(res, field)), want, err_msg=field)
     assert res.gather_products.dtype == jnp.int32
     assert res.transpose_products.dtype == jnp.int32
     got = (int(res.gather_products), int(res.transpose_products))
@@ -191,7 +196,8 @@ _OWLQN_SCOPES = ["photon.lbfgs/two_loop", "photon.owlqn/pseudo_gradient",
                  "photon.owlqn/direction", "photon.owlqn/line_search",
                  "photon.owlqn/update"]
 _TRON_SCOPES = ["photon.tron/cg", "photon.tron/hvp", "photon.tron/precond",
-                "photon.tron/trial"]  # the trial point's (f, g): ISSUE 36
+                "photon.tron/trial",  # the trial point's (f, g): ISSUE 36
+                "photon.tron/curvature"]  # d2 of an iterate, once: ISSUE 37
 # (optimizer, line_search, sparse_grad) -> (program, scopes beside the
 # kernels', call sites of X^T d: distinct name stacks of the `lp` gather)
 LOWERED = {
@@ -203,8 +209,9 @@ LOWERED = {
         ("photon_fit_lbfgs", _LBFGS_SCOPES, 2),  # g0, a search's trial
     # g0, a backtracking trial (dead code: compiled away), the accepted point
     ("owlqn", "full", "csc_pallas"): ("photon_fit_owlqn", _OWLQN_SCOPES, 3),
-    ("tron", "full", "csc_pallas"):
-        ("photon_fit_tron", _TRON_SCOPES, 3),  # g0, an HVP, the trial point
+    # g0, an HVP, the trial point, and the Jacobi diagonal (a transpose of
+    # the curvature through the view) at w0 and after an accepted step
+    ("tron", "full", "csc_pallas"): ("photon_fit_tron", _TRON_SCOPES, 5),
 }
 
 
@@ -284,6 +291,31 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
         assert any("photon.csc/boundary_combine/lp" in s for s in trial)
         assert not any("photon.tron/hvp" in s or "photon.tron/cg" in s
                        for s in trial)
+        # the second-order oracle at an iterate, once (ISSUE 37): an HVP
+        # gathers X v and dv[rows] and not X w; the curvature's one gather
+        # is X w, at w0 and in the loop; the diagonal's one is d2[rows]
+        names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+        takes = [names[loc] for loc in re.findall(
+            r'call @_take\w*\(.*loc\((#loc\d+)\)$', text, re.M)]
+        assert all(s.endswith("photon.table_gather/rows/jit(_take)")
+                   for s in takes), takes
+        under = lambda scope: [s for s in takes if f"/{scope}/" in s]
+        assert len(under("photon.tron/hvp")) == 2
+        assert len(under("photon.tron/curvature")) == 2  # w0's, the loop's
+        assert len(under("photon.tron/precond")) == 2
+        assert len(under("photon.tron/trial")) == 2  # X w, d[rows]
+        assert len(takes) == 10  # and (f0, g0)'s two
+        # the diagonal's prefix sum is the f32 cumsum, not the Pallas scan
+        # (d2 is all-positive: ``make_csc_path``)
+        assert not any("photon.tron/precond" in s
+                       and "multiply_prefix_sum" in s for s in stacks)
+        assert any("photon.tron/hvp" in s
+                   and "multiply_prefix_sum" in s for s in stacks)
+        # and no scatter-add: the program's scatters write the <= B
+        # spanning columns of a boundary combine and a history's one slot
+        assert all(s.endswith(("photon.csc/boundary_combine/span/scatter",
+                               "photon.tron/update/scatter"))
+                   for s in stacks if s.endswith("scatter"))
     if optimizer == "owlqn":
         assert not any("photon.lbfgs/update" in s
                        or "photon.lbfgs/line_search" in s for s in stacks)
@@ -399,8 +431,8 @@ class _OnDevice:
 def _result(passes, gathers, transposes, trials=None, nonzeros=None,
             cls=_OnDevice, tron=None):
     """``trials`` and ``nonzeros`` are OWL-QN's, ``tron`` = (CG steps,
-    refused steps, diagonals) TRON's: None from the others."""
-    cg, refused, diagonals = tron or (None, None, None)
+    refused steps, diagonals, curvatures) TRON's: None from the others."""
+    cg, refused, diagonals, curvatures = tron or (None,) * 4
     return pytypes.SimpleNamespace(
         iterations=cls(passes), gather_products=cls(gathers),
         transpose_products=cls(transposes),
@@ -408,7 +440,8 @@ def _result(passes, gathers, transposes, trials=None, nonzeros=None,
         nonzeros=None if nonzeros is None else cls(nonzeros),
         cg_steps=None if cg is None else cls(cg),
         rejected_steps=None if refused is None else cls(refused),
-        precond_passes=None if diagonals is None else cls(diagonals))
+        precond_passes=None if diagonals is None else cls(diagonals),
+        curvature_passes=None if curvatures is None else cls(curvatures))
 
 
 def test_record_fit_fetches_nothing_until_read_and_keeps_64():
@@ -438,7 +471,7 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
         "dispatch_s": 0.5, "iterations": 1, "gather_products": 6,
         "transpose_products": 6, "line_search_trials": None,
         "nonzeros": None, "cg_steps": None, "rejected_steps": None,
-        "precond_passes": None}
+        "precond_passes": None, "curvature_passes": None}
     assert records[0]["compiled"] is False  # the first record has gone
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 641}
     # an OWL-QN fit's record carries its two counters, fetched with the
@@ -451,18 +484,19 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
     assert _OnDevice.fetched == fetched + 5
     assert (last["line_search_trials"], last["nonzeros"]) == (12, 580063)
     assert (last["iterations"], last["gather_products"]) == (10, 23)
-    assert (last["cg_steps"], last["rejected_steps"],
-            last["precond_passes"]) == (None, None, None)
-    # and a TRON fit's its three (ISSUE 36), fetched on read and not before
+    assert (last["cg_steps"], last["rejected_steps"], last["precond_passes"],
+            last["curvature_passes"]) == (None, None, None, None)
+    # and a TRON fit's its four (ISSUE 36, 37), fetched on read and not
+    # before
     fetched = _OnDevice.fetched
     tm.record_fit(optimizer="tron", sparse_grad="csc_pallas", compiled=False,
                   dispatch_s=0.1,
-                  result=_result(6, 19, 19, tron=(12, 1, 6)))
+                  result=_result(6, 19, 19, tron=(12, 1, 5, 5)))
     assert _OnDevice.fetched == fetched
     last = tm.fit_records()[-1]
-    assert _OnDevice.fetched == fetched + 6
-    assert (last["cg_steps"], last["rejected_steps"],
-            last["precond_passes"]) == (12, 1, 6)
+    assert _OnDevice.fetched == fetched + 7
+    assert (last["cg_steps"], last["rejected_steps"], last["precond_passes"],
+            last["curvature_passes"]) == (12, 1, 5, 5)
     assert (last["line_search_trials"], last["nonzeros"]) == (None, None)
 
 
@@ -483,18 +517,24 @@ def test_fit_distributed_leaves_a_record_without_a_device_fetch():
     assert (rec["iterations"], rec["gather_products"],
             rec["transpose_products"]) == (int(res.iterations), 29, 29)
     assert 0 < rec["dispatch_s"] < 60
-    # TRON's own three: products = (f0, g0) + an HVP a CG step + a trial
-    # a pass; a diagonal at w0 and one an accepted step
+    # TRON's own: products = (f0, g0) + an HVP a CG step + a trial a
+    # pass; a diagonal at w0 and one a step accepted before the last (here
+    # every step is accepted and the last one converges); no curvature
+    # without the sorted view
     assert rec["cg_steps"] == 29 - 1 - rec["iterations"]
-    assert rec["rejected_steps"] == int(res.rejected_steps)
-    assert rec["precond_passes"] == (
-        1 + rec["iterations"] - rec["rejected_steps"])
+    assert rec["rejected_steps"] == int(res.rejected_steps) == 0
+    assert rec["precond_passes"] == rec["iterations"] > 1
+    assert rec["curvature_passes"] == 0
+    with_view = parity_fit(("tron", "full", "csc", 1), obj)
+    assert tm.fit_records()[-1]["curvature_passes"] == int(
+        with_view.precond_passes) == rec["iterations"]
     for case in (("lbfgs", "margin", "scatter", 1),
                  ("owlqn", "full", "scatter", 1)):
         other = parity_fit(case)
         rec = tm.fit_records()[-1]
         assert rec["optimizer"] == case[0]
-        for name in ("cg_steps", "rejected_steps", "precond_passes"):
+        for name in ("cg_steps", "rejected_steps", "precond_passes",
+                     "curvature_passes"):
             assert getattr(other, name) is None and rec[name] is None
 
 

@@ -210,6 +210,140 @@ def test_run_optimizer_is_the_direct_call(rng, name):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# -- TRON's second-order oracle at an iterate, once (ISSUE 37) ---------------
+def _poisson_oracles(rng, n=400, d=12, l2=1.0):
+    """A dense Poisson problem whose first TRON step is refused (counts in
+    the tens make ``|g0|`` too wide a radius for ``exp``), with its oracle
+    twice: from ``w`` (``hvp_w``, ``diag_w``) and from the curvature vector
+    ``d2(w) = exp(X w)`` (``curvature``, ``hvp_c``, ``diag_c``)."""
+    X = jnp.asarray(rng.normal(size=(n, d)) * 0.5)
+    y = jnp.asarray(rng.poisson(30.0 * np.exp(
+        np.asarray(X) @ (rng.normal(size=d) * 0.5))).astype(float))
+    batch = make_batch(X, np.asarray(y), dtype=jnp.float64)
+    obj = make_objective("poisson")
+    curvature = lambda w: jnp.exp(X @ w)
+    hvp_c = lambda d2, v: X.T @ (d2 * (X @ v)) + l2 * v
+    diag_c = lambda d2: (X * X).T @ d2 + l2
+    return dict(
+        fg=lambda w: obj.value_and_grad(w, batch, l2),
+        w0=jnp.zeros(d, jnp.float64),
+        hvp_w=lambda w, v: hvp_c(curvature(w), v),
+        diag_w=lambda w: diag_c(curvature(w)),
+        curvature=curvature, hvp_c=hvp_c, diag_c=diag_c)
+
+
+def _accepted(res, p):
+    """-> [steps] bool: the steps of a ``tolerance=0`` fit of problem ``p``
+    that moved the loss (a refused step hands back the loss before it, to
+    the bit)."""
+    history = np.asarray(res.loss_history)[:int(res.iterations)]
+    return np.diff(history, prepend=float(p["fg"](p["w0"])[0])) != 0
+
+
+def test_tron_with_curvature_is_tron_without(rng):
+    """The HVP and the diagonal handed ``curvature(w)`` are those handed
+    ``w``: the same arithmetic on one ``d2`` where there was one a call."""
+    p = _poisson_oracles(rng)
+    cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
+    without = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=p["diag_w"])
+    with_c = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_c"], precond=p["diag_c"],
+                  curvature=p["curvature"])
+    assert int(without.rejected_steps) >= 1  # both branches of the cond
+    assert int(without.cg_steps) > int(without.iterations) == 8
+    for name in ("iterations", "cg_steps", "rejected_steps",
+                 "precond_passes", "gather_products"):
+        assert int(getattr(with_c, name)) == int(getattr(without, name)), name
+    np.testing.assert_allclose(np.asarray(with_c.w), np.asarray(without.w),
+                               rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(with_c.loss_history),
+                               np.asarray(without.loss_history), rtol=1e-12)
+    assert int(without.curvature_passes) == 0
+    assert int(with_c.curvature_passes) == int(with_c.precond_passes)
+
+
+@pytest.mark.parametrize("precond", [False, True])
+def test_refused_step_keeps_the_curvature_of_the_kept_w(rng, precond):
+    """With ``curvature`` the identity, the state's ``c`` IS the iterate the
+    oracle believes it stands at. The fit is then the plain one bit for bit
+    only if, after a refused step as after an accepted one, ``c`` (and with
+    it the diagonal) is that of the kept ``w``."""
+    p = _poisson_oracles(rng)
+    cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
+    diag = p["diag_w"] if precond else None
+    plain = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=diag)
+    carried = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=diag,
+                   curvature=lambda w: w)
+    refused = ~_accepted(plain, p)
+    assert refused[0] and not refused.all()
+    assert int(plain.rejected_steps) == refused.sum()
+    for a, b in zip(jax.tree.leaves(carried._replace(curvature_passes=None)),
+                    jax.tree.leaves(plain._replace(curvature_passes=None))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("steps", [1, 2, 6])
+def test_second_order_passes_stop_before_the_last_iterate(rng, steps):
+    """One curvature and one diagonal at ``w0``, and one of each for every
+    step that is accepted and followed by another iteration: what the last
+    iteration would compute no CG solve reads."""
+    p = _poisson_oracles(rng)
+    res = tron(p["fg"], p["w0"],
+               OptimizerConfig(max_iters=steps, tolerance=0.0),
+               hvp=p["hvp_c"], precond=p["diag_c"], curvature=p["curvature"])
+    assert int(res.iterations) == steps
+    want = 1 + int(_accepted(res, p)[:-1].sum())
+    assert int(res.precond_passes) == int(res.curvature_passes) == want
+    if steps == 6:
+        assert 1 < want < 6  # a refused step and accepted ones among them
+    for counter in (res.precond_passes, res.curvature_passes):
+        assert counter.dtype == jnp.int32 and counter.shape == ()
+    # the diagonal alone follows the same rule
+    alone = tron(p["fg"], p["w0"],
+                 OptimizerConfig(max_iters=steps, tolerance=0.0),
+                 hvp=p["hvp_w"], precond=p["diag_w"])
+    assert (int(alone.precond_passes), int(alone.curvature_passes)) == (
+        want, 0)
+
+
+def test_converged_fit_computes_no_curvature_of_its_last_iterate(rng):
+    fg, obj, batch, X, y, ref, l2 = _logreg_problem(rng)
+    Xj = jnp.asarray(X)
+    sig = jax.nn.sigmoid
+    curvature = lambda w: sig(Xj @ w) * sig(-(Xj @ w))
+    res = tron(fg, jnp.zeros(X.shape[1], jnp.float64),
+               OptimizerConfig(max_iters=100, tolerance=1e-10),
+               hvp=lambda d2, v: Xj.T @ (d2 * (Xj @ v)) + l2 * v,
+               precond=lambda d2: (Xj * Xj).T @ d2 + l2,
+               curvature=curvature)
+    assert bool(res.converged) and int(res.rejected_steps) == 0
+    # every step accepted; the converging one renews nothing
+    assert int(res.curvature_passes) == int(res.iterations) > 1
+    np.testing.assert_allclose(res.value, ref.fun, rtol=1e-9)
+    np.testing.assert_allclose(res.w, ref.x, rtol=1e-4, atol=1e-6)
+
+
+def test_curvature_needs_an_explicit_hvp(rng):
+    p = _poisson_oracles(rng)
+    with pytest.raises(ValueError, match="curvature"):
+        tron(p["fg"], p["w0"], curvature=p["curvature"])
+
+
+def test_run_optimizer_hands_tron_the_curvature(rng):
+    from photon_ml_tpu.optimize import run_optimizer
+
+    p = _poisson_oracles(rng)
+    cfg = OptimizerConfig(max_iters=4, tolerance=0.0)
+    kw = dict(hvp=p["hvp_c"], precond=p["diag_c"], curvature=p["curvature"])
+    want = tron(p["fg"], p["w0"], cfg, **kw)
+    got = run_optimizer("tron", p["fg"], p["w0"], cfg, **kw)
+    assert int(got.curvature_passes) == int(want.curvature_passes) >= 1
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the other optimizers take none of it and count none of it
+    assert run_optimizer("lbfgs", p["fg"], p["w0"], cfg,
+                         **kw).curvature_passes is None
+
+
 # -- the (s, y) history: one owner of its layout (optimize/common.py) --------
 HISTORY_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "data", "history_parity_pr35.npz")

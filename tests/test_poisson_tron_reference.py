@@ -185,20 +185,27 @@ def test_program_follows_the_reference_step_by_step(followed, sparse_grad,
     assert theirs.any() and not theirs.all()  # both branches are held
     np.testing.assert_array_equal(
         refused(np.asarray(res.loss_history), f_start, rel=1e-12), theirs)
-    # the three counters against the reference's counts; with every step's
+    # the counters against the reference's counts; with every step's
     # loss equal to 1e-9, an equal total is an equal count a step
-    for counter in (res.cg_steps, res.rejected_steps, res.precond_passes):
+    for counter in (res.cg_steps, res.rejected_steps, res.precond_passes,
+                    res.curvature_passes):
         assert counter.dtype == jnp.int32 and counter.shape == ()
     assert int(res.cg_steps) == sum(cg)
     assert int(res.rejected_steps) == int(theirs.sum())
-    assert int(res.precond_passes) == 1 + int((~theirs).sum())
+    # w0's diagonal and one a step accepted before the last (ISSUE 37: the
+    # last iterate's no CG solve would read); with the sorted view in hand
+    # each comes from a curvature vector the solve's HVPs share
+    diagonals = 1 + int((~theirs[:-1]).sum())
+    curvatures = diagonals if sparse_grad == "csc" else 0
+    assert int(res.precond_passes) == diagonals
+    assert int(res.curvature_passes) == curvatures
     assert int(res.gather_products) == 1 + sum(cg) + STEPS
     assert int(res.transpose_products) == int(res.gather_products)
     record = training_metrics().fit_records()[-1]
     assert record["optimizer"] == "tron"
     assert (record["cg_steps"], record["rejected_steps"],
-            record["precond_passes"]) == (
-        sum(cg), int(theirs.sum()), 1 + int((~theirs).sum()))
+            record["precond_passes"], record["curvature_passes"]) == (
+        sum(cg), int(theirs.sum()), diagonals, curvatures)
     # and the runner's own derivations, for a program without the counters
     assert poisson.accepted_steps(
         np.asarray(res.loss_history), f_start, rel=1e-4) == (~theirs).sum()
@@ -215,8 +222,11 @@ def test_counters_of_each_step(followed):
     theirs = refused(losses, f_start)
     assert np.diff([0] + [int(r.rejected_steps) for r in fits]).tolist() == (
         theirs.astype(int).tolist())
-    assert np.diff([1] + [int(r.precond_passes) for r in fits]).tolist() == (
-        (~theirs).astype(int).tolist())
+    # a cap of s computes w0's diagonal and one for each of the first
+    # s - 1 steps that was accepted: the s-th step's has no reader
+    for counter in ("precond_passes", "curvature_passes"):
+        assert np.diff([1] + [int(getattr(r, counter)) for r in fits]
+                       ).tolist() == [0] + (~theirs[:-1]).astype(int).tolist()
 
 
 def test_other_optimizers_count_none_of_the_three():
@@ -234,7 +244,8 @@ def test_other_optimizers_count_none_of_the_three():
             config=OptimizerConfig(max_iters=2, tolerance=0.0),
             sparse_grad="scatter")
         record = training_metrics().fit_records()[-1]
-        for name in ("cg_steps", "rejected_steps", "precond_passes"):
+        for name in ("cg_steps", "rejected_steps", "precond_passes",
+                     "curvature_passes"):
             assert getattr(res, name) is None and record[name] is None
 
 
@@ -243,7 +254,7 @@ def test_tron_without_a_preconditioner_computes_no_diagonal():
 
     fg = jax.value_and_grad(lambda w: jnp.sum(jnp.cosh(w - 1.0)))
     res = tron(fg, jnp.zeros(5), OptimizerConfig(max_iters=4, tolerance=0.0))
-    assert int(res.precond_passes) == 0
+    assert int(res.precond_passes) == int(res.curvature_passes) == 0
     assert int(res.cg_steps) == int(res.gather_products) - 1 - 4
 
 
@@ -268,6 +279,7 @@ def test_overflowing_trial_is_a_refused_step(sparse_grad):
                       dtype=jnp.float32, w0=0.0)
     assert one.w.dtype == jnp.float32
     assert int(one.rejected_steps) == 1 and int(one.precond_passes) == 1
+    assert int(one.curvature_passes) == (sparse_grad == "csc")
     np.testing.assert_array_equal(np.asarray(one.w), 0.0)
     np.testing.assert_allclose(float(one.value), f_start, rtol=1e-5)
     # (that the trial really overflowed: the next test)
@@ -278,6 +290,12 @@ def test_overflowing_trial_is_a_refused_step(sparse_grad):
     assert np.all(np.isfinite(history)) and np.all(np.isfinite(longer.w))
     assert history[0] == np.float32(one.value)
     assert 1 <= int(longer.rejected_steps) < 8
+    # the refused steps renewed neither the curvature nor the diagonal
+    moved = np.diff(history, prepend=history[0]) != 0  # step 1 was refused
+    assert int(longer.rejected_steps) == 8 - moved.sum()
+    assert int(longer.precond_passes) == 1 + moved[:-1].sum()
+    assert int(longer.curvature_passes) == (
+        int(longer.precond_passes) if sparse_grad == "csc" else 0)
     assert float(longer.value) < f_start - 1.0  # accepted steps followed
     assert np.all(np.diff(history) <= 0)
     assert bool(jnp.isfinite(longer.grad_norm))

@@ -379,3 +379,89 @@ def test_poisson_tron_fit_compiles_at_the_cells_shapes(topo):
     peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert 0.125 * 16 * 2 ** 30 < peak < 16e9  # over the cell's floor
+
+
+# (loss, outer iterations) of the two TRON cells (benchmark/configs/
+# criteo-lr-tron.json, criteo-poisson-tron.json): 2^20 rows x 39 over 2^24
+TRON_CELLS = {"criteo-lr-tron": ("logistic", 1),
+              "criteo-poisson-tron": ("poisson", 6)}
+
+
+@pytest.mark.parametrize("cell", list(TRON_CELLS))
+def test_tron_evaluates_its_second_order_oracle_once_an_iterate(topo, cell):
+    """``photon_fit_tron`` over a precomputed view, as the cells run it
+    (ISSUE 37), read off the compiled text. The curvature ``d2(w)`` is
+    carried, so an HVP holds two row gathers (``X v`` and ``dv[rows]``: the
+    parent's third was ``X w`` again) beside its combine's ``lp``; the
+    Jacobi diagonal is a transpose of ``d2`` through the view, so nothing
+    sorts (XLA's scatter-add of 40.9M updates did, 467 ms a diagonal on
+    the chip) and the only scatters left write a combine's <= B spanning
+    columns; every gathered table has the fast memory space, the 4 MB
+    ``d2`` among them; and no ``[rows]`` vector is copied in the program:
+    the CG loop reads ``d2`` out of the outer loop's state in place. About
+    10 s a cell: no column sort to compile."""
+    from photon_ml_tpu.parallel.data_parallel import fit_distributed
+    from photon_ml_tpu.types import CSCTranspose
+
+    task, iters = TRON_CELLS[cell]
+    rows, dim = 1 << 20, 1 << 24
+    mesh = make_mesh({"data": 1}, devices=topo.devices[:1])
+    obj = make_objective(task)
+    cfg = OptimizerConfig(max_iters=iters, tolerance=0.0)
+
+    def fit(w0, indices, labels, offsets, weights, csc_rows, col_starts):
+        batch = LabeledBatch(SparseFeatures(indices, None, dim=dim),
+                             labels, offsets, weights)
+        r = fit_distributed(
+            obj, batch, mesh, w0, l2=1.0, config=cfg, optimizer="tron",
+            sparse_grad="csc_pallas", precomputed_csc=CSCTranspose(
+                values=None, rows=csc_rows, col_starts=col_starts))
+        return r.w, r.value, r.cg_steps, r.precond_passes, r.curvature_passes
+
+    on_rows = NamedSharding(mesh, P("data"))
+    s = jax.ShapeDtypeStruct
+    row = s((rows,), f32, sharding=on_rows)
+    text = jax.jit(fit).lower(
+        s((dim,), f32, sharding=NamedSharding(mesh, P())),
+        s((rows, K), i32, sharding=on_rows), row, row, row,
+        s((1, rows * K), i32, sharding=on_rows),
+        s((1, dim + 1), i32, sharding=on_rows)).compile().as_text()
+
+    assert "photon.tron/curvature" in text and "tpu_custom_call" in text
+    assert " sort(" not in text and "scatter-add" not in text
+    scatters = re.findall(r'scatter\([^\n]*op_name="([^"]*)"', text)
+    assert scatters and all(
+        name.endswith("photon.csc/boundary_combine/span/scatter")
+        for name in scatters), scatters
+    assert not re.search(r"= f32\[%d\]\S* copy\(" % rows, text)
+
+    layouts = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", text,
+                              re.M))
+    # (table, name stack above the gather's own scopes) of each row gather
+    gathers = [(layouts[table], site) for table, site in re.findall(
+        r"= f32\[\d+,128\]\S* fusion\((%[\w.\-]+), [^\n]*kind=kCustom"
+        r'[^\n]*op_name="([^"]*)/rows/jit\(_take\)/gather', text)]
+    for layout, site in gathers:
+        assert re.fullmatch(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}",
+                            layout), (layout, site)
+
+    def tables(scope, kernel):
+        return sorted(int(re.match(r"f32\[(\d+),", layout)[1]) * 128
+                      for layout, site in gathers
+                      if f"{scope}/{kernel}" in site)
+
+    halves = [-(-(rows * K) // (2 * 128)) * 128] * 2  # `lp`'s two runs
+    assert tables("photon.tron/hvp", "photon.table_gather") == [rows, dim]
+    assert tables("photon.tron/hvp", "photon.csc") == halves
+    assert tables("photon.tron/trial", "photon.table_gather") == [rows, dim]
+    # the diagonal gathers d2[rows] and nothing of w's length: at w0, and
+    # in the branch of the loop's ``cond`` that runs where a step is
+    # accepted and another iteration follows (never with one iteration:
+    # ``curvature_passes``, held on the CPU, says how often)
+    assert tables("photon.tron/precond", "photon.table_gather") == [rows] * 2
+    # w0's curvature shares (f0, g0)'s gather of X w0; the loop's is the
+    # accepted point's
+    assert tables("photon.tron/curvature", "photon.table_gather") == [dim]
+    # (f0, g0), an HVP and the trial point: a product gather, d[rows] and
+    # `lp`'s two runs each; a diagonal: d2[rows] and `lp`'s two
+    assert len(gathers) == 3 * 4 + 2 * 3 + 1
