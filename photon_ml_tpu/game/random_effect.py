@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import List, Optional, Sequence
 
 import jax
@@ -550,12 +551,21 @@ def upload(a, dtype=None) -> jax.Array:
     return jnp.asarray(a)
 
 
-def fetch(a) -> np.ndarray:
-    """Device array -> host numpy, counted
-    (``photon_train_d2h_bytes_total``); the call waits for the value."""
-    out = np.asarray(a)
-    if isinstance(a, jax.Array):
-        training_metrics().count_d2h(out.nbytes)
+def fetch(a, what: str) -> np.ndarray:
+    """Device array -> host numpy: the GAME path's one sync point. The
+    call waits for the value, inside a ``cd.fetch`` span (arg ``what``:
+    which value, e.g. ``train_loss``); a device array counts its bytes
+    (``photon_train_d2h_bytes_total``), one sync and the seconds the host
+    waited (``photon_train_syncs_total``, ``_sync_wait_seconds_total``)."""
+    if not isinstance(a, jax.Array):
+        return np.asarray(a)
+    with obs_trace.span("cd.fetch", cat="train", what=what):
+        t0 = time.perf_counter()
+        out = np.asarray(a)
+        waited = time.perf_counter() - t0
+    tm = training_metrics()
+    tm.count_d2h(out.nbytes)
+    tm.count_sync(waited)
     return out
 
 
@@ -778,7 +788,7 @@ class RandomEffectFitResult:
         if not any(c.shape[0] for c in self.converged):
             return {"converged": 0, "iterations_sum": 0, "iterations_max": 0}
         got = fetch(_fit_counts(tuple(self.converged),
-                                tuple(self.iterations)))
+                                tuple(self.iterations)), what="re.counts")
         return {"converged": int(got[0]), "iterations_sum": int(got[1]),
                 "iterations_max": int(got[2])}
 

@@ -2,7 +2,7 @@
 
 Generalized out of ``serve/metrics.py`` (which re-exports from here,
 unchanged API, byte-identical ``/metrics`` render) so training records
-through the same primitives: per-sweep solve/eval/comm time, chunk-cache
+through the same primitives: CD runs and sweeps, chunk-cache
 hits/misses, prefetch stalls, and cross-shard exchange bytes all land in
 one registry with the serving series' exposition format.
 
@@ -20,12 +20,12 @@ from __future__ import annotations
 
 import collections
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = [
     "Histogram", "ServingMetrics", "MetricsRegistry", "TrainingMetrics",
-    "DEFAULT_LATENCY_BUCKETS_MS", "DEFAULT_SECONDS_BUCKETS",
-    "escape_label_value", "training_metrics",
+    "TransferCounts", "DEFAULT_LATENCY_BUCKETS_MS",
+    "DEFAULT_SECONDS_BUCKETS", "escape_label_value", "training_metrics",
 ]
 
 # Default latency buckets (milliseconds): log-ish spacing from sub-ms to
@@ -232,14 +232,32 @@ class MetricsRegistry:
             return snap
 
 
+class TransferCounts(NamedTuple):
+    """What the GAME path moved and waited for so far (running totals of
+    :meth:`TrainingMetrics.transfer_counts`)."""
+
+    h2d_bytes: float
+    d2h_bytes: float
+    compiles: float  # XLA compiles; a persistent-cache load is not one
+    syncs: float  # blocking fetches (``random_effect.fetch``)
+    sync_wait_s: float  # host seconds spent inside them
+    cache_loads: float  # programs loaded from the persistent cache
+
+    def since(self, before: "TransferCounts") -> dict:
+        """The record fields of what moved between ``before`` and this."""
+        return {"h2d_bytes": self.h2d_bytes - before.h2d_bytes,
+                "d2h_bytes": self.d2h_bytes - before.d2h_bytes,
+                "compiles": self.compiles - before.compiles,
+                "syncs": self.syncs - before.syncs,
+                "sync_wait_seconds": self.sync_wait_s - before.sync_wait_s,
+                "cache_loads": self.cache_loads - before.cache_loads}
+
+
 class TrainingMetrics:
     """The training-side series (``photon_train_`` prefix), recorded by
     descent / streaming / entity_shard / chunk_cache through one
     process-wide instance (:func:`training_metrics`):
 
-      sweep_steps_total{coordinate} — CD coordinate steps;
-      solve_seconds / eval_seconds / comm_seconds{coordinate} —
-        histograms, the per-step phase split the CD history carries;
       chunk_cache_{warm,cold,fallthrough}_passes_total — decode-once
         cache effectiveness (warm == hit);
       prefetch_{stall,decode,transfer}_seconds_total — the streamed-pass
@@ -264,14 +282,20 @@ class TrainingMetrics:
         random-effect solves of those sweeps did;
       h2d_bytes_total / d2h_bytes_total — bytes the GAME path uploaded
         and fetched (:meth:`count_h2d` / :meth:`count_d2h`, called where
-        the program moves them); compiles_total — XLA compiles the
-        process made since the first sweep record was asked for. A
-        sweep's own share of the three is in its record
-        (:meth:`record_sweep`, read by :meth:`sweep_records`).
+        the program moves them); syncs_total / sync_wait_seconds_total —
+        its blocking fetches and the host's seconds inside them
+        (:meth:`count_sync`); compiles_total / cache_loads_total — XLA
+        compiles and persistent-cache loads the process made since the
+        first :meth:`transfer_counts`. A sweep's own share of them is in
+        its record (:meth:`record_sweep`, read by :meth:`sweep_records`);
+      run_total / run_seconds{stage} — ``CoordinateDescent.run`` calls,
+        the whole run and its ``prepare`` / ``finish`` stages, each run
+        also one record (:meth:`record_run`, read by :meth:`run_records`).
     """
 
     FIT_RECORDS = 64
     SWEEP_RECORDS = 64
+    RUN_RECORDS = 64
     # the device scalars of an ``OptimizationResult`` a fit record keeps
     _FIT_COUNTERS = ("iterations", "gather_products", "transpose_products",
                      "line_search_trials", "nonzeros", "cg_steps",
@@ -280,14 +304,6 @@ class TrainingMetrics:
     def __init__(self):
         self.registry = MetricsRegistry()
         r = self.registry
-        self._steps = r.counter("photon_train_sweep_steps_total",
-                                "CD coordinate steps completed")
-        self._solve = r.histogram("photon_train_solve_seconds",
-                                  bounds=DEFAULT_SECONDS_BUCKETS)
-        self._eval = r.histogram("photon_train_eval_seconds",
-                                 bounds=DEFAULT_SECONDS_BUCKETS)
-        self._comm = r.histogram("photon_train_comm_seconds",
-                                 bounds=DEFAULT_SECONDS_BUCKETS)
         self._cache = {
             "warm": r.counter("photon_train_chunk_cache_warm_passes_total"),
             "cold": r.counter("photon_train_chunk_cache_cold_passes_total"),
@@ -359,18 +375,27 @@ class TrainingMetrics:
                               "bytes the GAME path uploaded")
         self._d2h = r.counter("photon_train_d2h_bytes_total",
                               "bytes the GAME path fetched")
-        self._compiles = r.counter("photon_train_compiles_total",
-                                   "XLA compiles since the first sweep")
+        self._compiles = r.counter(
+            "photon_train_compiles_total",
+            "XLA compiles and persistent-cache loads since the first "
+            "sweep")
+        self._syncs = r.counter("photon_train_syncs_total",
+                                "blocking fetches of the GAME path")
+        self._sync_wait = r.counter(
+            "photon_train_sync_wait_seconds_total",
+            "host seconds spent blocked in those fetches")
+        self._cache_loads = r.counter(
+            "photon_train_cache_loads_total",
+            "programs loaded from the persistent compilation cache")
         self._compile_listener = False
         self._sweep_ring: collections.deque = collections.deque(
             maxlen=self.SWEEP_RECORDS)
-
-    def record_step(self, coordinate: str, solve_s: float, eval_s: float,
-                    comm_s: float) -> None:
-        self._steps.inc(1, coordinate=coordinate)
-        self._solve.observe(solve_s, coordinate=coordinate)
-        self._eval.observe(eval_s, coordinate=coordinate)
-        self._comm.observe(comm_s, coordinate=coordinate)
+        self._runs = r.counter("photon_train_run_total",
+                               "CoordinateDescent.run calls")
+        self._run_s = r.histogram("photon_train_run_seconds",
+                                  bounds=DEFAULT_SECONDS_BUCKETS)
+        self._run_ring: collections.deque = collections.deque(
+            maxlen=self.RUN_RECORDS)
 
     def count_h2d(self, nbytes: int) -> None:
         self._h2d.inc(int(nbytes))
@@ -378,10 +403,17 @@ class TrainingMetrics:
     def count_d2h(self, nbytes: int) -> None:
         self._d2h.inc(int(nbytes))
 
-    def transfer_counts(self) -> Tuple[float, float, float]:
-        """(bytes uploaded, bytes fetched, compiles) so far; the first
-        call starts the count of compiles (a ``jax.monitoring``
-        listener, which cannot be taken off again)."""
+    def count_sync(self, wait_s: float) -> None:
+        """One blocking fetch and the seconds the host waited in it."""
+        self._syncs.inc(1)
+        self._sync_wait.inc(wait_s)
+
+    def transfer_counts(self) -> TransferCounts:
+        """The running totals of :class:`TransferCounts`; the first call
+        starts the count of compiles and cache loads (``jax.monitoring``
+        listeners, which cannot be taken off again). JAX reports a load
+        from the persistent cache as a compile too, so a load is taken
+        off the compiles."""
         if not self._compile_listener:
             self._compile_listener = True
             import jax.monitoring as mon
@@ -390,18 +422,29 @@ class TrainingMetrics:
                 if event.endswith("backend_compile_duration"):
                     self._compiles.inc(1)
 
+            def on_event(event, **_):
+                if event.endswith("compilation_cache/cache_hits"):
+                    self._cache_loads.inc(1)
+
             mon.register_event_duration_secs_listener(on_duration)
-        return self._h2d.get(), self._d2h.get(), self._compiles.get()
+            mon.register_event_listener(on_event)
+        loads = self._cache_loads.get()
+        return TransferCounts(self._h2d.get(), self._d2h.get(),
+                              self._compiles.get() - loads,
+                              self._syncs.get(), self._sync_wait.get(),
+                              loads)
 
     def record_sweep(self, record: dict) -> None:
         """One CD sweep, at its end. ``record``: ``iteration``,
-        ``seconds``, ``h2d_bytes`` / ``d2h_bytes`` / ``compiles`` (the
-        sweep's share of :meth:`transfer_counts`) and ``coordinates``,
-        one dict a coordinate step (``name``, ``type``, ``seconds``,
-        ``fit_seconds``, ``rescore_seconds``, each closed by a fetched
-        value; for a random effect also ``entities_solved``,
-        ``iterations_sum``, ``iterations_max``, ``real_slots``,
-        ``padded_slots``, ``buckets``, ``blocks``)."""
+        ``seconds``, the sweep's share of :meth:`transfer_counts`
+        (``h2d_bytes``, ``d2h_bytes``, ``compiles``, ``syncs``,
+        ``sync_wait_seconds``, ``cache_loads``: ``TransferCounts.since``)
+        and ``coordinates``, one dict a coordinate step (``name``,
+        ``type``, ``seconds``, ``fit_seconds``, ``rescore_seconds``, each
+        closed by a fetched value, and the step's ``syncs`` and
+        ``sync_wait_seconds``; for a random effect also
+        ``entities_solved``, ``iterations_sum``, ``iterations_max``,
+        ``real_slots``, ``padded_slots``, ``buckets``, ``blocks``)."""
         self._sweeps.inc(1)
         self._sweep_s.observe(record["seconds"])
         for c in record["coordinates"]:
@@ -418,6 +461,23 @@ class TrainingMetrics:
     def sweep_records(self) -> List[dict]:
         """The last ``SWEEP_RECORDS`` sweeps, oldest first."""
         return list(self._sweep_ring)
+
+    def record_run(self, record: dict) -> None:
+        """One ``CoordinateDescent.run``, at its end. ``record``:
+        ``seconds`` (the whole call), ``prepare_seconds`` (the
+        ``cd.prepare`` span: everything before the first sweep),
+        ``finish_seconds`` (``cd.finish``: the model build and the
+        history), ``sweeps`` (sweep records it left), and ``prepare`` /
+        ``finish``: each stage's share of :meth:`transfer_counts`."""
+        self._runs.inc(1)
+        self._run_s.observe(record["seconds"], stage="run")
+        self._run_s.observe(record["prepare_seconds"], stage="prepare")
+        self._run_s.observe(record["finish_seconds"], stage="finish")
+        self._run_ring.append(record)
+
+    def run_records(self) -> List[dict]:
+        """The last ``RUN_RECORDS`` runs, oldest first."""
+        return list(self._run_ring)
 
     def record_chunk_cache_pass(self, kind: str) -> None:
         c = self._cache.get(kind)

@@ -425,10 +425,17 @@ def profile(trace_dir: Optional[str]):
     """A JAX profiler session into ``trace_dir`` (no-op when None), as a
     context manager: the one place the program starts one (the drivers'
     ``--profile-dir``). Load the result in TensorBoard/Perfetto, or read
-    it by scope with ``photon-trace kernels``."""
+    it by scope with ``photon-trace kernels`` and its idle time by span
+    with ``photon-trace gaps``. The host's events and the spans are
+    recorded, the Python tracer's one event a Python call is not (the
+    benchmark's ``--trace 1`` options): it slows the host several-fold,
+    and the device's idle gaps would be its own."""
     if not trace_dir:
         return contextlib.nullcontext()
-    return jax.profiler.trace(trace_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return jax.profiler.trace(trace_dir, profiler_options=opts)
 
 
 def maybe_start_from_env() -> Optional[Tracer]:

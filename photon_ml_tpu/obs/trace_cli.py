@@ -28,6 +28,12 @@ driver uses, without touching jax-compiled code.
 ``.xplane.pb``) by the program's ``photon.*`` scopes: device seconds,
 share of busy time, executions, bytes accessed per second and the
 instruction names that carried each scope (:mod:`photon_ml_tpu.obs.xplane`).
+
+``gaps``: the same trace's device idle time — each gap between leaf ops,
+from a device's first op to its last — put down to the innermost photon
+span on the host that covers it (``no span`` where none does): idle
+milliseconds, gaps and share of the idle time by span; the rows partition
+the idle time.
 """
 
 from __future__ import annotations
@@ -251,11 +257,24 @@ def _cmd_kernels(args) -> int:
     return 0 if table["busy_s"] > 0 else 1
 
 
+def _cmd_gaps(args) -> int:
+    from photon_ml_tpu.obs import xplane
+
+    table = xplane.gap_table(xplane.device_ops(args.trace),
+                             xplane.host_spans(args.trace))
+    if args.json:
+        print(json.dumps(table))
+    else:
+        print(xplane.format_gaps(table))
+    return 0 if table["devices"] else 1
+
+
 def build_arg_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="photon-trace",
         description="merge / validate / smoke-test photon trace files; "
-                    "read a device trace by scope")
+                    "read a device trace by scope and its idle time by "
+                    "host span")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     m = sub.add_parser("merge", help="merge per-rank trace files")
@@ -288,6 +307,15 @@ def build_arg_parser() -> argparse.ArgumentParser:
     k.add_argument("--json", action="store_true",
                    help="print the table as one JSON object")
     k.set_defaults(fn=_cmd_kernels)
+
+    g = sub.add_parser("gaps",
+                       help="device idle time by the innermost host span "
+                            "that covers it, from a profiler trace")
+    g.add_argument("trace", help="a profile directory, or one .xplane.pb "
+                                 "(or its .gz)")
+    g.add_argument("--json", action="store_true",
+                   help="print the table as one JSON object")
+    g.set_defaults(fn=_cmd_gaps)
     return p
 
 

@@ -1,4 +1,5 @@
-"""The device trace by ``photon.*`` scope: what ``photon-trace kernels`` prints.
+"""The device trace by ``photon.*`` scope and its idle time by host span: what
+``photon-trace kernels`` and ``photon-trace gaps`` print.
 
 The JAX profiler's ``.xplane.pb`` holds, per device, a line ``XLA Ops`` with
 one event per executed HLO instruction. The instruction's metadata carries
@@ -14,6 +15,11 @@ instructions of its body, so time is counted on *leaf* events: those that hold
 no other event of their line. An instruction belongs to the innermost
 ``photon.*`` scope of its name stack; a fusion has the name stack of the
 instruction XLA took its metadata from.
+
+The host's plane (``/host:CPU``) holds a line a thread. While a profiler
+session is live every ``obs.trace.span`` is an annotation there, on the
+device's clock (``docs/observability.md``, the annotation rule); an idle gap
+of a device is put down to the innermost span that covers it.
 """
 
 from __future__ import annotations
@@ -25,12 +31,19 @@ import re
 from collections import defaultdict
 from typing import Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["find_xplane", "device_ops", "scope_of", "kernel_table",
-           "format_table"]
+__all__ = ["find_xplane", "device_ops", "host_spans", "scope_of",
+           "kernel_table", "format_table", "gap_table", "format_gaps"]
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 UNSCOPED = "(no photon scope)"
+NO_SPAN = "no span"
+# the form of an ``obs.trace.span`` name, ``area.name`` (``cd.fetch``,
+# ``re.solve.bucket``); the runtime's own host events carry ``::``, spaces,
+# capitals, ``$`` or parentheses, or are one word (``shard_args``), and an
+# XLA instruction has a numeric suffix (``fusion.12``)
+SPAN_NAME = re.compile(r"^[a-z][a-z0-9_-]*(\.[a-z][a-z0-9_-]*)+$")
 # name-stack components that are control flow or a transformation, not a
 # scope: they may stand between a photon scope and its sub-scope
 _NOT_A_SCOPE = re.compile(
@@ -130,71 +143,96 @@ def find_xplane(path: str) -> str:
     return path
 
 
+def _read(path: str) -> memoryview:
+    path = find_xplane(path)
+    opener = gzip.open if path.endswith(".gz") else open
+    with opener(path, "rb") as f:
+        return memoryview(f.read())
+
+
+def _plane_lines(plane) -> Iterator[Tuple[str, List[Tuple[dict, int, int]]]]:
+    """(line name, [(event metadata, start_ps, end_ps)]) of one plane; the
+    metadata is ``{"name", "tf_op", "bytes_accessed", "flops"}``, ``name``
+    cut at the assignment (``fusion.79``) and at an annotation's arguments
+    (``cd.fetch#what=model#``)."""
+    lines, event_md, stat_names = [], {}, {}
+    for f, _, v in _fields(plane):
+        if f == 3:
+            lines.append(v)
+        elif f == 4:
+            key, md = _map_entry(v)
+            event_md[key] = md
+        elif f == 5:
+            key, md = _map_entry(v)
+            stat_names[key] = next(
+                (_text(x) for g, _, x in _fields(md) if g == 2), "")
+    decoded: Dict[int, dict] = {}
+
+    def metadata(mid: int) -> dict:
+        md = decoded.get(mid)
+        if md is None:
+            buf = event_md.get(mid, b"")
+            name = next((_text(x) for g, _, x in _fields(buf) if g == 2), "")
+            stats = _metadata_stats(buf, stat_names)
+            md = decoded[mid] = {
+                "name": name.split(" = ")[0].lstrip("%").split("#")[0],
+                "tf_op": stats.get("tf_op", ""),
+                "bytes_accessed": int(stats.get("bytes_accessed", 0)),
+                "flops": int(stats.get("flops", 0))}
+        return md
+
+    for line in lines:
+        name, t0_ns, events = "", 0, []
+        for f, _, v in _fields(line):
+            if f == 2:
+                name = _text(v)
+            elif f == 3:
+                t0_ns = v
+            elif f == 4:
+                events.append(v)
+        got = []
+        for ev in events:
+            mid = offset = dur = 0
+            for f, _, v in _fields(ev):
+                if f == 1:
+                    mid = v
+                elif f == 2:
+                    offset = v
+                elif f == 3:
+                    dur = v
+            start = t0_ns * 1000 + offset
+            got.append((metadata(mid), start, start + dur))
+        yield name, got
+
+
 def device_ops(path: str) -> Dict[str, List[dict]]:
     """-> {device plane: [op]}: every event of the plane's ``XLA Ops``
     line as ``{"name", "start_ps", "end_ps", "tf_op", "bytes_accessed",
     "flops"}`` (``name`` cut at the assignment: ``fusion.79``)."""
-    path = find_xplane(path)
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rb") as f:
-        space = memoryview(f.read())
     out: Dict[str, List[dict]] = {}
-    for field, _, plane in _fields(space):
+    for field, _, plane in _fields(_read(path)):
         if field != 1 or not DEVICE_PLANE.match(_plane_name(plane)):
             continue
-        lines, event_md, stat_names = [], {}, {}
-        for f, _, v in _fields(plane):
-            if f == 3:
-                lines.append(v)
-            elif f == 4:
-                key, md = _map_entry(v)
-                event_md[key] = md
-            elif f == 5:
-                key, md = _map_entry(v)
-                stat_names[key] = next(
-                    (_text(x) for g, _, x in _fields(md) if g == 2), "")
-        decoded: Dict[int, dict] = {}
-
-        def metadata(mid: int) -> dict:
-            md = decoded.get(mid)
-            if md is None:
-                buf = event_md.get(mid, b"")
-                name = next((_text(x) for g, _, x in _fields(buf) if g == 2),
-                            "")
-                stats = _metadata_stats(buf, stat_names)
-                md = decoded[mid] = {
-                    "name": name.split(" = ")[0].lstrip("%"),
-                    "tf_op": stats.get("tf_op", ""),
-                    "bytes_accessed": int(stats.get("bytes_accessed", 0)),
-                    "flops": int(stats.get("flops", 0))}
-            return md
-
-        ops = []
-        for line in lines:
-            name, t0_ns, events = "", 0, []
-            for f, _, v in _fields(line):
-                if f == 2:
-                    name = _text(v)
-                elif f == 3:
-                    t0_ns = v
-                elif f == 4:
-                    events.append(v)
-            if name != OPS_LINE:
-                continue
-            for ev in events:
-                mid = offset = dur = 0
-                for f, _, v in _fields(ev):
-                    if f == 1:
-                        mid = v
-                    elif f == 2:
-                        offset = v
-                    elif f == 3:
-                        dur = v
-                start = t0_ns * 1000 + offset
-                ops.append({**metadata(mid), "start_ps": start,
-                            "end_ps": start + dur})
-        out[_plane_name(plane)] = ops
+        out[_plane_name(plane)] = [
+            {**md, "start_ps": s0, "end_ps": s1}
+            for name, events in _plane_lines(plane) if name == OPS_LINE
+            for md, s0, s1 in events]
     return out
+
+
+def host_spans(path: str) -> List[Tuple[str, int, int]]:
+    """-> [(name, start_ps, end_ps)]: the host events whose name has a
+    span's form (:data:`SPAN_NAME`), and those named by one word that is
+    the area of such a span (``fit`` beside ``fit.dispatch``)."""
+    events = []
+    for field, _, plane in _fields(_read(path)):
+        if field == 1 and _plane_name(plane) == HOST_PLANE:
+            events.extend((md["name"], s0, s1)
+                          for _, line in _plane_lines(plane)
+                          for md, s0, s1 in line if s1 > s0)
+    spans = [ev for ev in events if SPAN_NAME.match(ev[0])]
+    areas = {name.split(".")[0] for name, _, _ in spans}
+    return spans + [ev for ev in events if ev[0] in areas]
 
 
 # -- from ops to scopes ------------------------------------------------------
@@ -294,4 +332,107 @@ def format_table(table: dict, instructions: int = 4) -> str:
             out.append(f"{scope:<44} {r['device_s']:>11.6f} "
                        f"{100 * r['share']:>7.2f} {r['executions']:>8d} "
                        f"{r['bytes_per_s'] / 1e9:>8.2f}  {names}")
+    return "\n".join(out)
+
+
+# -- from idle gaps to host spans ---------------------------------------------
+def _busy(ops: List[dict]) -> List[Tuple[int, int]]:
+    """The union of a device's leaf-op intervals, in order."""
+    merged: List[List[int]] = []
+    for s0, s1 in sorted((op["start_ps"], op["end_ps"])
+                         for op in _leaves(ops)):
+        if merged and s0 <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], s1)
+        else:
+            merged.append([s0, s1])
+    return [(s0, s1) for s0, s1 in merged]
+
+
+def _innermost(spans: List[Tuple[str, int, int]]
+               ) -> List[Tuple[int, int, str]]:
+    """The host's time as disjoint pieces ``(start, end, span)``, each under
+    the innermost span that covers it: of those that cover it, the one that
+    began last (on one thread spans nest, so that is the inner one; an
+    equal start goes to the one that ends first). Time no span covers
+    has no piece."""
+    edges = sorted({t for _, s0, s1 in spans for t in (s0, s1)})
+    starts = sorted(spans, key=lambda sp: sp[1])
+    pieces, active, k = [], [], 0
+    for t0, t1 in zip(edges, edges[1:]):
+        active = [sp for sp in active if sp[2] > t0]
+        while k < len(starts) and starts[k][1] <= t0:
+            if starts[k][2] > t0:
+                active.append(starts[k])
+            k += 1
+        if active:
+            name = max(active, key=lambda sp: (sp[1], -sp[2]))[0]
+            if pieces and pieces[-1][2] == name and pieces[-1][1] == t0:
+                pieces[-1] = (pieces[-1][0], t1, name)
+            else:
+                pieces.append((t0, t1, name))
+    return pieces
+
+
+def gap_table(ops_by_device: Dict[str, List[dict]],
+              spans: List[Tuple[str, int, int]]) -> dict:
+    """-> ``{"devices", "window_s", "busy_s", "idle_s", "gaps", "spans":
+    {span: {"idle_s", "gaps", "share"}}}`` over all devices: each idle gap
+    between a device's leaf ops (``device_ops``), from its first op to its
+    last, is cut where the innermost covering host span (``host_spans``)
+    changes and each part is put down to that span (:data:`NO_SPAN` where
+    none covers it), so the rows partition the idle time. A gap counts once
+    in each row it touches; shares are of the idle time."""
+    pieces = _innermost(spans)
+    rows: Dict[str, dict] = defaultdict(lambda: {"ps": 0, "gaps": 0})
+    window = busy = n_gaps = 0
+    for ops in ops_by_device.values():
+        if not ops:
+            continue
+        busy_ps = _busy(ops)
+        window += busy_ps[-1][1] - busy_ps[0][0]
+        busy += sum(s1 - s0 for s0, s1 in busy_ps)
+        gaps = [(a[1], b[0]) for a, b in zip(busy_ps, busy_ps[1:])]
+        n_gaps += len(gaps)
+        j = 0
+        for g0, g1 in gaps:
+            while j < len(pieces) and pieces[j][1] <= g0:
+                j += 1
+            touched, cur, k = set(), g0, j
+            while cur < g1:
+                if k < len(pieces) and pieces[k][0] < g1:
+                    p0, p1, name = pieces[k]
+                    if p0 > cur:
+                        rows[NO_SPAN]["ps"] += p0 - cur
+                        touched.add(NO_SPAN)
+                    rows[name]["ps"] += min(p1, g1) - max(p0, cur)
+                    touched.add(name)
+                    cur = min(p1, g1)
+                    k += 1
+                else:
+                    rows[NO_SPAN]["ps"] += g1 - cur
+                    touched.add(NO_SPAN)
+                    cur = g1
+            for name in touched:
+                rows[name]["gaps"] += 1
+    idle = sum(r["ps"] for r in rows.values())
+    return {"devices": sum(1 for ops in ops_by_device.values() if ops),
+            "window_s": window * 1e-12, "busy_s": busy * 1e-12,
+            "idle_s": idle * 1e-12, "gaps": n_gaps,
+            "spans": {name: {"idle_s": r["ps"] * 1e-12, "gaps": r["gaps"],
+                             "share": r["ps"] / idle if idle else 0.0}
+                      for name, r in sorted(rows.items(),
+                                            key=lambda kv: -kv[1]["ps"])}}
+
+
+def format_gaps(table: dict) -> str:
+    idle = table["idle_s"]
+    out = [f"device idle {1e3 * idle:.3f} ms of a window of "
+           f"{1e3 * table['window_s']:.3f} ms over {table['devices']} "
+           f"device(s), in {table['gaps']} gaps; by the innermost host span "
+           "that covers it",
+           "",
+           f"{'span':<32} {'idle ms':>12} {'gaps':>8} {'% idle':>7}"]
+    for name, r in table["spans"].items():
+        out.append(f"{name:<32} {1e3 * r['idle_s']:>12.3f} {r['gaps']:>8d} "
+                   f"{100 * r['share']:>7.2f}")
     return "\n".join(out)

@@ -14,11 +14,14 @@ turn):
   in a driver);
 * ``profile``: inside ``obs.trace.profile`` (a driver's ``--profile-dir``),
   after which ``photon-trace kernels`` is run on the trace and written to
-  ``<out>.kernels.txt`` / ``.json``; ``--keep-trace`` also keeps the
-  ``.xplane.pb`` gzipped (small sizes only: see ``--set``).
+  ``<out>.kernels.txt`` / ``.json``, and ``photon-trace gaps`` (the device's
+  idle time by host span) to ``<out>.gaps.txt`` / ``.json``;
+  ``--keep-trace`` also keeps the ``.xplane.pb`` gzipped (small sizes only:
+  see ``--set``).
 
 Prints one JSON line: each window's rate as the harness counts it and the
-fit records of its fits, the device. ``--set key=value`` overrides numbers of
+fit records of its fits (and, for a GLMix cell, the run records of its runs),
+the device. ``--set key=value`` overrides numbers of
 the cell's configuration (``--set rows_per_chip_log2=12``), for the small
 trace recorded under ``tests/data/``.
 """
@@ -63,12 +66,16 @@ def one_window(runner, tracing: str, seconds: float, out, keep_trace) -> dict:
             / (window["end"] - window["start"]),
             "fit_s": [q["t1"] - q["t0"] for q in pieces],
             "fits": training_metrics().fit_records()[-len(pieces):],
+            "runs": training_metrics().run_records()[-len(pieces):],
         }
         if tracing == "profile":
             found = xplane.find_xplane(trace_dir)
             table = xplane.kernel_table(found)
+            gaps = xplane.gap_table(xplane.device_ops(found),
+                                    xplane.host_spans(found))
             result["attributed_share"] = table["attributed_share"]
             result["busy_s"] = table["busy_s"]
+            result["idle_s"] = gaps["idle_s"]
             if out:
                 os.makedirs(os.path.dirname(os.path.abspath(out)),
                             exist_ok=True)
@@ -76,6 +83,10 @@ def one_window(runner, tracing: str, seconds: float, out, keep_trace) -> dict:
                     f.write(xplane.format_table(table, instructions=8) + "\n")
                 with open(out + ".kernels.json", "w") as f:
                     json.dump(table, f)
+                with open(out + ".gaps.txt", "w") as f:
+                    f.write(xplane.format_gaps(gaps) + "\n")
+                with open(out + ".gaps.json", "w") as f:
+                    json.dump(gaps, f)
             if keep_trace:
                 os.makedirs(os.path.dirname(os.path.abspath(keep_trace)),
                             exist_ok=True)

@@ -569,11 +569,10 @@ def test_streamed_results_count_nothing():
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 3}
 
 
-# the series a fresh TrainingMetrics rendered at the parent commit, in its
-# order: the new ones may only follow them
+# the series a fresh TrainingMetrics rendered before the fit's records, in
+# their order (less the four per-step series PR 38 removed: the CD step's
+# seconds are in the sweep record): the new ones may only follow them
 _PARENT_SERIES = [
-    "photon_train_sweep_steps_total", "photon_train_solve_seconds",
-    "photon_train_eval_seconds", "photon_train_comm_seconds",
     "photon_train_chunk_cache_warm_passes_total",
     "photon_train_chunk_cache_cold_passes_total",
     "photon_train_chunk_cache_fallthrough_passes_total",
@@ -608,7 +607,11 @@ def test_prometheus_text_keeps_its_contract():
         "photon_train_re_newton_iterations_total",
         "photon_train_re_row_slots_total",
         "photon_train_h2d_bytes_total", "photon_train_d2h_bytes_total",
-        "photon_train_compiles_total"]
+        "photon_train_compiles_total",
+        # the GAME path's blocking fetches, cache loads and runs (PR 38)
+        "photon_train_syncs_total", "photon_train_sync_wait_seconds_total",
+        "photon_train_cache_loads_total", "photon_train_run_total",
+        "photon_train_run_seconds"]
     assert dict(names)["photon_train_fit_dispatch_seconds"] == "histogram"
     assert "photon_train_fit_total 1\n" in text
     assert "photon_train_fit_passes_total 10\n" in text

@@ -195,18 +195,30 @@ class TestRegistry:
 
 
 class TestTrainingMetrics:
-    def test_record_step_and_render(self):
+    def test_record_run_and_render(self):
         tm = TrainingMetrics()
-        tm.record_step("fixed", solve_s=0.5, eval_s=0.1, comm_s=0.02)
-        tm.record_step("per-user", solve_s=1.5, eval_s=0.2, comm_s=0.04)
+        stage = {"h2d_bytes": 0.0, "d2h_bytes": 8.0, "compiles": 0.0,
+                 "syncs": 1.0, "sync_wait_seconds": 0.01,
+                 "cache_loads": 0.0}
+        for seconds in (1.5, 2.5):
+            tm.record_run({"seconds": seconds, "prepare_seconds": 0.25,
+                           "finish_seconds": 0.125, "sweeps": 2,
+                           "prepare": stage, "finish": stage})
+        runs = tm.run_records()
+        assert [r["seconds"] for r in runs] == [1.5, 2.5]
+        assert runs[0]["prepare"]["syncs"] == 1.0
         out = tm.render()
-        assert ('photon_train_sweep_steps_total{coordinate="fixed"} 1'
-                in out)
-        assert 'coordinate="per-user"' in out
-        assert "photon_train_solve_seconds" in out
-        steps = tm.snapshot()["photon_train_sweep_steps_total"]
-        assert sum(steps.values()) == 2
-        assert 'coordinate="fixed"' in steps
+        assert "photon_train_run_total 2\n" in out
+        assert 'photon_train_run_seconds_count{stage="run"} 2' in out
+        assert 'photon_train_run_seconds_sum{stage="prepare"} 0.5' in out
+        seconds = tm.snapshot()["photon_train_run_seconds"]
+        assert set(seconds) == {'stage="run"', 'stage="prepare"',
+                                'stage="finish"'}
+        # a ring of RUN_RECORDS, oldest first
+        for i in range(TrainingMetrics.RUN_RECORDS):
+            tm.record_run({**runs[0], "seconds": float(i)})
+        assert len(tm.run_records()) == TrainingMetrics.RUN_RECORDS
+        assert tm.run_records()[0]["seconds"] == 0.0
 
     def test_chunk_cache_and_prefetch_and_exchange(self):
         tm = TrainingMetrics()

@@ -302,23 +302,24 @@ def test_second_run_compiles_and_uploads_nothing(rng, optimizer):
             glmix_configs(l2, optimizer), n_iterations=2, dtype=jnp.float32,
             dataset_cache=cache).run(train)
         after = tm.transfer_counts()
-        return model, history, [a - b for a, b in zip(after, before)]
+        return model, history, after.since(before)
 
-    first, _, (up1, _, compiles1) = run(1.0)
+    first, _, moved1 = run(1.0)
     keys = set(cache)
-    assert compiles1 > 0 and up1 > n * 4 * 10  # tables, views, the shard
-    second, history, (up2, down2, compiles2) = run(1.0)
+    # tables, views, the shard
+    assert moved1["compiles"] > 0 and moved1["h2d_bytes"] > n * 4 * 10
+    second, history, moved2 = run(1.0)
     assert set(cache) == keys  # nothing was built again
-    assert compiles2 == 0
+    assert moved2["compiles"] == 0
     # the run's offsets, and the regularisation scalars of each solve
-    assert n * 4 <= up2 <= n * 4 + 256
+    assert n * 4 <= moved2["h2d_bytes"] <= n * 4 + 256
     # the model comes to the host once, with a few scalars a step
-    assert down2 < 64 * 1024
+    assert moved2["d2h_bytes"] < 64 * 1024
     for a, b in zip(model_vectors(first), model_vectors(second)):
         np.testing.assert_array_equal(a, b)
     # a grid point: other weights, the same programs and tables
-    _, history, (up3, _, compiles3) = run(1.0 + 1e-3)
-    assert compiles3 == 0 and up3 <= n * 4 + 256
+    _, history, moved3 = run(1.0 + 1e-3)
+    assert moved3["compiles"] == 0 and moved3["h2d_bytes"] <= n * 4 + 256
     # the sweep records say the same of each sweep
     for rec in tm.sweep_records()[-2:]:
         assert rec["compiles"] == 0 and rec["h2d_bytes"] <= 64
