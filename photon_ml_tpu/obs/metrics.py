@@ -271,7 +271,8 @@ class TrainingMetrics:
         fits ran, as their programs counted them (an OWL-QN fit's record
         also carries ``line_search_trials`` and ``nonzeros``, a TRON
         fit's ``cg_steps``, ``rejected_steps``, ``precond_passes`` and
-        ``curvature_passes``, in :meth:`fit_records` alone: no series). A
+        ``curvature_passes``, and either's ``margins_reused``, in
+        :meth:`fit_records` alone: no series). A
         fit's counters stay device scalars in its record
         (:meth:`record_fit`) until a read (:meth:`fit_records`,
         :meth:`snapshot`, :meth:`render`) or until the record leaves the
@@ -299,7 +300,8 @@ class TrainingMetrics:
     # the device scalars of an ``OptimizationResult`` a fit record keeps
     _FIT_COUNTERS = ("iterations", "gather_products", "transpose_products",
                      "line_search_trials", "nonzeros", "cg_steps",
-                     "rejected_steps", "precond_passes", "curvature_passes")
+                     "rejected_steps", "precond_passes", "curvature_passes",
+                     "margins_reused")
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -513,10 +515,11 @@ class TrainingMetrics:
         ``iterations`` / ``gather_products`` / ``transpose_products`` /
         ``line_search_trials`` / ``nonzeros`` (OWL-QN's) / ``cg_steps`` /
         ``rejected_steps`` / ``precond_passes`` / ``curvature_passes``
-        (TRON's) are kept as they are — device scalars of a fit that may
-        still be running — and fetched only when the record is read, never
-        on the fit's path; only a record pushed out of the ring (a fit
-        ``FIT_RECORDS`` calls back) is fetched here, to be counted."""
+        (TRON's) / ``margins_reused`` (either's) are kept as they are —
+        device scalars of a fit that may still be running — and fetched
+        only when the record is read, never on the fit's path; only a
+        record pushed out of the ring (a fit ``FIT_RECORDS`` calls back)
+        is fetched here, to be counted."""
         rec = {"optimizer": optimizer, "sparse_grad": sparse_grad,
                "compiled": bool(compiled), "dispatch_s": float(dispatch_s),
                **{f: getattr(result, f, None) for f in self._FIT_COUNTERS},

@@ -1,4 +1,5 @@
 from photon_ml_tpu.optimize.common import (
+    MarginOracle,
     OptimizationResult,
     OptimizerConfig,
     PathConfig,
@@ -21,21 +22,23 @@ def get_optimizer(name: str):
 
 
 def run_optimizer(optimizer: str, fg, w0, config, *, l1=None, l1_mask=None,
-                  hvp=None, precond=None, curvature=None):
+                  hvp=None, precond=None, margins=None):
     """Run ``optimizer`` on ``fg(w) -> (value, grad)`` from ``w0``, handing
     it the extras it takes and no others: OWL-QN the L1 weight ``l1`` and
     the mask of the coefficients it shrinks (``l1_mask``, None = all); TRON
-    the Hessian-vector product ``hvp(w, v)`` (None = autodiff of ``fg``),
-    the preconditioner's diagonal ``precond(w)`` (None = plain CG) and the
-    linearization ``curvature(w) -> c`` that both then read in ``w``'s
-    place (None = each recomputes its own); L-BFGS neither."""
+    the Hessian-vector product ``hvp(w, v)`` (None = autodiff of ``fg``)
+    and the preconditioner's diagonal ``precond(w)`` (None = plain CG);
+    both ``fg`` in halves that share a point's margins (``margins``, a
+    :class:`MarginOracle`, None = ``fg`` alone: TRON's ``hvp`` and
+    ``precond`` then read its ``curvature(m)`` in ``w``'s place); L-BFGS
+    none of them."""
     opt = get_optimizer(optimizer)
     optimizer = optimizer.lower()
     if optimizer == "owlqn":
-        return opt(fg, w0, l1, config, l1_mask=l1_mask)
+        return opt(fg, w0, l1, config, l1_mask=l1_mask, margins=margins)
     if optimizer == "tron":
         return opt(fg, w0, config, hvp=hvp, precond=precond,
-                   curvature=curvature)
+                   margins=margins)
     return opt(fg, w0, config)
 
 

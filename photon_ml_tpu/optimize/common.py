@@ -12,7 +12,7 @@ iteration.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -171,13 +171,35 @@ class OptimizationResult(NamedTuple):
     # point was refused (``w`` kept, the radius shrunk), the Jacobi
     # diagonals computed (the starting point's and one a step that is
     # accepted and followed by another iteration; 0 without a
-    # preconditioner) and the evaluations of the caller's ``curvature``
-    # (at the same points; 0 without one: ``cg_steps / curvature_passes``
-    # HVPs shared each). None from the other optimizers.
+    # preconditioner) and the evaluations of a :class:`MarginOracle`'s
+    # ``curvature`` (at the same points; 0 without one: ``cg_steps /
+    # curvature_passes`` HVPs shared each). None from the other optimizers.
     cg_steps: "jax.Array | None" = None
     rejected_steps: "jax.Array | None" = None
     precond_passes: "jax.Array | None" = None
     curvature_passes: "jax.Array | None" = None
+    # OWL-QN and TRON, counted on the device (i32 scalar): the evaluations
+    # that read the margins of a point the fit had already evaluated
+    # instead of gathering them (a :class:`MarginOracle`'s): OWL-QN's
+    # gradient at a search's accepted point, one a pass, and TRON's
+    # curvature at an accepted trial point, one a renewal. 0 from either
+    # without such an oracle; None from the other optimizers.
+    margins_reused: "jax.Array | None" = None
+
+
+class MarginOracle(NamedTuple):
+    """A smooth objective in halves that share the margins ``m = X w`` of
+    a point (``parallel.data_parallel.make_csc_path`` builds one over the
+    sorted view): ``value(w) -> (f, m)`` gathers them, ``grad(w, m) -> g``
+    and ``curvature(m) -> c`` (the linearization an ``hvp(c, v)`` and a
+    ``precond(c)`` read) take them instead of gathering them again.
+    ``value`` then ``grad`` at one ``w`` is the same arithmetic as the
+    objective's ``fun_and_grad(w)``. ``m`` is whatever pytree ``value``
+    hands back: ``w`` itself makes ``curvature`` a function of the point."""
+
+    value: Callable
+    grad: Callable
+    curvature: Callable
 
 
 def converged_check(f_prev, f, g_norm, g0_norm, tol, f_scale=None):

@@ -176,35 +176,49 @@ def backtracking(
     shrink: float = 0.5,
     max_evals: int = 30,
     project: Callable | None = None,
+    aux0=None,
 ):
     """Armijo backtracking with optional orthant projection (OWL-QN style).
 
-    fun(w) -> f. ``project(w_trial)`` maps the trial point back to the
+    fun(w) -> f, or ``(f, aux)`` where ``aux0`` (the ``aux`` of ``w``) is
+    given: the search then carries the last trial's ``aux`` and hands back
+    the accepted point's (``aux0`` where none was accepted), so a caller
+    reads what the evaluation of ``w_new`` computed without computing it
+    again. ``project(w_trial)`` maps the trial point back to the
     feasible orthant before evaluation (identity if None). The sufficient
     decrease test uses the OWL-QN form f_new <= f0 + c1 * pseudo_grad.(w_new - w)
     which reduces to plain Armijo when project is None and pseudo_grad is the
-    gradient. Returns (w_new, f_new, n_evals, ok).
+    gradient. Returns (w_new, f_new, aux_new, n_evals, ok); ``aux_new`` is
+    None without ``aux0``.
     """
     proj = project if project is not None else (lambda x: x)
+    evaluate = fun if aux0 is not None else (lambda x: (fun(x), None))
 
     def body(s):
-        alpha, _, _, i, _ = s
+        alpha, _, _, _, i, _ = s
         w_new = proj(w + alpha * p)
-        f_new = fun(w_new)
+        f_new, aux = evaluate(w_new)
         ok = f_new <= f0 + c1 * jnp.sum(pseudo_grad * (w_new - w))
-        return (jnp.where(ok, alpha, alpha * shrink), w_new, f_new, i + 1, ok)
+        return (jnp.where(ok, alpha, alpha * shrink), w_new, f_new, aux,
+                i + 1, ok)
 
     def cond(s):
-        _, _, _, i, ok = s
+        *_, i, ok = s
         return (~ok) & (i < max_evals)
 
-    _, w_new, f_new, i, ok = lax.while_loop(
+    # the carried aux starts as zeros: ``aux0`` is read again below, so a
+    # loop started from it would begin with a copy of it
+    _, w_new, f_new, aux_new, i, ok = lax.while_loop(
         cond, body,
         match_vma_tree(
-            (jnp.asarray(alpha0, f0.dtype), w, f0, jnp.asarray(0), jnp.asarray(False)),
+            (jnp.asarray(alpha0, f0.dtype), w, f0,
+             jax.tree.map(jnp.zeros_like, aux0), jnp.asarray(0),
+             jnp.asarray(False)),
             f0,
         ),
     )
-    w_new = jax.tree.map(lambda a, b: jnp.where(ok, b, a), w, w_new)
+    keep = lambda a, b: jnp.where(ok, b, a)
+    w_new = jax.tree.map(keep, w, w_new)
+    aux_new = jax.tree.map(keep, aux0, aux_new)
     f_new = jnp.where(ok, f_new, f0)
-    return w_new, f_new, i, ok
+    return w_new, f_new, aux_new, i, ok

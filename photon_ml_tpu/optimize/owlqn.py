@@ -8,6 +8,10 @@ lives inside f, matching the reference's split — SURVEY.md §3.1
 regularization row). Standard Andrew & Gao (2007) scheme: pseudo-gradient,
 L-BFGS direction from smooth-gradient history, orthant projection of both the
 direction and the line-search iterates.
+
+Handed a :class:`~photon_ml_tpu.optimize.common.MarginOracle`, the search's
+trials carry the margins ``X w`` their values gathered, and the gradient at
+the accepted point reads them: one gather a trial, none more a pass.
 """
 
 from __future__ import annotations
@@ -19,6 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.optimize.common import (
+    MarginOracle,
     OptimizationResult,
     OptimizerConfig,
     converged_check,
@@ -46,6 +51,7 @@ class _State(NamedTuple):
     w: jax.Array
     F: jax.Array  # full objective incl. L1
     g: jax.Array  # smooth gradient
+    m: object  # the margins of w (None without a MarginOracle)
     s_hist: jax.Array
     y_hist: jax.Array
     rho: jax.Array
@@ -53,7 +59,7 @@ class _State(NamedTuple):
     stalled: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
-    n_gather: jax.Array  # i32: every evaluation gathers its margins
+    n_trials: jax.Array  # i32: the searches' trial points, each one gather
     n_transpose: jax.Array  # i32: only an accepted point's gradient transposes
 
 
@@ -63,20 +69,37 @@ def owlqn(
     l1_weight,
     config: OptimizerConfig = OptimizerConfig(),
     l1_mask: Optional[jax.Array] = None,
+    margins: Optional[MarginOracle] = None,
 ) -> OptimizationResult:
     """Minimize f(w) + l1_weight * ||w * l1_mask||_1; fun_and_grad is the
-    smooth part. l1_mask defaults to all-ones (mask the intercept with 0)."""
+    smooth part. l1_mask defaults to all-ones (mask the intercept with 0).
+    ``margins`` optionally is the same smooth part in halves that share a
+    point's margins: every evaluation then goes through it, and the
+    gradient at a search's accepted point reads that trial's margins."""
     m = config.history
     d = w0.shape[0]
     dtype = w0.dtype
     mask = jnp.ones((d,), dtype) if l1_mask is None else l1_mask.astype(dtype)
     lam = jnp.asarray(l1_weight, dtype) * mask
 
-    def full_value(w):
-        f, _ = fun_and_grad(w)
-        return f + jnp.sum(lam * jnp.abs(w))
+    if margins is None:
+        def full_value(w):
+            f, _ = fun_and_grad(w)
+            return f + jnp.sum(lam * jnp.abs(w))
 
-    f0, g0 = fun_and_grad(w0)
+        def grad_at(w, _):
+            return fun_and_grad(w)[1]
+
+        f0, g0 = fun_and_grad(w0)
+        m0 = None
+    else:
+        def full_value(w):
+            f, mw = margins.value(w)
+            return f + jnp.sum(lam * jnp.abs(w)), mw
+
+        grad_at = margins.grad
+        f0, m0 = margins.value(w0)
+        g0 = margins.grad(w0, m0)
     F0 = f0 + jnp.sum(lam * jnp.abs(w0))
     pg0_norm = l2_norm(pseudo_gradient(w0, g0, lam))
     loss_hist, gnorm_hist = init_history(config.max_iters, F0.dtype)
@@ -99,11 +122,14 @@ def owlqn(
             return jnp.where(w_trial * xi > 0, w_trial, 0.0)
 
         # under photon.owlqn/line_search: projection, the trial's value
-        w_new, F_new, n_trials, ok = backtracking(
+        # (and its margins, with the oracle: the accepted point's, or the
+        # kept w's where the search failed)
+        w_new, F_new, m_new, trials, ok = backtracking(
             full_value, s.w, p, s.F, pg, alpha0=alpha0,
             max_evals=config.max_line_search_steps, project=project,
+            aux0=s.m,
         )
-        _, g_new = fun_and_grad(w_new)
+        g_new = grad_at(w_new, m_new)
         with jax.named_scope("photon.owlqn/update"):
             step = w_new - s.w
             y = g_new - s.g
@@ -118,13 +144,12 @@ def owlqn(
             pg_new_norm = l2_norm(pseudo_gradient(w_new, g_new, lam))
         conv = converged_check(s.F, F_new, pg_new_norm, pg0_norm, config.tolerance)
         return _State(
-            s.it + 1, k_new, w_new, F_new, g_new,
+            s.it + 1, k_new, w_new, F_new, g_new, m_new,
             s_hist, y_hist, rho, conv, ~ok,
             s.loss_hist.at[s.it].set(F_new),
             s.gnorm_hist.at[s.it].set(pg_new_norm),
-            # a trial reads the value alone (its gradient is dead code);
-            # the accepted point is evaluated once more for its gradient
-            s.n_gather + n_trials.astype(jnp.int32) + 1,
+            # a trial reads the value alone (its gradient is dead code)
+            s.n_trials + trials.astype(jnp.int32),
             s.n_transpose + 1,
         )
 
@@ -132,22 +157,27 @@ def owlqn(
         return (~s.converged) & (~s.stalled) & (s.it < config.max_iters)
 
     init = _State(
-        it=jnp.asarray(0), k=jnp.asarray(0), w=w0, F=F0, g=g0,
+        it=jnp.asarray(0), k=jnp.asarray(0), w=w0, F=F0, g=g0, m=m0,
         s_hist=history_zeros(m, d, dtype), y_hist=history_zeros(m, d, dtype),
         rho=jnp.zeros((m,), dtype),
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
-        n_gather=jnp.asarray(1, jnp.int32),  # (f0, g0)
-        n_transpose=jnp.asarray(1, jnp.int32),
+        n_trials=jnp.asarray(0, jnp.int32),
+        n_transpose=jnp.asarray(1, jnp.int32),  # (f0, g0)
     )
     s = lax.while_loop(cond, body, match_vma_tree(init, g0))
     with jax.named_scope("photon.owlqn/pseudo_gradient"):
         final_pg = pseudo_gradient(s.w, s.g, lam)
+    passes = s.it.astype(jnp.int32)
+    # the accepted point's gradient reads its trial's margins, or (without
+    # the oracle) gathers them once more
+    reused = passes if margins is not None else jnp.zeros_like(passes)
     return OptimizationResult(
         w=s.w, value=s.F, grad_norm=l2_norm(final_pg), iterations=s.it,
         converged=s.converged, loss_history=s.loss_hist, grad_norm_history=s.gnorm_hist,
-        gather_products=s.n_gather, transpose_products=s.n_transpose,
-        # every gather but (f0, g0)'s and an accepted point's a pass is a trial
-        line_search_trials=s.n_gather - 1 - s.it.astype(jnp.int32),
+        gather_products=1 + s.n_trials + passes - reused,  # (f0, g0)'s first
+        transpose_products=s.n_transpose,
+        line_search_trials=s.n_trials,
         nonzeros=jnp.count_nonzero(s.w).astype(jnp.int32),
+        margins_reused=reused,
     )

@@ -8,13 +8,17 @@ HVP is forward-over-reverse autodiff inside the same XLA program — roughly two
 fused gradient passes, with any cross-device reduction riding ICI.
 
 The second-order oracle is evaluated at an iterate ONCE. A caller whose
-Hessian is ``X^T diag(d2(w)) X + ...`` hands :func:`tron` the linearization
-``curvature(w) -> c`` (any pytree: the ``[rows]`` vector ``d2`` for a GLM)
-and an ``hvp`` and a ``precond`` that read ``c`` where they would read ``w``:
-``c`` is carried in the loop state beside the Jacobi diagonal, recomputed
-only where a step is accepted and another CG solve will follow, so every
-CG step of a solve and the diagonal share one evaluation of it. Without
-``curvature`` both are handed ``w`` itself and recompute what they need.
+Hessian is ``X^T diag(d2(w)) X + ...`` hands :func:`tron` its objective in
+halves that share a point's margins ``m = X w`` (a
+:class:`~photon_ml_tpu.optimize.common.MarginOracle`), whose ``curvature(m)
+-> c`` linearizes it there (any pytree: the ``[rows]`` vector ``d2`` for a
+GLM), and an ``hvp`` and a ``precond`` that read ``c`` where they would read
+``w``: ``c`` is carried in the loop state beside the Jacobi diagonal,
+recomputed only where a step is accepted and another CG solve will follow,
+from the margins the accepted trial point's evaluation gathered, so every
+CG step of a solve and the diagonal share one evaluation of it and none
+gathers again. Without the oracle both are handed ``w`` itself and
+recompute what they need.
 """
 
 from __future__ import annotations
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.optimize.common import (
+    MarginOracle,
     OptimizationResult,
     OptimizerConfig,
     converged_check,
@@ -116,7 +121,7 @@ class _State(NamedTuple):
     f: jax.Array
     g: jax.Array
     delta: jax.Array
-    c: object  # curvature(w) of the kept w (() without ``curvature``)
+    c: object  # the curvature of the kept w's margins (() without them)
     m_diag: jax.Array  # cached preconditioner diag ([0] when unused)
     converged: jax.Array
     stalled: jax.Array
@@ -126,7 +131,7 @@ class _State(NamedTuple):
     cg_steps: jax.Array  # i32: CG steps (one HVP each) over all iterations
     rejected_steps: jax.Array  # i32: iterations whose trial point was refused
     precond_passes: jax.Array  # i32: Jacobi diagonals computed, m0 included
-    curvature_passes: jax.Array  # i32: curvature(w) evaluations, w0's included
+    curvature_passes: jax.Array  # i32: curvature(m) evaluations, w0's included
 
 
 def tron(
@@ -136,37 +141,51 @@ def tron(
     hvp: Callable | None = None,
     max_cg_iters: int | None = None,
     precond: Callable | None = None,
-    curvature: Callable | None = None,
+    margins: MarginOracle | None = None,
 ) -> OptimizationResult:
     """Minimize fun(w). ``hvp(w, v)`` defaults to forward-over-reverse autodiff
     of the gradient part of ``fun_and_grad``. ``precond(w)`` optionally
     returns the Hessian diagonal at w (one extra data pass per OUTER
     iteration) for Jacobi-preconditioned CG — fewer inner HVP passes on
-    badly-scaled problems. ``curvature(w) -> c`` optionally linearizes the
-    objective at an iterate: ``hvp(c, v)`` and ``precond(c)`` are then
-    called with it in ``w``'s place (an explicit ``hvp`` is required). The
-    curvature and the diagonal are those of the kept ``w``: computed at
-    ``w0`` and after a step that is accepted AND followed by another
-    iteration — a refused step keeps them, and the last iterate's, which
-    no CG solve would read, are never computed."""
+    badly-scaled problems. ``margins`` optionally evaluates the same
+    objective in halves that share a point's margins ``m``: every
+    evaluation then goes through it, and its ``curvature(m) -> c``
+    linearizes the objective at an iterate: ``hvp(c, v)`` and
+    ``precond(c)`` are then called with it in ``w``'s place (an explicit
+    ``hvp`` is required). The curvature and the diagonal are those of the
+    kept ``w``: computed at ``w0`` and after a step that is accepted AND
+    followed by another iteration, from that trial's margins — a refused
+    step keeps them, and the last iterate's, which no CG solve would read,
+    are never computed."""
     dtype = w0.dtype
-    if curvature is not None and hvp is None:
-        raise ValueError("curvature needs the hvp(c, v) that reads it")
+    if margins is not None and hvp is None:
+        raise ValueError("the margins' curvature needs the hvp(c, v) that "
+                         "reads it")
     if hvp is None:
         grad_only = lambda w: fun_and_grad(w)[1]
 
         def hvp(w, v):
             return jax.jvp(grad_only, (w,), (v,))[1]
 
+    if margins is None:
+        def evaluate(w):  # -> (f, g, margins): none without the oracle
+            return (*fun_and_grad(w), None)
+
+        curvature = None
+    else:
+        def evaluate(w):
+            f, mw = margins.value(w)
+            return f, margins.grad(w, mw), mw
+
+        curvature = jax.named_scope("photon.tron/curvature")(
+            margins.curvature)
     hvp = jax.named_scope("photon.tron/hvp")(hvp)
-    trial = jax.named_scope("photon.tron/trial")(fun_and_grad)
+    trial = jax.named_scope("photon.tron/trial")(evaluate)
     if precond is not None:
         precond = jax.named_scope("photon.tron/precond")(precond)
-    if curvature is not None:
-        curvature = jax.named_scope("photon.tron/curvature")(curvature)
     second_order = precond is not None or curvature is not None
     max_cg = max_cg_iters if max_cg_iters is not None else max(w0.shape[0], 20)
-    f0, g0 = fun_and_grad(w0)
+    f0, g0, m0 = evaluate(w0)
     g0_norm = l2_norm(g0)
     loss_hist, gnorm_hist = init_history(config.max_iters, f0.dtype)
 
@@ -175,9 +194,10 @@ def tron(
         return jnp.maximum(md, jnp.finfo(dtype).eps
                            * jnp.maximum(jnp.max(md), 1.0))
 
-    def _second_order(w):
-        """-> (c, m_diag) at ``w``, each as the state holds it."""
-        c = curvature(w) if curvature is not None else ()
+    def _second_order(w, mw):
+        """-> (c, m_diag) at ``w`` (of margins ``mw``), each as the state
+        holds it."""
+        c = curvature(mw) if curvature is not None else ()
         at = w if curvature is None else c
         return c, (_guard(precond(at)) if precond is not None
                    else jnp.zeros((0,), dtype))
@@ -190,7 +210,7 @@ def tron(
         step, r, n_cg = _steihaug_cg(lambda v: hvp(at, v), s.g, s.delta,
                                   cg_tol, max_cg, m_diag=m_diag)
         w_try = s.w + step
-        f_try, g_try = trial(w_try)
+        f_try, g_try, m_try = trial(w_try)
         gs = jnp.sum(s.g * step)
         # r == -(g + H step) from CG, so s.H.s = -g.s - r.s and
         # prered = -(g.s + s.H.s/2) = 0.5*(r.s - g.s) — no extra HVP needed
@@ -230,10 +250,12 @@ def tron(
         stalled = delta < eps * jnp.maximum(l2_norm(w_new), 1.0)
         # the curvature and the diagonal each cost data passes: recompute
         # them only where w moved (a refused step keeps w, so both stay
-        # valid) and a CG solve will read them (``cond`` of the next state)
+        # valid) and a CG solve will read them (``cond`` of the next state).
+        # A renewal's w_new is w_try: the curvature reads its margins
         renew = accept & ~conv & ~stalled & (s.it + 1 < config.max_iters)
         if second_order:
-            c_new, m_new = lax.cond(renew, lambda: _second_order(w_new),
+            c_new, m_new = lax.cond(renew,
+                                    lambda: _second_order(w_new, m_try),
                                     lambda: (s.c, s.m_diag))
         else:
             c_new, m_new = s.c, s.m_diag
@@ -256,10 +278,10 @@ def tron(
     def cond(s: _State):
         return (~s.converged) & (~s.stalled) & (s.it < config.max_iters)
 
-    c0, m0 = _second_order(w0)
+    c0, diag0 = _second_order(w0, m0)
     init = _State(
         it=jnp.asarray(0), w=w0, f=f0, g=g0,
-        delta=g0_norm, c=c0, m_diag=m0,
+        delta=g0_norm, c=c0, m_diag=diag0,
         converged=jnp.asarray(False), stalled=jnp.asarray(False),
         loss_hist=loss_hist, gnorm_hist=gnorm_hist,
         n_products=jnp.asarray(1, jnp.int32),  # (f0, g0)
@@ -276,4 +298,7 @@ def tron(
         cg_steps=s.cg_steps, rejected_steps=s.rejected_steps,
         precond_passes=s.precond_passes,
         curvature_passes=s.curvature_passes,
+        # every curvature but w0's is an accepted trial point's, read off
+        # the margins that trial gathered
+        margins_reused=jnp.maximum(s.curvature_passes - 1, 0),
     )

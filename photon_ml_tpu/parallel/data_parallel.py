@@ -27,7 +27,7 @@ from photon_ml_tpu.obs import trace as obs_trace
 from photon_ml_tpu.ops.losses import apply_weights, mask_margins
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optimize import OptimizerConfig, run_optimizer
-from photon_ml_tpu.optimize.common import OptimizationResult
+from photon_ml_tpu.optimize.common import MarginOracle, OptimizationResult
 from photon_ml_tpu.parallel.mesh import shard_batch
 from photon_ml_tpu.types import (
     LabeledBatch,
@@ -72,9 +72,19 @@ def distributed_value_and_grad(
 
 
 @jax.named_scope("photon.glm/reg")
-def _add_l2(objective, w, l2, f, g):
+def _add_l2_value(objective, w, l2, f):
     wr = objective._reg_mask(w)
-    return f + 0.5 * l2 * jnp.sum(wr * wr), g + l2 * wr
+    return f + 0.5 * l2 * jnp.sum(wr * wr)
+
+
+@jax.named_scope("photon.glm/reg")
+def _add_l2_grad(objective, w, l2, g):
+    return g + l2 * objective._reg_mask(w)
+
+
+def _add_l2(objective, w, l2, f, g):
+    return (_add_l2_value(objective, w, l2, f),
+            _add_l2_grad(objective, w, l2, g))
 
 
 def distributed_hvp(objective: GLMObjective, mesh: Mesh, axis: str = "data") -> Callable:
@@ -270,6 +280,9 @@ class CSCPath(NamedTuple):
     curvature: Callable
     hvp_at: Callable
     diag_at: Callable
+    value: Callable
+    grad_at: Callable
+    d2_at: Callable
 
 
 def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
@@ -296,6 +309,15 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     sum: ``csc_transpose_apply``'s f32 cumsum under ``use_pallas`` too,
     since ``d2`` is all-positive). ``hvp`` is ``hvp_at`` of ``curvature``:
     one body.
+
+    And the objective comes in halves that share a point's margins ``m = X
+    w_eff + offsets + adjust`` (rows sharded over ``axis``), so that a caller
+    who has evaluated a point reads its margins where it would gather them
+    again (an optimizer's :class:`MarginOracle`): ``value(w, batch, l2) ->
+    (f, m)`` (one gather), ``grad_at(w, m, batch, csc, l2) -> g`` (``w`` for
+    the L2 term; the transpose's gather alone) and ``d2_at(m, batch) -> d2``
+    (no gather). ``fg`` is ``grad_at`` of ``value``'s margins and
+    ``curvature`` is ``d2_at`` of a point's margins: one body each.
 
     Normalization composes with the coefficient-space trick: margins use
     ``w_eff = f̃·w`` plus the scalar shift adjustment, and the transposed
@@ -333,35 +355,46 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
         w_eff, adjust = _eff(w)
         return ell_margins(batch.features, w_eff) + batch.offsets + adjust
 
-    def _margin_value_and_d(w, batch):
-        m = _margins_at(w, batch)
-        per_ex = _weighted_loss(objective.loss, batch.weights, batch.labels)
-        with jax.named_scope("photon.glm/loss"):
-            f, d = jax.value_and_grad(per_ex)(m)
-        return f, d
+    def _per_ex(batch):
+        return _weighted_loss(objective.loss, batch.weights, batch.labels)
 
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(), P(axis), P(axis)),
-        out_specs=(P(), P()),
-        check_vma=check_vma,
-    )
-    def shard_fg(w, batch, csc_sh):
-        f, d = _margin_value_and_d(w, batch)
-        csc = jax.tree.map(lambda a: a[0], csc_sh)
-        g = _chain_t(apply_t(csc, d), jnp.sum(d))
-        return _psum_value(f, axis), _psum_grad(g, axis)
-
-    @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
-    )
-    def curvature(w, batch):
-        m = _margins_at(w, batch)
+    def _d2(m, batch):
         with jax.named_scope("photon.glm/loss"):
             return apply_weights(batch.weights,
                                  objective.loss.d2(
                                      mask_margins(batch.weights, m),
                                      batch.labels))
+
+    @functools.partial(
+        shard_map, mesh=mesh, in_specs=(P(), P(axis)),
+        out_specs=(P(), P(axis)),
+    )
+    def shard_value(w, batch):
+        m = _margins_at(w, batch)
+        with jax.named_scope("photon.glm/loss"):
+            f = _per_ex(batch)(m)
+        return _psum_value(f, axis), m
+
+    @functools.partial(
+        shard_map, mesh=mesh,
+        in_specs=(P(axis), P(axis), P(axis)),
+        out_specs=P(),
+        check_vma=check_vma,
+    )
+    def shard_grad_at(m, batch, csc_sh):
+        with jax.named_scope("photon.glm/loss"):
+            d = jax.grad(_per_ex(batch))(m)
+        csc = jax.tree.map(lambda a: a[0], csc_sh)
+        return _psum_grad(_chain_t(apply_t(csc, d), jnp.sum(d)), axis)
+
+    @functools.partial(
+        shard_map, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
+    )
+    def curvature(w, batch):
+        return _d2(_margins_at(w, batch), batch)
+
+    d2_at = shard_map(_d2, mesh=mesh, in_specs=(P(axis), P(axis)),
+                      out_specs=P(axis))
 
     @functools.partial(
         shard_map, mesh=mesh,
@@ -405,10 +438,17 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
             diag = diag * f * f
         return _psum_grad(diag, axis)
 
+    def value(w, batch, l2=0.0):
+        f, m = shard_value(w, batch)
+        return _add_l2_value(objective, w, jnp.asarray(l2, w.dtype), f), m
+
+    def grad_at(w, m, batch, csc, l2=0.0):
+        g = shard_grad_at(m, batch, csc)
+        return _add_l2_grad(objective, w, jnp.asarray(l2, w.dtype), g)
+
     def fg(w, batch, csc, l2=0.0):
-        l2 = jnp.asarray(l2, w.dtype)
-        f, g = shard_fg(w, batch, csc)
-        return _add_l2(objective, w, l2, f, g)
+        f, m = value(w, batch, l2)
+        return f, grad_at(w, m, batch, csc, l2)
 
     def hvp_at(d2, v, batch, csc, l2=0.0):
         l2 = jnp.asarray(l2, v.dtype)
@@ -422,7 +462,8 @@ def make_csc_path(objective: GLMObjective, mesh: Mesh, axis: str = "data",
     def diag_at(d2, csc, l2=0.0):
         return _add_l2_diag(objective, shard_diag_at(d2, csc), l2)
 
-    return CSCPath(build, fg, hvp, curvature, hvp_at, diag_at)
+    return CSCPath(build, fg, hvp, curvature, hvp_at, diag_at, value,
+                   grad_at, d2_at)
 
 
 # What "auto" resolves to where it was measured (PERF.md sections 5 and 7):
@@ -677,17 +718,24 @@ def _black_box_fit(objective, mesh, axis, optimizer, config, sparse_grad,
                        else jnp.ones_like(w0).at[mask_int].set(0.0))
             # TRON's second-order oracle. The Jacobi preconditioner buys
             # fewer CG passes (each CG step is a full pass) for a diagonal
-            # an outer iteration. With the sorted view in hand TRON
-            # carries the curvature d2(w) of its iterate: every HVP of a
-            # CG solve and the diagonal (a transpose of d2 through the
-            # view, no scatter-add) read that one vector. Without a view
-            # each recomputes the margins at the w it is handed.
+            # an outer iteration. With the sorted view in hand OWL-QN and
+            # TRON evaluate through the halves that share a point's
+            # margins: OWL-QN's gradient at a search's accepted point and
+            # TRON's curvature d2 at an accepted trial point read the
+            # margins that point's value gathered, and TRON carries d2:
+            # every HVP of a CG solve and the diagonal (a transpose of d2
+            # through the view, no scatter-add) read that one vector.
+            # Without a view each recomputes the margins at the w it is
+            # handed.
             if use_csc:
                 if csc is None:
                     csc = path.build(b)
                 fg_at = lambda w: path.fg(w, b, csc, l2v)
                 second_order = dict(
-                    curvature=lambda w: path.curvature(w, b),
+                    margins=MarginOracle(
+                        value=lambda w: path.value(w, b, l2v),
+                        grad=lambda w, m: path.grad_at(w, m, b, csc, l2v),
+                        curvature=lambda m: path.d2_at(m, b)),
                     hvp=lambda d2, v: path.hvp_at(d2, v, b, csc, l2v),
                     precond=lambda d2: path.diag_at(d2, csc, l2v))
             else:

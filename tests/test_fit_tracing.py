@@ -49,7 +49,9 @@ PARITY_CASES = {
     ("lbfgs", "margin", "csc", 1): (9, 9),
     ("lbfgs", "margin", "csc", 4): (9, 9),
     ("lbfgs", "full", "csc", 1): (9, 9),
-    ("owlqn", "full", "csc", 1): (17, 9),
+    # over the sorted view the accepted point's gradient reads the margins
+    # its trial gathered: a gather a trial and (f0, g0)'s
+    ("owlqn", "full", "csc", 1): (9, 9),
     ("tron", "full", "csc", 1): (29, 29),
     ("tron", "full", "csc", 4): (29, 29),
 }
@@ -207,8 +209,9 @@ LOWERED = {
         ("photon_fit_lbfgs_margin", _LBFGS_SCOPES, 2),
     ("lbfgs", "full", "csc_pallas"):
         ("photon_fit_lbfgs", _LBFGS_SCOPES, 2),  # g0, a search's trial
-    # g0, a backtracking trial (dead code: compiled away), the accepted point
-    ("owlqn", "full", "csc_pallas"): ("photon_fit_owlqn", _OWLQN_SCOPES, 3),
+    # g0 and the accepted point, each from margins it holds: a backtracking
+    # trial evaluates the value alone
+    ("owlqn", "full", "csc_pallas"): ("photon_fit_owlqn", _OWLQN_SCOPES, 2),
     # g0, an HVP, the trial point, and the Jacobi diagonal (a transpose of
     # the curvature through the view) at w0 and after an accepted step
     ("tron", "full", "csc_pallas"): ("photon_fit_tron", _TRON_SCOPES, 5),
@@ -292,8 +295,9 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
         assert not any("photon.tron/hvp" in s or "photon.tron/cg" in s
                        for s in trial)
         # the second-order oracle at an iterate, once (ISSUE 37): an HVP
-        # gathers X v and dv[rows] and not X w; the curvature's one gather
-        # is X w, at w0 and in the loop; the diagonal's one is d2[rows]
+        # gathers X v and dv[rows] and not X w; the curvature gathers
+        # nothing, at w0 and in the loop: it reads the margins of (f0, g0)
+        # and of the accepted trial; the diagonal's one gather is d2[rows]
         names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
         takes = [names[loc] for loc in re.findall(
             r'call @_take\w*\(.*loc\((#loc\d+)\)$', text, re.M)]
@@ -301,10 +305,10 @@ def test_lowered_fit_holds_every_scope_once_per_call_site(fit, vector_gather):
                    for s in takes), takes
         under = lambda scope: [s for s in takes if f"/{scope}/" in s]
         assert len(under("photon.tron/hvp")) == 2
-        assert len(under("photon.tron/curvature")) == 2  # w0's, the loop's
+        assert len(under("photon.tron/curvature")) == 0
         assert len(under("photon.tron/precond")) == 2
         assert len(under("photon.tron/trial")) == 2  # X w, d[rows]
-        assert len(takes) == 10  # and (f0, g0)'s two
+        assert len(takes) == 8  # and (f0, g0)'s two
         # the diagonal's prefix sum is the f32 cumsum, not the Pallas scan
         # (d2 is all-positive: ``make_csc_path``)
         assert not any("photon.tron/precond" in s
@@ -429,9 +433,10 @@ class _OnDevice:
 
 
 def _result(passes, gathers, transposes, trials=None, nonzeros=None,
-            cls=_OnDevice, tron=None):
+            cls=_OnDevice, tron=None, reused=None):
     """``trials`` and ``nonzeros`` are OWL-QN's, ``tron`` = (CG steps,
-    refused steps, diagonals, curvatures) TRON's: None from the others."""
+    refused steps, diagonals, curvatures) TRON's, ``reused`` both's: None
+    from the others."""
     cg, refused, diagonals, curvatures = tron or (None,) * 4
     return pytypes.SimpleNamespace(
         iterations=cls(passes), gather_products=cls(gathers),
@@ -441,7 +446,8 @@ def _result(passes, gathers, transposes, trials=None, nonzeros=None,
         cg_steps=None if cg is None else cls(cg),
         rejected_steps=None if refused is None else cls(refused),
         precond_passes=None if diagonals is None else cls(diagonals),
-        curvature_passes=None if curvatures is None else cls(curvatures))
+        curvature_passes=None if curvatures is None else cls(curvatures),
+        margins_reused=None if reused is None else cls(reused))
 
 
 def test_record_fit_fetches_nothing_until_read_and_keeps_64():
@@ -471,32 +477,35 @@ def test_record_fit_fetches_nothing_until_read_and_keeps_64():
         "dispatch_s": 0.5, "iterations": 1, "gather_products": 6,
         "transpose_products": 6, "line_search_trials": None,
         "nonzeros": None, "cg_steps": None, "rejected_steps": None,
-        "precond_passes": None, "curvature_passes": None}
+        "precond_passes": None, "curvature_passes": None,
+        "margins_reused": None}
     assert records[0]["compiled"] is False  # the first record has gone
     assert tm.snapshot()["photon_train_fit_passes_total"] == {"": 641}
-    # an OWL-QN fit's record carries its two counters, fetched with the
+    # an OWL-QN fit's record carries its three counters, fetched with the
     # other three when the record is read and not before
     fetched = _OnDevice.fetched
     tm.record_fit(optimizer="owlqn", sparse_grad="csc", compiled=False,
-                  dispatch_s=0.1, result=_result(10, 23, 11, 12, 580063))
+                  dispatch_s=0.1,
+                  result=_result(10, 13, 11, 12, 580063, reused=10))
     assert _OnDevice.fetched == fetched
     last = tm.fit_records()[-1]
-    assert _OnDevice.fetched == fetched + 5
+    assert _OnDevice.fetched == fetched + 6
     assert (last["line_search_trials"], last["nonzeros"]) == (12, 580063)
-    assert (last["iterations"], last["gather_products"]) == (10, 23)
+    assert last["margins_reused"] == 10
+    assert (last["iterations"], last["gather_products"]) == (10, 13)
     assert (last["cg_steps"], last["rejected_steps"], last["precond_passes"],
             last["curvature_passes"]) == (None, None, None, None)
-    # and a TRON fit's its four (ISSUE 36, 37), fetched on read and not
-    # before
+    # and a TRON fit's its five, fetched on read and not before
     fetched = _OnDevice.fetched
     tm.record_fit(optimizer="tron", sparse_grad="csc_pallas", compiled=False,
                   dispatch_s=0.1,
-                  result=_result(6, 19, 19, tron=(12, 1, 5, 5)))
+                  result=_result(6, 19, 19, tron=(12, 1, 5, 5), reused=4))
     assert _OnDevice.fetched == fetched
     last = tm.fit_records()[-1]
-    assert _OnDevice.fetched == fetched + 7
+    assert _OnDevice.fetched == fetched + 8
     assert (last["cg_steps"], last["rejected_steps"], last["precond_passes"],
-            last["curvature_passes"]) == (12, 1, 5, 5)
+            last["curvature_passes"], last["margins_reused"]) == (
+        12, 1, 5, 5, 4)
     assert (last["line_search_trials"], last["nonzeros"]) == (None, None)
 
 
@@ -524,10 +533,12 @@ def test_fit_distributed_leaves_a_record_without_a_device_fetch():
     assert rec["cg_steps"] == 29 - 1 - rec["iterations"]
     assert rec["rejected_steps"] == int(res.rejected_steps) == 0
     assert rec["precond_passes"] == rec["iterations"] > 1
-    assert rec["curvature_passes"] == 0
+    assert rec["curvature_passes"] == rec["margins_reused"] == 0
     with_view = parity_fit(("tron", "full", "csc", 1), obj)
     assert tm.fit_records()[-1]["curvature_passes"] == int(
         with_view.precond_passes) == rec["iterations"]
+    # every curvature but w0's is read off an accepted trial's margins
+    assert tm.fit_records()[-1]["margins_reused"] == rec["iterations"] - 1
     for case in (("lbfgs", "margin", "scatter", 1),
                  ("owlqn", "full", "scatter", 1)):
         other = parity_fit(case)

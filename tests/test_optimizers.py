@@ -211,25 +211,35 @@ def test_run_optimizer_is_the_direct_call(rng, name):
 
 
 # -- TRON's second-order oracle at an iterate, once (ISSUE 37) ---------------
+def _margin_oracle(fg, margins, curvature):
+    """``fg`` in halves: the value beside the margins ``margins(w)``, the
+    gradient, and the curvature of the margins."""
+    from photon_ml_tpu.optimize import MarginOracle
+
+    return MarginOracle(value=lambda w: (fg(w)[0], margins(w)),
+                        grad=lambda w, m: fg(w)[1], curvature=curvature)
+
+
 def _poisson_oracles(rng, n=400, d=12, l2=1.0):
     """A dense Poisson problem whose first TRON step is refused (counts in
     the tens make ``|g0|`` too wide a radius for ``exp``), with its oracle
     twice: from ``w`` (``hvp_w``, ``diag_w``) and from the curvature vector
-    ``d2(w) = exp(X w)`` (``curvature``, ``hvp_c``, ``diag_c``)."""
+    ``d2 = exp(m)`` of the margins ``m = X w`` (``oracle``, a
+    ``MarginOracle``, and ``hvp_c``, ``diag_c``)."""
     X = jnp.asarray(rng.normal(size=(n, d)) * 0.5)
     y = jnp.asarray(rng.poisson(30.0 * np.exp(
         np.asarray(X) @ (rng.normal(size=d) * 0.5))).astype(float))
     batch = make_batch(X, np.asarray(y), dtype=jnp.float64)
     obj = make_objective("poisson")
-    curvature = lambda w: jnp.exp(X @ w)
+    fg = lambda w: obj.value_and_grad(w, batch, l2)
     hvp_c = lambda d2, v: X.T @ (d2 * (X @ v)) + l2 * v
     diag_c = lambda d2: (X * X).T @ d2 + l2
     return dict(
-        fg=lambda w: obj.value_and_grad(w, batch, l2),
-        w0=jnp.zeros(d, jnp.float64),
-        hvp_w=lambda w, v: hvp_c(curvature(w), v),
-        diag_w=lambda w: diag_c(curvature(w)),
-        curvature=curvature, hvp_c=hvp_c, diag_c=diag_c)
+        fg=fg, w0=jnp.zeros(d, jnp.float64),
+        hvp_w=lambda w, v: hvp_c(jnp.exp(X @ w), v),
+        diag_w=lambda w: diag_c(jnp.exp(X @ w)),
+        oracle=_margin_oracle(fg, lambda w: X @ w, jnp.exp),
+        hvp_c=hvp_c, diag_c=diag_c)
 
 
 def _accepted(res, p):
@@ -241,13 +251,14 @@ def _accepted(res, p):
 
 
 def test_tron_with_curvature_is_tron_without(rng):
-    """The HVP and the diagonal handed ``curvature(w)`` are those handed
-    ``w``: the same arithmetic on one ``d2`` where there was one a call."""
+    """The HVP and the diagonal handed the oracle's curvature of the
+    margins are those handed ``w``: the same arithmetic on one ``d2`` where
+    there was one a call."""
     p = _poisson_oracles(rng)
     cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
     without = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=p["diag_w"])
     with_c = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_c"], precond=p["diag_c"],
-                  curvature=p["curvature"])
+                  margins=p["oracle"])
     assert int(without.rejected_steps) >= 1  # both branches of the cond
     assert int(without.cg_steps) > int(without.iterations) == 8
     for name in ("iterations", "cg_steps", "rejected_steps",
@@ -257,27 +268,31 @@ def test_tron_with_curvature_is_tron_without(rng):
                                rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(np.asarray(with_c.loss_history),
                                np.asarray(without.loss_history), rtol=1e-12)
-    assert int(without.curvature_passes) == 0
+    assert int(without.curvature_passes) == int(without.margins_reused) == 0
     assert int(with_c.curvature_passes) == int(with_c.precond_passes)
+    # every curvature but w0's is read off an accepted trial's margins
+    assert int(with_c.margins_reused) == int(with_c.curvature_passes) - 1 > 0
 
 
 @pytest.mark.parametrize("precond", [False, True])
 def test_refused_step_keeps_the_curvature_of_the_kept_w(rng, precond):
-    """With ``curvature`` the identity, the state's ``c`` IS the iterate the
-    oracle believes it stands at. The fit is then the plain one bit for bit
-    only if, after a refused step as after an accepted one, ``c`` (and with
-    it the diagonal) is that of the kept ``w``."""
+    """With ``w`` itself for the margins and the identity for the
+    curvature, the state's ``c`` IS the iterate the oracle believes it
+    stands at. The fit is then the plain one bit for bit only if, after a
+    refused step as after an accepted one, ``c`` (and with it the
+    diagonal) is that of the kept ``w``."""
     p = _poisson_oracles(rng)
     cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
     diag = p["diag_w"] if precond else None
     plain = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=diag)
     carried = tron(p["fg"], p["w0"], cfg, hvp=p["hvp_w"], precond=diag,
-                   curvature=lambda w: w)
+                   margins=_margin_oracle(p["fg"], lambda w: w, lambda m: m))
     refused = ~_accepted(plain, p)
     assert refused[0] and not refused.all()
     assert int(plain.rejected_steps) == refused.sum()
-    for a, b in zip(jax.tree.leaves(carried._replace(curvature_passes=None)),
-                    jax.tree.leaves(plain._replace(curvature_passes=None))):
+    counters = dict(curvature_passes=None, margins_reused=None)
+    for a, b in zip(jax.tree.leaves(carried._replace(**counters)),
+                    jax.tree.leaves(plain._replace(**counters))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -289,7 +304,7 @@ def test_second_order_passes_stop_before_the_last_iterate(rng, steps):
     p = _poisson_oracles(rng)
     res = tron(p["fg"], p["w0"],
                OptimizerConfig(max_iters=steps, tolerance=0.0),
-               hvp=p["hvp_c"], precond=p["diag_c"], curvature=p["curvature"])
+               hvp=p["hvp_c"], precond=p["diag_c"], margins=p["oracle"])
     assert int(res.iterations) == steps
     want = 1 + int(_accepted(res, p)[:-1].sum())
     assert int(res.precond_passes) == int(res.curvature_passes) == want
@@ -309,12 +324,12 @@ def test_converged_fit_computes_no_curvature_of_its_last_iterate(rng):
     fg, obj, batch, X, y, ref, l2 = _logreg_problem(rng)
     Xj = jnp.asarray(X)
     sig = jax.nn.sigmoid
-    curvature = lambda w: sig(Xj @ w) * sig(-(Xj @ w))
     res = tron(fg, jnp.zeros(X.shape[1], jnp.float64),
                OptimizerConfig(max_iters=100, tolerance=1e-10),
                hvp=lambda d2, v: Xj.T @ (d2 * (Xj @ v)) + l2 * v,
                precond=lambda d2: (Xj * Xj).T @ d2 + l2,
-               curvature=curvature)
+               margins=_margin_oracle(fg, lambda w: Xj @ w,
+                                      lambda m: sig(m) * sig(-m)))
     assert bool(res.converged) and int(res.rejected_steps) == 0
     # every step accepted; the converging one renews nothing
     assert int(res.curvature_passes) == int(res.iterations) > 1
@@ -325,7 +340,7 @@ def test_converged_fit_computes_no_curvature_of_its_last_iterate(rng):
 def test_curvature_needs_an_explicit_hvp(rng):
     p = _poisson_oracles(rng)
     with pytest.raises(ValueError, match="curvature"):
-        tron(p["fg"], p["w0"], curvature=p["curvature"])
+        tron(p["fg"], p["w0"], margins=p["oracle"])
 
 
 def test_run_optimizer_hands_tron_the_curvature(rng):
@@ -333,15 +348,69 @@ def test_run_optimizer_hands_tron_the_curvature(rng):
 
     p = _poisson_oracles(rng)
     cfg = OptimizerConfig(max_iters=4, tolerance=0.0)
-    kw = dict(hvp=p["hvp_c"], precond=p["diag_c"], curvature=p["curvature"])
+    kw = dict(hvp=p["hvp_c"], precond=p["diag_c"], margins=p["oracle"])
     want = tron(p["fg"], p["w0"], cfg, **kw)
     got = run_optimizer("tron", p["fg"], p["w0"], cfg, **kw)
     assert int(got.curvature_passes) == int(want.curvature_passes) >= 1
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # the other optimizers take none of it and count none of it
-    assert run_optimizer("lbfgs", p["fg"], p["w0"], cfg,
-                         **kw).curvature_passes is None
+    # L-BFGS takes none of it and counts none of it
+    plain = run_optimizer("lbfgs", p["fg"], p["w0"], cfg, **kw)
+    assert plain.curvature_passes is None and plain.margins_reused is None
+
+
+# -- the backtracking search hands back what its accepted trial computed -----
+def _search_problem():
+    """A quadratic and a descent direction whose first trial (alpha 1)
+    overshoots: its second, alpha 1/2, is accepted. ``aux`` of a point is a
+    vector its evaluation computes beside the value (OWL-QN's margins)."""
+    A = jnp.asarray(np.random.default_rng(5).normal(size=(7, 4)))
+
+    def fun(w):
+        m = A @ w
+        return jnp.sum((m - 1.0) ** 2), m
+
+    w = jnp.zeros(4)
+    g = jax.grad(lambda w: fun(w)[0])(w)
+    # along -s g the quadratic passes Armijo up to alpha ~ 2 / 3
+    s = 3.0 * (g @ g) / (g @ (2.0 * A.T @ (A @ g)))
+    return fun, w, -s * g, g
+
+
+def test_search_hands_back_the_aux_of_the_trial_it_accepts():
+    from photon_ml_tpu.optimize.linesearch import backtracking
+
+    fun, w, p, g = _search_problem()
+    f0, m0 = fun(w)
+    w_new, f_new, m_new, n, ok = jax.jit(
+        lambda w: backtracking(fun, w, p, f0, g, aux0=m0))(w)
+    assert bool(ok) and int(n) == 2  # it backtracked once
+    np.testing.assert_array_equal(np.asarray(w_new), np.asarray(w + 0.5 * p))
+    f_want, m_want = jax.jit(fun)(w_new)
+    np.testing.assert_array_equal(np.asarray(m_new), np.asarray(m_want))
+    assert float(f_new) == float(f_want)
+    # without ``aux0`` ``fun`` returns the value alone: the same search
+    plain = jax.jit(lambda w: backtracking(lambda x: fun(x)[0], w, p, f0,
+                                           g))(w)
+    assert plain[2] is None
+    for a, b in ((plain[0], w_new), (plain[1], f_new), (plain[3], n)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_failed_search_hands_back_the_kept_points_aux():
+    """An ascent direction: no trial passes, ``w`` is kept, and so is the
+    ``aux`` the caller handed in, not the last trial's."""
+    from photon_ml_tpu.optimize.linesearch import backtracking
+
+    fun, w, p, g = _search_problem()
+    f0, _ = fun(w)
+    kept = jnp.full((7,), 7.0)  # anything but a trial's margins
+    w_new, f_new, m_new, n, ok = jax.jit(lambda w: backtracking(
+        fun, w, -p, f0, g, max_evals=3, aux0=kept))(w)
+    assert not bool(ok) and int(n) == 3
+    np.testing.assert_array_equal(np.asarray(w_new), np.asarray(w))
+    assert float(f_new) == float(f0)
+    np.testing.assert_array_equal(np.asarray(m_new), np.asarray(kept))
 
 
 # -- the (s, y) history: one owner of its layout (optimize/common.py) --------

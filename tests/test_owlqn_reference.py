@@ -1,7 +1,9 @@
 """OWL-QN with an elastic net (ISSUE 34): the program's fit against the
 plain reference ``benchmark/reference_owlqn.py`` step by step in float64,
-the reference against the KKT conditions and an outside solver, and the two
-counters the fit now keeps against the reference's counts."""
+the reference against the KKT conditions and an outside solver, the two
+counters the fit now keeps against the reference's counts, and the fit over
+the sorted view, whose gradient reads the margins of the trial its search
+accepted, against the results the parent commit's black-box form gave."""
 
 import os
 import sys
@@ -34,13 +36,14 @@ def problem(rows=ROWS, dim=DIM, k=K, seed=2147483659):
 
 
 def program_fit(indices, labels, dim, sparse_grad, chips, steps=STEPS,
-                l2=L2):
+                l2=L2, dtype=jnp.float64):
     n = indices.shape[0]
     batch = LabeledBatch(SparseFeatures(jnp.asarray(indices), None, dim=dim),
-                         jnp.asarray(labels), jnp.zeros(n), jnp.ones(n))
+                         jnp.asarray(labels, dtype), jnp.zeros(n, dtype),
+                         jnp.ones(n, dtype))
     mesh = make_mesh({"data": chips}, devices=jax.devices()[:chips])
     return fit_distributed(
-        make_objective("logistic"), batch, mesh, jnp.full((dim,), W0),
+        make_objective("logistic"), batch, mesh, jnp.full((dim,), W0, dtype),
         l2=l2, l1=L1, optimizer="owlqn",
         config=OptimizerConfig(max_iters=steps, tolerance=0.0),
         sparse_grad=sparse_grad, line_search="full")
@@ -80,12 +83,18 @@ def test_program_follows_the_reference_step_by_step(followed, sparse_grad,
     assert res.nonzeros.dtype == jnp.int32
     assert int(res.line_search_trials) == sum(trials)
     assert int(res.nonzeros) == np.count_nonzero(w_ref)
-    assert int(res.gather_products) == sum(trials) + STEPS + 1
+    # over the sorted view the accepted point's gradient reads its trial's
+    # margins: a gather a trial and (f0, g0)'s; without it one more a pass
+    reused = STEPS if sparse_grad == "csc" else 0
+    assert res.margins_reused.dtype == jnp.int32
+    assert int(res.margins_reused) == reused
+    assert int(res.gather_products) == sum(trials) + STEPS + 1 - reused
     assert int(res.transpose_products) == STEPS + 1
     record = training_metrics().fit_records()[-1]
     assert record["optimizer"] == "owlqn"
     assert record["line_search_trials"] == sum(trials)
     assert record["nonzeros"] == np.count_nonzero(w_ref)
+    assert record["margins_reused"] == reused
 
 
 def test_trial_count_of_a_step_that_backtracks():
@@ -108,6 +117,42 @@ def test_trial_count_of_a_step_that_backtracks():
     # a weak L2 conditions the problem worse: rounding grows to 3e-9
     np.testing.assert_allclose(np.asarray(fits[-1].loss_history), values,
                                rtol=1e-7)
+    # the search that halves its step reuses the margins of the trial it
+    # accepts (the second), as every other one does: a gather a trial
+    for s, res in zip((8, 9, 10, 11), fits):
+        assert int(res.margins_reused) == int(res.iterations) == s
+        assert int(res.gather_products) == 1 + int(res.line_search_trials)
+
+
+# -- over the sorted view, the parent's results to the bit -------------------
+PARITY = os.path.join(ROOT, "tests", "data", "sorted_view_parity.npz")
+PARITY_FIELDS = ("w", "value", "grad_norm", "iterations", "converged",
+                 "loss_history", "grad_norm_history", "line_search_trials",
+                 "nonzeros")
+PARITY_CASES = [(dtype, chips) for dtype in ("float64", "float32")
+                for chips in (1, 4)]
+
+
+def parity_fit(dtype, chips):
+    """The fit whose results ``tests/data/sorted_view_parity.npz`` holds as
+    the parent commit returned them, when the gradient at an accepted point
+    gathered that point's margins again."""
+    indices, labels = problem()
+    return program_fit(indices, labels, DIM, "csc", chips,
+                       dtype=getattr(jnp, dtype))
+
+
+@pytest.mark.parametrize("dtype,chips", PARITY_CASES)
+def test_sorted_view_fit_keeps_the_parents_bits(dtype, chips):
+    """The margins the gradient reads are the array the search's gather
+    made: every number of the fit is the parent's, float32 included."""
+    res = parity_fit(dtype, chips)
+    assert int(res.margins_reused) == STEPS
+    with np.load(PARITY) as parent:
+        for field in PARITY_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(res, field)),
+                parent[f"owlqn-{dtype}-{chips}/{field}"], err_msg=field)
 
 
 def test_other_optimizers_count_neither():
@@ -123,6 +168,8 @@ def test_other_optimizers_count_neither():
             config=OptimizerConfig(max_iters=2, tolerance=0.0),
             sparse_grad="scatter")
         assert res.line_search_trials is None and res.nonzeros is None
+        # TRON counts the margins it reuses (none without the sorted view)
+        assert (res.margins_reused is None) == (optimizer == "lbfgs")
         record = training_metrics().fit_records()[-1]
         assert record["line_search_trials"] is None
         assert record["nonzeros"] is None

@@ -3,7 +3,9 @@ plain reference ``benchmark/reference_poisson.PoissonL2`` against central
 differences and an outside solver, the program's fit against
 ``reference.tron_steps`` of it step by step in float64, TRON's three new
 counters against the reference's counts, a trial point that overflows
-float32, and the faults the benchmark plants."""
+float32, the faults the benchmark plants, and the fit over the sorted view,
+whose curvature at an accepted trial point reads that point's margins,
+against the results the parent commit gave."""
 
 import os
 import sys
@@ -199,6 +201,10 @@ def test_program_follows_the_reference_step_by_step(followed, sparse_grad,
     curvatures = diagonals if sparse_grad == "csc" else 0
     assert int(res.precond_passes) == diagonals
     assert int(res.curvature_passes) == curvatures
+    # every renewal reads the accepted trial's margins; w0's curvature
+    # shares (f0, g0)'s
+    assert res.margins_reused.dtype == jnp.int32
+    assert int(res.margins_reused) == max(curvatures - 1, 0)
     assert int(res.gather_products) == 1 + sum(cg) + STEPS
     assert int(res.transpose_products) == int(res.gather_products)
     record = training_metrics().fit_records()[-1]
@@ -227,6 +233,9 @@ def test_counters_of_each_step(followed):
     for counter in ("precond_passes", "curvature_passes"):
         assert np.diff([1] + [int(getattr(r, counter)) for r in fits]
                        ).tolist() == [0] + (~theirs[:-1]).astype(int).tolist()
+    # and every renewal is read off the trial's margins, none at w0
+    assert np.diff([0] + [int(r.margins_reused) for r in fits]).tolist() == (
+        [0] + (~theirs[:-1]).astype(int).tolist())
 
 
 def test_other_optimizers_count_none_of_the_three():
@@ -247,6 +256,8 @@ def test_other_optimizers_count_none_of_the_three():
         for name in ("cg_steps", "rejected_steps", "precond_passes",
                      "curvature_passes"):
             assert getattr(res, name) is None and record[name] is None
+        # OWL-QN counts the margins it reuses (none without the sorted view)
+        assert (res.margins_reused is None) == (optimizer == "lbfgs")
 
 
 def test_tron_without_a_preconditioner_computes_no_diagonal():
@@ -256,6 +267,39 @@ def test_tron_without_a_preconditioner_computes_no_diagonal():
     res = tron(fg, jnp.zeros(5), OptimizerConfig(max_iters=4, tolerance=0.0))
     assert int(res.precond_passes) == int(res.curvature_passes) == 0
     assert int(res.cg_steps) == int(res.gather_products) - 1 - 4
+
+
+# -- over the sorted view, the parent's results to the bit -------------------
+PARITY = os.path.join(ROOT, "tests", "data", "sorted_view_parity.npz")
+PARITY_FIELDS = ("w", "value", "grad_norm", "iterations", "converged",
+                 "loss_history", "grad_norm_history", "gather_products",
+                 "cg_steps", "rejected_steps", "precond_passes",
+                 "curvature_passes")
+PARITY_CASES = [(dtype, chips) for dtype in ("float64", "float32")
+                for chips in (1, 4)]
+
+
+def parity_fit(dtype, chips):
+    """The fit whose results ``tests/data/sorted_view_parity.npz`` holds as
+    the parent commit returned them, when the curvature at an accepted trial
+    point gathered that point's margins again."""
+    indices, counts, offsets = problem()
+    return program_fit(indices, counts, offsets, DIM, "csc", chips,
+                       dtype=getattr(jnp, dtype))
+
+
+@pytest.mark.parametrize("dtype,chips", PARITY_CASES)
+def test_sorted_view_fit_keeps_the_parents_bits(dtype, chips):
+    """The curvature of a renewal is read off the very margins the trial's
+    gather made: the CG and accept sequence, the counts and every number of
+    the fit are the parent's, float32 included."""
+    res = parity_fit(dtype, chips)
+    assert int(res.margins_reused) == int(res.curvature_passes) - 1 >= 1
+    with np.load(PARITY) as parent:
+        for field in PARITY_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(res, field)),
+                parent[f"tron-{dtype}-{chips}/{field}"], err_msg=field)
 
 
 # -- a trial point that overflows float32 -----------------------------------

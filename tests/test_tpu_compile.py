@@ -459,9 +459,62 @@ def test_tron_evaluates_its_second_order_oracle_once_an_iterate(topo, cell):
     # accepted and another iteration follows (never with one iteration:
     # ``curvature_passes``, held on the CPU, says how often)
     assert tables("photon.tron/precond", "photon.table_gather") == [rows] * 2
-    # w0's curvature shares (f0, g0)'s gather of X w0; the loop's is the
-    # accepted point's
-    assert tables("photon.tron/curvature", "photon.table_gather") == [dim]
+    # the curvature gathers nothing: w0's reads the margins of (f0, g0),
+    # the loop's those of the accepted trial point (``margins_reused``)
+    assert tables("photon.tron/curvature", "photon.table_gather") == []
     # (f0, g0), an HVP and the trial point: a product gather, d[rows] and
     # `lp`'s two runs each; a diagonal: d2[rows] and `lp`'s two
-    assert len(gathers) == 3 * 4 + 2 * 3 + 1
+    assert len(gathers) == 3 * 4 + 2 * 3
+
+
+def test_owlqn_gathers_an_accepted_point_once(topo):
+    """``photon_fit_owlqn`` over a precomputed view at ``criteo-enet.fit``'s
+    shapes (2^19 rows of 39 implicit ones over 2^24 columns, float32, ten
+    iterations under ``tolerance=0``), compiled as ``fit_distributed``
+    calls it (its own jit, no caller's around it) and read off the text. A
+    trial's value gathers its margins ``X w`` under
+    ``photon.owlqn/line_search`` and the gradient at the accepted point
+    reads them: the loop holds one gather of the 2^24 table, ``(f0, g0)``
+    the other, and each gradient (``g0``'s, the accepted point's) one
+    ``d[rows]``. Every gathered table has the fast memory space, ``w0``'s
+    among them (left in HBM while the accepted point was gathered again:
+    202.5 ms where the loop's take 36.8 on the v5e). The margins ride in
+    the loops' state: no ``[rows]`` vector is copied. About 15 s."""
+    from photon_ml_tpu.parallel import data_parallel as dp
+    from photon_ml_tpu.types import CSCTranspose
+
+    rows, dim = 1 << 19, 1 << 24
+    mesh = make_mesh({"data": 1}, devices=topo.devices[:1])
+    cfg = OptimizerConfig(max_iters=10, tolerance=0.0)
+    _, make = dp._black_box_fit(make_objective("logistic"), mesh, "data",
+                                "owlqn", cfg, "csc_pallas", True)
+    on_rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    s = jax.ShapeDtypeStruct
+    row = s((rows,), f32, sharding=on_rows)
+    batch = LabeledBatch(SparseFeatures(s((rows, K), i32, sharding=on_rows),
+                                        None, dim=dim), row, row, row)
+    view = CSCTranspose(values=None,
+                        rows=s((1, rows * K), i32, sharding=on_rows),
+                        col_starts=s((1, dim + 1), i32, sharding=on_rows))
+    text = jax.jit(make()).lower(
+        s((dim,), f32, sharding=rep), batch, s((), f32, sharding=rep),
+        s((), f32, sharding=rep), view).compile().as_text()
+
+    assert not re.search(r"= f32\[%d\]\S* copy\(" % rows, text)
+    layouts = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", text,
+                              re.M))
+    gathers = [(layouts[table], site) for table, site in re.findall(
+        r"= f32\[\d+,128\]\S* fusion\((%[\w.\-]+), [^\n]*kind=kCustom"
+        r'[^\n]*op_name="([^"]*)/rows/jit\(_take\)/gather', text)]
+    for layout, site in gathers:
+        assert re.fullmatch(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}",
+                            layout), (layout, site)
+    sizes = [(int(re.match(r"f32\[(\d+),", layout)[1]) * 128, site)
+             for layout, site in gathers]
+    of_w = [site for size, site in sizes if size == dim]
+    assert len(of_w) == 2, sizes
+    assert sum("photon.owlqn/line_search/" in site for site in of_w) == 1
+    # each gradient: d[rows], and `lp` over the 81.8 MB of prefixes whole
+    assert sorted(size for size, _ in sizes if size != dim) == (
+        [rows] * 2 + [rows * K] * 2), sizes

@@ -2,7 +2,11 @@
 the curvature vector ``d2(w)`` of an iterate, the Hessian-vector product and
 the Jacobi diagonal that read it — against ``GLMObjective``'s own
 ``diagonal_hessian`` / ``hvp`` (autodiff and scatter-adds) on 1 and 4
-virtual CPU devices, and against the one-call ``hvp(w, v)`` bit for bit."""
+virtual CPU devices, and against the one-call ``hvp(w, v)`` bit for bit.
+And the halves that share a point's margins (``value``, ``grad_at``,
+``d2_at``) against the one-call ``fg`` and ``curvature``, and OWL-QN and
+TRON handed them as a ``MarginOracle`` against the same optimizers handed
+the black box, bit for bit."""
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +128,101 @@ def test_product_at_the_curvature_is_the_one_call_product(rng, mode, chips):
     np.testing.assert_array_equal(np.asarray(one_call), np.asarray(at))
     np.testing.assert_allclose(one_call, obj.hvp(w, v, batch, L2),
                                rtol=1e-10, atol=1e-12)
+
+
+def _margin_oracle(path, sharded, csc, l2=L2):
+    from photon_ml_tpu.optimize import MarginOracle
+
+    return MarginOracle(
+        value=lambda w: path.value(w, sharded, l2),
+        grad=lambda w, m: path.grad_at(w, m, sharded, csc, l2),
+        curvature=lambda m: path.d2_at(m, sharded))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("mode", ["csc", "csc_pallas"])
+@pytest.mark.parametrize("normalized", [False, True])
+def test_halves_are_the_one_call_oracle(rng, normalized, mode, chips):
+    """``fg`` is ``grad_at`` of ``value``'s margins and ``curvature`` is
+    ``d2_at`` of them: one body each, so equal to the bit; and both are the
+    objective's own (f, g) and d2."""
+    if normalized:
+        norm = NormalizationContext(
+            factors=jnp.asarray(rng.uniform(0.5, 2.0, DIM)),
+            shifts=jnp.asarray(rng.normal(size=DIM) * 0.1),
+            intercept_index=0)
+        obj = make_objective("poisson", normalization=norm, intercept_index=0)
+    else:
+        obj = make_objective("poisson")
+    batch = _batch(rng, valued=True)
+    w = jnp.asarray(rng.normal(size=DIM) * 0.2)
+    path, sharded, csc = _oracle(obj, batch, chips,
+                                 use_pallas=mode == "csc_pallas")
+    oracle = _margin_oracle(path, sharded, csc)
+
+    def halves(w):
+        f, m = oracle.value(w)
+        return f, oracle.grad(w, m)
+
+    f, g = jax.jit(halves)(w)
+    d2 = jax.jit(lambda w: oracle.curvature(oracle.value(w)[1]))(w)
+    m = jax.jit(oracle.value)(w)[1]
+    assert m.shape == sharded.labels.shape  # one a row, padding included
+    f1, g1 = jax.jit(lambda w: path.fg(w, sharded, csc, L2))(w)
+    c1 = jax.jit(lambda w: path.curvature(w, sharded))(w)
+    for got, want in ((f, f1), (g, g1), (d2, c1)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    f2, g2 = obj.value_and_grad(w, batch, L2)
+    np.testing.assert_allclose(f, f2, rtol=1e-12)
+    np.testing.assert_allclose(g, g2, rtol=1e-10, atol=1e-12)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("optimizer", ["owlqn", "tron"])
+def test_margin_form_is_the_black_box_form(rng, optimizer, chips):
+    """The same fit twice over one sorted view: handed ``fg`` alone (TRON
+    ``fg`` in halves whose "margins" are ``w`` itself, so its curvature
+    gathers ``X w`` again: the parent's program), and handed the halves.
+    The margins an evaluation reads are the array a gather made, so every
+    number is the same to the bit; OWL-QN's count of gathers differs by the
+    evaluations that read margins (``margins_reused``), its gradient at
+    every accepted point."""
+    from photon_ml_tpu.optimize import OptimizerConfig, owlqn, tron
+
+    obj = make_objective("poisson")
+    batch = _batch(rng, valued=True)
+    path, sharded, csc = _oracle(obj, batch, chips)
+    oracle = _margin_oracle(path, sharded, csc)
+    fg = lambda w: path.fg(w, sharded, csc, L2)
+    w0 = jnp.zeros(DIM)
+    if optimizer == "owlqn":
+        cfg = OptimizerConfig(max_iters=12, tolerance=0.0)
+        black = jax.jit(lambda w: owlqn(fg, w, 0.3, cfg))(w0)
+        halves = jax.jit(lambda w: owlqn(fg, w, 0.3, cfg, margins=oracle))(w0)
+        passes = int(black.iterations)
+        assert int(black.margins_reused) == 0
+        assert int(halves.margins_reused) == passes > 5
+        assert int(black.gather_products) - int(halves.gather_products) == (
+            passes)
+        assert int(halves.gather_products) == 1 + int(halves.line_search_trials)
+        same = halves._replace(margins_reused=None, gather_products=None)
+        want = black._replace(margins_reused=None, gather_products=None)
+    else:
+        from photon_ml_tpu.optimize import MarginOracle
+
+        cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
+        second = dict(hvp=lambda d2, v: path.hvp_at(d2, v, sharded, csc, L2),
+                      precond=lambda d2: path.diag_at(d2, csc, L2))
+        at_w = MarginOracle(value=lambda w: (fg(w)[0], w),
+                            grad=lambda w, _: fg(w)[1],
+                            curvature=lambda w: path.curvature(w, sharded))
+        black = jax.jit(lambda w: tron(fg, w, cfg, margins=at_w,
+                                       **second))(w0)
+        halves = jax.jit(lambda w: tron(fg, w, cfg, margins=oracle,
+                                        **second))(w0)
+        assert int(halves.margins_reused) == (
+            int(halves.curvature_passes) - 1) >= 2
+        same, want = halves, black
+    for a, b in zip(jax.tree.leaves(same), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert len(jax.tree.leaves(same)) == len(jax.tree.leaves(want)) > 8
